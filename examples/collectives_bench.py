@@ -33,7 +33,6 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n = jax.device_count()
     mesh = Mesh(np.asarray(jax.devices()).reshape(n), ("x",))
@@ -55,7 +54,7 @@ def main() -> None:
 
     results = {}
 
-    ar = shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
+    ar = jax.shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
                    in_specs=P("x"), out_specs=P("x"))
     t = timed(ar, xs)
     # Ring all-reduce moves 2*(n-1)/n of the data per link.
@@ -66,15 +65,10 @@ def main() -> None:
     }
 
     # all_gather replicates its output; the replication checker can't
-    # infer that, so it is disabled (kwarg name varies across jax vers).
-    try:
-        ag = shard_map(lambda v: jax.lax.all_gather(v, "x", tiled=True),
+    # infer that, so it is disabled.
+    ag = jax.shard_map(lambda v: jax.lax.all_gather(v, "x", tiled=True),
                        mesh=mesh, in_specs=P("x"), out_specs=P(None),
                        check_vma=False)
-    except TypeError:
-        ag = shard_map(lambda v: jax.lax.all_gather(v, "x", tiled=True),
-                       mesh=mesh, in_specs=P("x"), out_specs=P(None),
-                       check_rep=False)
     t = timed(ag, xs)
     results["all_gather"] = {
         "time_ms": round(t * 1e3, 3),
@@ -83,7 +77,7 @@ def main() -> None:
     }
 
     perm = [(i, (i + 1) % n) for i in range(n)]
-    pp = shard_map(lambda v: jax.lax.ppermute(v, "x", perm), mesh=mesh,
+    pp = jax.shard_map(lambda v: jax.lax.ppermute(v, "x", perm), mesh=mesh,
                    in_specs=P("x"), out_specs=P("x"))
     t = timed(pp, xs)
     results["ppermute"] = {

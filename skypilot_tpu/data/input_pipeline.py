@@ -7,9 +7,13 @@ never waste MXU cycles on padding and packed documents cannot attend
 across boundaries (ops.attention masks on segment ids).
 
 The packing hot loop is native C++ (native/packer.cc, loaded via
-ctypes, built on demand with g++) with a pure-numpy fallback — same
-split the reference makes for its performance-critical host paths
-(reference: SURVEY.md §0, third-party native data movers).
+ctypes, built on demand with g++ — nothing prebuilt is committed) with
+a pure-numpy fallback — same split the reference makes for its
+performance-critical host paths (reference: SURVEY.md §0, third-party
+native data movers). The fallback is never silent: a failed build is
+one typed ``input_pipeline.native_packer_unavailable`` event naming
+the compiler's error, and ``skytpu_packed_batches_total{packer}``
+says which packer produced every batch.
 
 ``prefetch`` overlaps host packing with device compute via a
 double-buffered background thread (the standard TPU input recipe).
@@ -25,6 +29,13 @@ import threading
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence
 
 import numpy as np
+
+from skypilot_tpu.observability import metrics, tracing
+
+PACKED_BATCHES = metrics.counter(
+    "skytpu_packed_batches_total",
+    "Packed train batches produced, by the packer that ran "
+    "(native = native/packer.cc, numpy = the fallback)", ("packer",))
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -47,19 +58,28 @@ def _load_native() -> Optional[ctypes.CDLL]:
                             os.path.join(_REPO_ROOT, "native")],
                            capture_output=True, timeout=120, check=True)
         lib = ctypes.CDLL(_LIB_PATH)
-        lib.pack_documents.restype = ctypes.c_int64
-        lib.pack_documents.argtypes = [
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-        ]
-        _lib = lib
-    except Exception:  # noqa: BLE001 — fall back to numpy
-        _lib = None
+    except (OSError, subprocess.SubprocessError) as e:
+        # Once per process (_lib_tried): the numpy packer takes over,
+        # and the event log says why.
+        detail = getattr(e, "stderr", None) or str(e)
+        if isinstance(detail, bytes):
+            detail = detail.decode(errors="replace")
+        tracing.add_event(
+            "input_pipeline.native_packer_unavailable",
+            {"error": type(e).__name__, "detail": detail[-2000:],
+             "fallback": "numpy"}, echo=True)
+        return None
+    lib.pack_documents.restype = ctypes.c_int64
+    lib.pack_documents.argtypes = [
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
+    ]
+    _lib = lib
     return _lib
 
 
@@ -150,6 +170,9 @@ def packed_batches(doc_stream: Iterable[Sequence[int]], batch: int,
             pending, batch, seq, pad_id, force_numpy)
         if placed == 0:
             break
+        PACKED_BATCHES.labels(
+            "numpy" if force_numpy or _load_native() is None
+            else "native").inc()
         pending = pending[placed:]
         yield {
             "tokens": tokens,

@@ -571,6 +571,14 @@ def truncated_draft(params: llama.Params, cfg: llama.LlamaConfig,
     return dict(params, blocks=blocks), dcfg
 
 
+def truncated_qweights(qweights, n_layers: int):
+    """The int8 tree of a ``self:N`` draft: the target's first
+    ``n_layers`` quantized blocks + its quantized head."""
+    return {"head": qweights["head"],
+            "blocks": {name: {k: a[:n_layers] for k, a in qw.items()}
+                       for name, qw in qweights["blocks"].items()}}
+
+
 def self_distilled_pair(params: llama.Params, cfg: llama.LlamaConfig,
                         draft_layers: int):
     """(target_params, draft_params, draft_cfg) at the distillation
@@ -596,12 +604,14 @@ def draft_engine_from_env(params: llama.Params, cfg: llama.LlamaConfig,
                           n_slots: int, max_len: int,
                           spec: Optional[str] = None,
                           kv_int8: bool = False,
-                          seed: int = 1) -> Optional[DraftEngine]:
+                          seed: int = 1,
+                          qweights=None) -> Optional[DraftEngine]:
     """Build the serving drafter from ``--draft-model`` /
     ``SKYTPU_DRAFT_MODEL``:
 
     * ``self:N`` — truncated-layer draft sharing the target's first N
-      blocks (zero extra weights, zero extra checkpoints);
+      blocks (zero extra weights, zero extra checkpoints); a w8a8
+      target passes its ``qweights`` and the draft runs w8a8 too;
     * a ``llama.CONFIGS`` name (e.g. ``llama3-400m``) — a separate
       draft config, randomly initialized (the repo's serving scaffold
       initializes the target the same way; a distilled checkpoint
@@ -612,9 +622,12 @@ def draft_engine_from_env(params: llama.Params, cfg: llama.LlamaConfig,
             else os.environ.get("SKYTPU_DRAFT_MODEL", "")).strip()
     if not spec:
         return None
+    dqweights = None
     if spec.startswith("self:"):
         n = int(spec.split(":", 1)[1])
         dparams, dcfg = truncated_draft(params, cfg, n)
+        if qweights is not None:
+            dqweights = truncated_qweights(qweights, dcfg.n_layers)
     elif spec in llama.CONFIGS:
         dcfg = llama.CONFIGS[spec]
         if dcfg.vocab_size != cfg.vocab_size:
@@ -626,4 +639,5 @@ def draft_engine_from_env(params: llama.Params, cfg: llama.LlamaConfig,
             f"SKYTPU_DRAFT_MODEL={spec!r}: expected 'self:N' or one "
             f"of {sorted(llama.CONFIGS)}")
     return DraftEngine(dparams, dcfg, n_slots=n_slots,
-                       max_len=max_len, kv_int8=kv_int8, seed=seed)
+                       max_len=max_len, kv_int8=kv_int8, seed=seed,
+                       qweights=dqweights)

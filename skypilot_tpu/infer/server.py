@@ -5,7 +5,8 @@ load balancer probes ``/health`` and proxies ``/generate``; the engine
 thread batches concurrent requests into shared decode bursts.
 
 Endpoints:
-  GET  /health              -> 200 {"status": "ok"} once warm
+  GET  /health              -> 200 {"status": "ok", "device": {...}}
+                               once warm (the device JAX opened)
   GET  /metrics             -> Prometheus text exposition of the
                                process registry (engine TTFT/TPOT
                                histograms, slot occupancy, queue depth,
@@ -41,8 +42,8 @@ from skypilot_tpu import chaos
 from skypilot_tpu.infer import qos as qos_lib
 from skypilot_tpu.observability import flight as flight_lib
 from skypilot_tpu.observability import health as health_lib
-from skypilot_tpu.observability import metrics, tracing
-from skypilot_tpu.utils import timeline
+from skypilot_tpu.observability import attribution, metrics, tracing
+from skypilot_tpu.utils import compile_cache, timeline
 
 HTTP_SECONDS = metrics.histogram(
     "skytpu_http_request_seconds",
@@ -744,7 +745,9 @@ def make_handler(model: ModelServer):
                     return self._json(503, {"status": "draining"},
                                       headers={"Retry-After": "1"})
                 if model._ready.is_set():
-                    return self._json(200, {"status": "ok"})
+                    return self._json(
+                        200, {"status": "ok",
+                              "device": attribution.device_report()})
                 return self._json(503, {"status": "warming"})
             if self.path == "/healthz":
                 # The fleet health model's shape: always 200 (the
@@ -1128,8 +1131,9 @@ def serve(engine, host: str = "0.0.0.0", port: int = 8080,
 
 
 def main() -> None:
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--config", default=None)
+    ap.add_argument("--config", default="llama3-400m")
     ap.add_argument("--port", type=int, default=8080)
     ap.add_argument("--slots", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=1024)
@@ -1285,9 +1289,7 @@ def main() -> None:
     from skypilot_tpu.infer import engine as eng, sampling
     from skypilot_tpu.models import llama
 
-    on_cpu = jax.default_backend() == "cpu"
-    cfg = llama.CONFIGS[args.config or
-                        ("llama3-tiny" if on_cpu else "llama3-400m")]
+    cfg = llama.CONFIGS[args.config]
     mesh = None
     if args.tp > 1:
         import numpy as np
@@ -1297,13 +1299,17 @@ def main() -> None:
             raise SystemExit(f"--tp {args.tp} needs {args.tp} devices, "
                              f"found {len(devices)}")
         mesh = Mesh(np.array(devices[:args.tp]), ("tp",))
-        # Sharded-at-init: each device materializes only its shards —
-        # a plain init_params would build the full fp tree on device 0
-        # and OOM exactly the bigger-than-one-chip models --tp exists
-        # for.
-        params = eng.InferenceEngine.sharded_init(cfg, mesh)
-    else:
-        params = llama.init_params(jax.random.key(0), cfg)
+    # Weights are built on the device(s) at the size they are served:
+    # int8 without the float tree they would quantize from, float in
+    # the compute dtype, sharded at init under --tp. A tree that
+    # cannot fit is a typed start-up error naming the bytes.
+    try:
+        params, qweights = eng.random_serving_weights(
+            cfg, weights_int8=args.weights_int8, mesh=mesh)
+    except eng.WeightsDoNotFitError as e:
+        tracing.add_event("server.weights_do_not_fit", e.typed_error,
+                          echo=True)
+        raise SystemExit(str(e))
     # "--span-buckets 0" disables bucketing; a comma list is an
     # explicit ladder; unset falls through to the engine default /
     # SKYTPU_SPAN_BUCKETS.
@@ -1320,14 +1326,15 @@ def main() -> None:
     catalog = ad_lib.catalog_from_env(cfg, adapters_json=args.adapters,
                                       slots=args.adapter_slots,
                                       rank=args.adapter_rank)
-    # Model-backed drafter (docs/serving.md §Speculative decoding):
-    # built BEFORE the engine slims the fp tree (a 'self:N' draft
-    # shares the target's first N blocks by reference). None = the
-    # n-gram drafter stays the only rung.
+    # Model-backed drafter (docs/serving.md §Speculative decoding): a
+    # 'self:N' draft shares the target's first N blocks (float or
+    # int8) by reference. None = the n-gram drafter stays the only
+    # rung.
     from skypilot_tpu.infer import draft as draft_lib
     draft_engine = draft_lib.draft_engine_from_env(
         params, cfg, n_slots=args.slots, max_len=args.max_len,
-        spec=args.draft_model, kv_int8=args.kv_int8)
+        spec=args.draft_model, kv_int8=args.kv_int8,
+        qweights=qweights)
     engine = eng.InferenceEngine(
         params, cfg, n_slots=args.slots, max_len=args.max_len,
         mesh=mesh,
@@ -1335,7 +1342,7 @@ def main() -> None:
                         args.max_len),
         sampling_params=sampling.SamplingParams(
             temperature=args.temperature),
-        kv_int8=args.kv_int8, weights_int8=args.weights_int8,
+        kv_int8=args.kv_int8, qweights=qweights,
         max_wave=args.admit_wave,
         prefill_chunk=args.prefill_chunk,
         kv_block=args.kv_block, kv_blocks=args.kv_blocks,
@@ -1365,10 +1372,6 @@ def main() -> None:
         # enters program identity (the compile watch is the gate).
         qos=qos_lib.scheduler_from_env(),
         adapters=catalog)
-    # The engine slims its own tree under weights_int8; drop main()'s
-    # reference too or the fp block weights stay resident for the whole
-    # server lifetime and the memory halving never happens.
-    del params
     if args.warm_grid:
         # Compile the whole program grid BEFORE /health can flip, then
         # arm the compile watch: from here on, a new program compiling
@@ -1387,7 +1390,9 @@ def main() -> None:
                          open_window_s=args.open_window,
                          coalesce_s=args.coalesce,
                          qos=qos_lib.admission_from_env("server"))
-    tracing.add_event("server.listening", {"port": args.port},
+    tracing.add_event("server.listening",
+                      {"port": args.port,
+                       "device": attribution.device_report()},
                       echo=True)
     try:
         httpd.serve_forever()

@@ -214,8 +214,9 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None,
     default ``seq -> sp``) is > 1, the sequence dimension is
     context-parallel: ring attention over that ring, circulating the
     unrepeated KV heads (see parallel.ring_attention). Otherwise local
-    flash/XLA attention. Mesh-axis names come from the rules table, never
-    hardcoded here.
+    flash/XLA attention — under a mesh through ``ra.local_attention``,
+    which shard_maps the flash kernel over the batch and heads axes.
+    Mesh-axis names come from the rules table, never hardcoded here.
     """
     from skypilot_tpu.ops import attention as attn_ops
     if mesh is not None:
@@ -223,6 +224,8 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None,
         from skypilot_tpu.parallel import sharding as sh
         rules = rules if rules is not None else sh.ACT_RULES
         seq_axis = rules.get("seq")
+        heads_axis = rules.get("heads")
+        hspec = heads_axis if isinstance(heads_axis, str) else None
         if (isinstance(seq_axis, str)
                 and mesh.shape.get(seq_axis, 1) > 1
                 and q.shape[1] % mesh.shape[seq_axis] == 0):
@@ -230,8 +233,6 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None,
             # attention — same degrade-to-replicated convention as
             # spec_for.) Packed sequences ride the ring: segment ids
             # circulate with their K/V blocks.
-            heads_axis = rules.get("heads")
-            hspec = heads_axis if isinstance(heads_axis, str) else None
             if rules.get("seq_layout") == "zigzag":
                 # forward_hidden already put activations/positions/segs
                 # in the zigzag layout (it owns the decision + permute).
@@ -243,6 +244,9 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None,
                 q, k, v, mesh, causal=True, axis=seq_axis,
                 batch_axes=rules.get("batch"), heads_axis=hspec,
                 segment_ids=segment_ids)
+        return ra.local_attention(
+            q, k, v, mesh, causal=True, batch_axes=rules.get("batch"),
+            heads_axis=hspec, segment_ids=segment_ids)
     return attn_ops.gqa_attention(q, k, v, causal=True,
                                   segment_ids=segment_ids)
 

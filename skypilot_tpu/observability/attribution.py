@@ -43,6 +43,7 @@ three axes, all host-side except one deliberate, sampled sync:
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import threading
 import time
@@ -92,27 +93,62 @@ HBM_DEVICE_IN_USE = metrics.gauge(
     "ledger's cross-check)")
 ROOFLINE_PEAK_FLOPS = metrics.gauge(
     "skytpu_roofline_peak_flops",
-    "Peak device FLOP/s the MFU column divides by "
-    "(SKYTPU_PEAK_TFLOPS, else a device-kind table, else a CPU "
-    "placeholder)")
+    "Peak device bf16 FLOP/s the MFU column divides by "
+    "(SKYTPU_PEAK_TFLOPS, else the device_kind peaks table)")
 ROOFLINE_PEAK_BW = metrics.gauge(
     "skytpu_roofline_peak_hbm_bytes_per_s",
     "Peak HBM bandwidth (bytes/s) the bandwidth-utilization column "
     "divides by (SKYTPU_PEAK_GBPS, else a device-kind table)")
 
-# Peak FLOP/s (bf16) and HBM bytes/s per device kind — the roofline
-# denominators. Matched by substring against jax device_kind; the CPU
-# fallback is a deliberately modest placeholder so MFU stays a
-# meaningful nonzero ratio in tests and local runs.
-_PEAKS: Dict[str, tuple] = {
-    "v6e": (918e12, 1638e9),
-    "v5p": (459e12, 2765e9),
-    "v5e": (394e12, 819e9),
-    "v4": (275e12, 1228e9),
-    "v3": (123e12, 900e9),
-    "v2": (45e12, 700e9),
-    "cpu": (0.5e12, 50e9),
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks: the roofline denominators."""
+    bf16_flops: float
+    int8_ops: float
+    hbm_bytes_per_s: float
+    source: str
+
+
+_CLOUD_DOCS = "Google Cloud TPU documentation, system architecture: "
+# THE peaks table (bench.py imports it). Keyed by exactly what
+# ``device.device_kind`` says — one v5e chip reports "TPU v5 lite" —
+# and a kind that is not here is an error, never a default: a silent
+# placeholder turns every MFU and roofline share into fiction.
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v4": DevicePeaks(275e12, 275e12, 1228e9, _CLOUD_DOCS + "TPU v4"),
+    "TPU v5 lite": DevicePeaks(197e12, 393e12, 819e9,
+                               _CLOUD_DOCS + "TPU v5e"),
+    "TPU v5": DevicePeaks(459e12, 918e12, 2765e9, _CLOUD_DOCS + "TPU v5p"),
+    "TPU v6 lite": DevicePeaks(918e12, 1836e12, 1640e9,
+                               _CLOUD_DOCS + "TPU v6e"),
 }
+# platform == "cpu" only (the test suite, local dry runs): a modest
+# placeholder so MFU stays a meaningful nonzero ratio there. Never
+# reached by an accelerator, whatever its kind.
+_CPU_PEAKS = DevicePeaks(0.5e12, 0.5e12, 50e9, "placeholder (CPU backend)")
+
+
+class UnknownDeviceError(LookupError):
+    """``device_kind`` has no row in :data:`PEAKS`."""
+
+
+def device_report() -> Dict[str, Any]:
+    """The device this process opened, as JAX reports it: what
+    ``/health``, the ``server.listening`` event and ``train.run``'s
+    summary carry so a caller can tell a chip run from a CPU run
+    without importing JAX itself. ``memory`` has one entry per local
+    device (``memory_stats()`` where the backend reports it; {} on
+    CPU)."""
+    import jax
+    devices = jax.local_devices()
+    keys = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+    memory = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        memory.append({k: int(stats[k]) for k in keys if k in stats})
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": jax.device_count(), "memory": memory}
 
 
 def devtime_every(default: int = 64) -> int:
@@ -125,24 +161,30 @@ def devtime_every(default: int = 64) -> int:
         return default
 
 
+def peaks_for(device=None) -> DevicePeaks:
+    """The :data:`PEAKS` row of ``device`` (default: the first local
+    device). Raises :class:`UnknownDeviceError` for an accelerator the
+    table does not list."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if getattr(device, "platform", "") == "cpu":
+        return _CPU_PEAKS
+    kind = str(getattr(device, "device_kind", ""))
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no published peaks for device_kind {kind!r} (known: "
+            f"{sorted(PEAKS)}); add its row to observability/"
+            f"attribution.py PEAKS with the source") from None
+
+
 def device_peaks(device=None) -> tuple:
-    """(peak FLOP/s, peak HBM bytes/s) for the local device, env
+    """(peak bf16 FLOP/s, peak HBM bytes/s) for the local device, env
     overrides first (SKYTPU_PEAK_TFLOPS / SKYTPU_PEAK_GBPS)."""
-    kind = ""
-    if device is not None:
-        kind = str(getattr(device, "device_kind", "")).lower()
-    else:
-        try:
-            import jax
-            kind = str(getattr(jax.devices()[0], "device_kind",
-                               "")).lower()
-        except Exception:              # no backend at all: placeholder
-            kind = "cpu"
-    flops, bw = _PEAKS["cpu"]
-    for k, peaks in _PEAKS.items():
-        if k in kind:
-            flops, bw = peaks
-            break
+    row = peaks_for(device)
+    flops, bw = row.bf16_flops, row.hbm_bytes_per_s
     env_f = os.environ.get("SKYTPU_PEAK_TFLOPS")
     if env_f:
         try:

@@ -251,6 +251,9 @@ class CompileWatch:
                     {"program": key, "compile_s": round(dt, 4)},
                     echo=True)
             return out
+        # The jitted function itself, for callers that lower/compile
+        # ahead of time (tests/test_chip_compile.py).
+        wrapped.__wrapped__ = fn
         return wrapped
 
     # -- warmup state ------------------------------------------------------

@@ -380,6 +380,17 @@ def _flash_bwd(causal, block_q, block_k, interpret, residuals, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def fit_block(s: int, want: int, lanes_only: bool = False):
+    """Largest block <= ``want`` that divides ``s``: a multiple of 128
+    rows where one exists, else (unless ``lanes_only``) a multiple of
+    the 8-row sublane tile; None when neither divides ``s``."""
+    for multiple in (LANES,) if lanes_only else (LANES, SUBLANES):
+        for b in range(min(want, s) // multiple * multiple, 0, -multiple):
+            if s % b == 0:
+                return b
+    return None
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     segment_ids=None,
                     block_q: int = DEFAULT_BLOCK_Q,
@@ -390,20 +401,22 @@ def flash_attention(q, k, v, causal: bool = True,
     K/V must already be GQA-expanded to H heads (ops.attention does it).
     ``segment_ids`` [B, S] int32 enables packed-sequence masking (needs
     block_k to be a multiple of 128 for the lane-tiled compare).
+
+    ``block_q``/``block_k`` are upper bounds: each shrinks to the
+    largest divisor of S that keeps the tiling (S = 1280 runs at 256,
+    not at an error). Only a sequence with no such divisor raises.
     """
     b, s, h, d = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if s % block_q or s % block_k:
-        raise ValueError(f"seq len {s} must be divisible by block sizes "
-                         f"({block_q}, {block_k})")
+    fit_q = fit_block(s, block_q)
+    fit_k = fit_block(s, block_k, lanes_only=segment_ids is not None)
+    if fit_q is None or fit_k is None:
+        raise ValueError(
+            f"seq len {s} has no block <= ({block_q}, {block_k}) that "
+            f"divides it and keeps the tiling (multiples of {SUBLANES} "
+            f"rows; the kv block a multiple of {LANES} under segment "
+            f"masking) — pad the sequence to a multiple of {LANES}")
+    block_q, block_k = fit_q, fit_k
     if segment_ids is not None:
-        if block_k % LANES:
-            raise ValueError(
-                f"segment masking needs the kv block to be a multiple "
-                f"of {LANES} lanes; effective block_k is {block_k} "
-                f"(seq len {s} — pad the sequence to a multiple of "
-                f"{LANES})")
         segment_ids = segment_ids.astype(jnp.int32)
     # [B,S,H,D] -> [B,H,S,D] for the kernels.
     qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))

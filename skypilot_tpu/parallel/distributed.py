@@ -44,8 +44,7 @@ def initialize_from_env() -> ProcessTopology:
     topo = topology_from_env()
     if topo.num_processes > 1 and topo.coordinator:
         import jax
-        from jax._src import distributed as _dist
-        if getattr(_dist.global_state, "client", None) is None:
+        if not jax.distributed.is_initialized():
             jax.distributed.initialize(
                 coordinator_address=topo.coordinator,
                 num_processes=topo.num_processes,

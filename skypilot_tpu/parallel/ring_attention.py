@@ -52,22 +52,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):              # jax >= 0.5
-    _shard_map_impl = jax.shard_map
-else:                                      # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across the 0.4 -> 0.5 rename: the replication
-    check kwarg was ``check_rep`` before it became ``check_vma``."""
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=check_vma)
-    except TypeError:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma)
-
 NEG_INF = -1e30
 
 
@@ -539,7 +523,7 @@ def zigzag_ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
                                            heads_axis, q, k)
     has_seg = segment_ids is not None
     seg = segment_ids if has_seg else _dummy_seg(q)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_zigzag_attn, axis, n, has_seg),
         mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec, seg_spec),
         out_specs=q_spec, check_vma=False)
@@ -610,6 +594,37 @@ def _dummy_seg(q):
     return jnp.zeros((q.shape[0], q.shape[1]), jnp.int32)
 
 
+def local_attention(q, k, v, mesh: Optional[Mesh], causal: bool = True,
+                    batch_axes=("dp", "fsdp"),
+                    heads_axis: Optional[str] = "tp",
+                    segment_ids=None):
+    """Attention with the sequence UNSHARDED under a mesh (no context
+    parallelism; ``mesh=None`` is the plain single-device call):
+    every (batch row, head) is independent, so the call
+    runs per shard of the batch and heads axes. The einsum path needs
+    no help — the SPMD compiler partitions it — but the Pallas flash
+    kernel does: "Mosaic kernels cannot be automatically partitioned",
+    so where the shapes pick the kernel it is wrapped in a
+    ``shard_map`` over those two axes (same divisibility fallback as
+    the ring: a dim its axis does not divide is replicated)."""
+    from skypilot_tpu.ops import attention as attn_ops
+    if mesh is None or mesh.size == 1 or not attn_ops.uses_flash(
+            q.shape[1], q.shape[-1]):
+        return attn_ops.gqa_attention(q, k, v, causal=causal,
+                                      segment_ids=segment_ids)
+    q_spec, kv_spec, seg_spec = _qkv_specs(mesh, None, batch_axes,
+                                           heads_axis, q, k)
+    has_seg = segment_ids is not None
+    seg = segment_ids if has_seg else _dummy_seg(q)
+    fn = jax.shard_map(
+        lambda q, k, v, seg: attn_ops.gqa_attention(
+            q, k, v, causal=causal,
+            segment_ids=seg if has_seg else None),
+        mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec, seg_spec),
+        out_specs=q_spec, check_vma=False)
+    return fn(q, k, v, seg)
+
+
 def ring_attention(q, k, v, mesh: Mesh, causal: bool = True,
                    axis: str = "sp", batch_axes=("dp", "fsdp"),
                    heads_axis: Optional[str] = "tp",
@@ -627,7 +642,7 @@ def ring_attention(q, k, v, mesh: Mesh, causal: bool = True,
                                            heads_axis, q, k)
     has_seg = segment_ids is not None
     seg = segment_ids if has_seg else _dummy_seg(q)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attn, axis, n, causal, has_seg),
         mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec, seg_spec),
         out_specs=q_spec, check_vma=False)
@@ -658,7 +673,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, causal: bool = True,
                 f"{axis}={n}; use ring_attention instead")
     has_seg = segment_ids is not None
     seg = segment_ids if has_seg else _dummy_seg(q)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, axis, n, causal, has_seg),
         mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec, seg_spec),
         out_specs=q_spec, check_vma=False)

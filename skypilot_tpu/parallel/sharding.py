@@ -96,23 +96,39 @@ def logical_to_sharding(logical_tree, mesh: Mesh, rules: Rules = DEFAULT_RULES,
         logical_tree, shapes, is_leaf=is_leaf)
 
 
-def shard_tree_subset(tree, logical_tree, mesh: Mesh, rules: Rules):
-    """device_put every array in ``tree`` per its axes in
-    ``logical_tree``, walking by DICT KEY so ``tree`` may be a subset
-    of the axes tree (e.g. w8a8 serving's slimmed params: embed + norms
-    only — a plain tree.map would fail on the structure mismatch).
-    Arrays without an axes entry are replicated."""
-    replicated = NamedSharding(mesh, P())
+def subset_shardings(tree, logical_tree, mesh: Mesh, rules: Rules):
+    """NamedShardings for every leaf of ``tree`` (arrays or
+    ShapeDtypeStructs) per its axes in ``logical_tree``, walking by
+    DICT KEY so ``tree`` may be a subset of the axes tree (e.g. w8a8
+    serving's slimmed params: embed + norms only — a plain tree.map
+    would fail on the structure mismatch). Leaves without an axes
+    entry are replicated."""
     if isinstance(tree, dict):
         sub = logical_tree if isinstance(logical_tree, dict) else {}
-        return {k: shard_tree_subset(v, sub.get(k), mesh, rules)
+        return {k: subset_shardings(v, sub.get(k), mesh, rules)
                 for k, v in tree.items()}
-    axes = logical_tree if isinstance(logical_tree, tuple) else None
-    if axes is None:
-        return jax.device_put(tree, replicated)
+    if not isinstance(logical_tree, tuple):
+        return NamedSharding(mesh, P())
+    return NamedSharding(mesh, spec_for(logical_tree, rules, mesh,
+                                        tree.shape))
+
+
+def shard_tree_subset(tree, logical_tree, mesh: Mesh, rules: Rules):
+    """device_put ``tree`` per :func:`subset_shardings`."""
     return jax.device_put(
-        tree, NamedSharding(mesh, spec_for(axes, rules, mesh,
-                                           tree.shape)))
+        tree, subset_shardings(tree, logical_tree, mesh, rules))
+
+
+def init_sharded(build, axes_of, mesh: Mesh, rules: Rules):
+    """Run the zero-argument ``build`` under jit with out_shardings:
+    every device materializes only its own shards, so a tree bigger
+    than one chip's HBM (8B weights in bf16, a 32-slot KV cache) is
+    never built whole on device 0 and resharded afterwards.
+    ``axes_of(abstract_tree)`` returns the logical axes (by dict key,
+    as in :func:`subset_shardings`)."""
+    abstract = jax.eval_shape(build)
+    return jax.jit(build, out_shardings=subset_shardings(
+        abstract, axes_of(abstract), mesh, rules))()
 
 
 # Inference TP rules: Megatron-style heads/mlp/vocab over tp; no data/

@@ -47,6 +47,8 @@ def evaluate(step_less_loss_fn: Callable, params,
 
 
 def main() -> None:
+    from skypilot_tpu.utils import compile_cache
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="llama", choices=("llama", "moe"))
     ap.add_argument("--config", default=None)

@@ -34,6 +34,8 @@ def log(*a):
 
 
 def main() -> None:
+    from skypilot_tpu.utils import compile_cache
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="llama",
                     choices=("llama", "moe", "pipeline"))
@@ -129,7 +131,7 @@ def main() -> None:
                                        dp=args.dp, ep=args.ep, pp=args.pp)
     mesh = mesh_lib.make_mesh(shape)
     log(f"mesh: {shape.as_dict()} over {n} devices "
-        f"({jax.devices()[0].device_kind})")
+        f"({jax.devices()[0].platform}: {jax.devices()[0].device_kind})")
 
     data_degree = shape.dp * shape.fsdp
     batch = args.batch or 4 * data_degree
@@ -255,6 +257,13 @@ def main() -> None:
                 state = trainer.create_train_state(cfg, tc, mesh,
                                                    model=model)
 
+    # Per-device bytes once the train state exists and before any step
+    # runs: under a mesh they should be level — a state piled on
+    # device 0 shows here, not as an allocator crash mid-run.
+    jax.block_until_ready(state)
+    state_bytes = [m.get("bytes_in_use")
+                   for m in attribution.device_report()["memory"]]
+
     if args.packed:
         import jax.numpy as jnp
 
@@ -330,6 +339,7 @@ def main() -> None:
         mgr.close()
     snap = gp.snapshot()
     tokens_per_s = batch * args.seq * (args.steps - start_step) / wall
+    from skypilot_tpu.ops import attention as attn_ops
     print(json.dumps({
         "final_loss": round(loss, 4),
         "steps": args.steps - start_step,
@@ -338,6 +348,13 @@ def main() -> None:
         "tokens_per_sec_per_chip": round(tokens_per_s / n, 1),
         "goodput": round(snap["goodput_ratio"], 4),
         "mesh": shape.as_dict(),
+        # Where it ran and what it compiled: the device as JAX reports
+        # it (with each local device's memory_stats), per-device bytes
+        # right after the train state was built, and the attention
+        # implementation every traced program chose.
+        "device": attribution.device_report(),
+        "state_bytes_in_use": state_bytes,
+        "attention": attn_ops.traced_impls(),
     }))
 
 

@@ -1,0 +1,397 @@
+"""What a chip run would hit, checked without a chip.
+
+Part 1 asks the TPU's own compiler (installed here; the chip is
+*described*, not attached — the `on-chip-measurement` guide §2.3) to
+compile the Pallas kernels and two engine programs at llama3-8b
+widths. Interpret-mode tests cannot see what it refuses: a block that
+breaks the (8, 128) tiling rule, a kernel that outgrows scoped VMEM, a
+Mosaic call the SPMD partitioner cannot split. Nothing runs, so these
+say nothing about results or times.
+
+Part 2 holds the rest of the bring-up contract on the CPU: the peaks
+table, the compile-cache helper, the weight builder's start-up error,
+and chip_smoke.py's control flow at tiny configs — including that a CPU
+never gets a passing last line.
+"""
+
+import dataclasses
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from skypilot_tpu.infer import engine as eng
+from skypilot_tpu.infer import kvcache
+from skypilot_tpu.models import llama
+from skypilot_tpu.observability import attribution
+from skypilot_tpu.ops import attention as attn_ops
+from skypilot_tpu.ops import flash_attention as fa
+from skypilot_tpu.ops import paged_attention as pa
+from skypilot_tpu.utils import compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+import chip_smoke  # noqa: E402
+
+# ---------------------------------------------------------------------------
+# Part 1: ahead-of-time compiles for a described v5e
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on the first device of a described v5e:2x2 host. The
+    persistent compile cache is off around these compiles: an
+    executable for a described device is written to it but can never
+    be read back, so it would only warn and pile up."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+def _sds(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+def _compiled_kernels(fn, *args, **kw) -> int:
+    """Compile for the described chip; count the Mosaic kernels in it."""
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    compiled = jitted.lower(*args, **kw).compile()
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# llama3-8b: 32 layers, 8 kv-heads of 128, 4 q-heads each; the serve
+# recipe's pool: 33 slots x 5 blocks of 256 rows.
+_L, _NB, _BL, _G, _HD, _REP = 32, 165, 256, 8, 128, 4
+
+
+@pytest.mark.parametrize("rows", [_REP, 512 * _REP],
+                         ids=["decode", "chunk"])
+@pytest.mark.parametrize("quant", [True, False], ids=["int8", "bf16"])
+def test_paged_kernel_compiles_for_v5e(one_chip, quant, rows):
+    """The paged-attention kernel lowers at the llama3-8b pool
+    [32, n_blocks, 256, 8, 128] — decode rows and a 512-token chunk's
+    rows (which need the row tiling to stay inside scoped VMEM)."""
+    S = _sds(one_chip)
+    slots = 33 if rows == _REP else 1
+    pool = S((_L, _NB, _BL, _G, _HD), jnp.int8 if quant else jnp.bfloat16)
+    scale = S((_L, _NB, _G, _BL), jnp.bfloat16) if quant else None
+
+    def f(q, kp, vp, ks, vs, table, lengths, layer):
+        return pa.paged_attention(q, kp, vp, ks, vs, table, lengths,
+                                  layer, span_blocks=5, interpret=False)
+
+    assert _compiled_kernels(
+        f, S((slots, _G, rows, _HD), jnp.bfloat16), pool, pool, scale,
+        scale, S((slots, 6), jnp.int32), S((slots,), jnp.int32),
+        S((), jnp.int32)) == 1
+
+
+def _flash_loss(q, k, v, seg=None):
+    return fa.flash_attention(q, k, v, causal=True,
+                              segment_ids=seg).astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("which", ["forward", "backward",
+                                   "segment_backward"])
+def test_flash_kernels_compile_for_v5e(one_chip, which):
+    """Flash forward, backward and segment-masked backward at the
+    trainer's shapes: [6, 2048, 16, 128] bf16 (llama3-1b)."""
+    S = _sds(one_chip)
+    x = S((6, 2048, 16, 128), jnp.bfloat16)
+    if which == "forward":
+        n = _compiled_kernels(
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+            x, x, x)
+        assert n == 1
+    elif which == "backward":
+        n = _compiled_kernels(
+            jax.grad(_flash_loss, argnums=(0, 1, 2)), x, x, x)
+        assert n == 3          # forward + dKV + dQ
+    else:
+        n = _compiled_kernels(
+            jax.grad(_flash_loss, argnums=(0, 1, 2)), x, x, x,
+            S((6, 2048), jnp.int32))
+        assert n == 3
+
+
+@pytest.fixture(scope="module")
+def engine_8b_2layers():
+    """The serve recipe's engine (w8a8, int8 KV, 32 slots, 1280) at
+    every llama3-8b width, depth cut to 2 layers so a compile stays a
+    few seconds. Weights are shapes only; the 2-layer cache is real
+    (~0.2 GB of host memory)."""
+    cfg = dataclasses.replace(llama.CONFIGS["llama3-8b"], n_layers=2)
+    params, qweights = jax.eval_shape(
+        lambda: kvcache.random_quantized_params(cfg))
+    return eng.InferenceEngine(
+        params, cfg, qweights=qweights, n_slots=32, max_len=1280,
+        prompt_buckets=(128, 512, 1280), kv_int8=True, max_wave=4,
+        pad_waves=True, prefix_pool=8, spec_k=4)
+
+
+def _engine_args(e, sharding):
+    abstract = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=sharding), tree)
+    S = _sds(sharding)
+    return (abstract(e.params), abstract(e.qweights), abstract(e.cache),
+            abstract(e.rng), S(e.block_table.shape, jnp.int32), S)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel"])
+def test_decode_burst_compiles_for_v5e(one_chip, engine_8b_2layers,
+                                       kernel, monkeypatch):
+    """The engine's own burst program (its jit, its donation), with the
+    gather read and with the paged kernel inside the layer scan —
+    compiled, so the suite's interpreter switch goes off here."""
+    monkeypatch.setattr(pa, "INTERPRET", False)
+    e = engine_8b_2layers
+    params, qw, cache, rng, table, S = _engine_args(e, one_chip)
+    n = _compiled_kernels(
+        e._decode_burst_fn.__wrapped__, params, cache, rng,
+        S((e.n_slots + 1,), jnp.bool_), table, k=4, qweights=qw,
+        span=None, kernel=kernel)
+    assert n == (1 if kernel else 0)
+
+
+def test_prefill_chunk_compiles_for_v5e(one_chip, engine_8b_2layers):
+    e = engine_8b_2layers
+    params, qw, cache, rng, table, S = _engine_args(e, one_chip)
+    i32 = S((), jnp.int32)
+    _compiled_kernels(
+        e._prefill_chunk_fn.__wrapped__, params, cache,
+        S((512,), jnp.int32), i32, i32, i32, i32, rng, table, final=True,
+        qweights=qw, span=640, kernel=False)
+
+
+def test_top_bucket_prefill_takes_flash_at_1280(one_chip,
+                                                engine_8b_2layers,
+                                                monkeypatch):
+    """The server's top prompt bucket is max_len (1280 in the recipe):
+    not a multiple of 512, so flash shrinks its block to 256 instead
+    of raising — and the wave program really holds the kernel. The
+    compile happens on the CPU backend, so the test steers the
+    backend check there (never a program option)."""
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    e = engine_8b_2layers
+    params, qw, cache, rng, table, S = _engine_args(e, one_chip)
+    n = _compiled_kernels(
+        e._admit_wave_fn.__wrapped__, params, cache,
+        S((4, 1280), jnp.int32), S((4,), jnp.int32), S((4,), jnp.int32),
+        rng, table, bucket=1280, qweights=qw)
+    assert n == 1
+
+
+# ---------------------------------------------------------------------------
+# Part 2: the bring-up contract on the CPU
+# ---------------------------------------------------------------------------
+
+
+class _Device:
+    def __init__(self, platform, kind, stats=None):
+        self.platform, self.device_kind, self._stats = platform, kind, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    v5e = _Device("tpu", "TPU v5 lite")
+    row = attribution.peaks_for(v5e)
+    assert (row.bf16_flops, row.int8_ops, row.hbm_bytes_per_s) == \
+        (197e12, 393e12, 819e9)
+    assert "v5e" in row.source
+    assert attribution.device_peaks(v5e) == (197e12, 819e9)
+    with pytest.raises(attribution.UnknownDeviceError, match="TPU v9"):
+        attribution.device_peaks(_Device("tpu", "TPU v9 mega"))
+    # The placeholder row is for platform == "cpu" only.
+    assert attribution.peaks_for(_Device("cpu", "cpu")).source.startswith(
+        "placeholder")
+    with pytest.raises(attribution.UnknownDeviceError):
+        attribution.peaks_for(_Device("tpu", "cpu"))
+
+
+def test_compile_cache_helper(monkeypatch, tmp_path):
+    # JAX_COMPILATION_CACHE_DIR set: it wins, untouched.
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert compile_cache.configure() == str(tmp_path)
+    assert os.environ[compile_cache.ENV_VAR] == str(tmp_path)
+    # Not set: the fixed in-checkout path. jax is imported in this
+    # process, so configure() also tells its config — put that back.
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    try:
+        assert compile_cache.configure() == os.path.join(REPO,
+                                                         ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(REPO, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert compile_cache.is_warm(str(tmp_path)) is False
+    (tmp_path / "entry").write_text("x")
+    assert compile_cache.is_warm(str(tmp_path)) is True
+
+
+def test_flash_block_shrinks_to_a_divisor():
+    assert fa.fit_block(2048, 512) == 512
+    assert fa.fit_block(1280, 512) == 256      # the recipe's top bucket
+    assert fa.fit_block(100, 64) is None
+    assert fa.fit_block(256, 64, lanes_only=True) is None
+    assert attn_ops.flash_eligible(1280, 128)
+    assert not attn_ops.flash_eligible(1280, 64)     # head_dim
+    assert not attn_ops.flash_eligible(1000, 128)    # no 128-row block
+    assert not attn_ops.flash_eligible(512, 128)     # short: einsum wins
+
+
+def test_serving_weights_that_cannot_fit_are_a_typed_error(monkeypatch):
+    cfg = llama.CONFIGS["llama3-tiny"]
+    params, qweights = eng.random_serving_weights(cfg)
+    assert qweights is None and params["embed"].dtype == cfg.dtype
+    params, qweights = eng.random_serving_weights(cfg, weights_int8=True)
+    assert sorted(params["blocks"]) == ["ln1", "ln2"]
+    assert qweights["blocks"]["wq"]["w"].dtype == jnp.int8
+    small = _Device("tpu", "TPU v5 lite", {"bytes_limit": 100_000})
+    monkeypatch.setattr(jax, "devices", lambda: [small])
+    with pytest.raises(eng.WeightsDoNotFitError) as err:
+        eng.random_serving_weights(cfg)
+    assert err.value.typed_error["type"] == "weights_do_not_fit"
+    assert err.value.typed_error["limit_bytes"] == 100_000
+    assert err.value.typed_error["need_bytes"] == \
+        cfg.num_params() * jnp.dtype(cfg.dtype).itemsize
+
+
+def test_tp_engine_builds_its_cache_sharded():
+    """Under a mesh the KV cache is created sharded (kv-heads over tp),
+    never whole on one device and resharded afterwards."""
+    from jax.sharding import Mesh
+    cfg = llama.CONFIGS["llama3-tiny"]
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    params, _ = eng.random_serving_weights(cfg, mesh=mesh)
+    e = eng.InferenceEngine(params, cfg, n_slots=2, max_len=64,
+                            prompt_buckets=(16,), mesh=mesh)
+    k = e.cache["k"]
+    assert k.sharding.spec[3] == "tp"
+    assert k.addressable_shards[0].data.shape[3] == cfg.n_kv_heads // 2
+    assert params["blocks"]["wq"].sharding.spec[2] == "tp"
+
+
+# chip_smoke.py's control flow, at tiny configs on the CPU.
+_TINY_TRAIN = ["--config", "llama3-tiny", "--seq", "64", "--batch", "2",
+               "--log-every", "1"]
+_TINY_PLAN = {
+    "serve": {"args": ["--config", "llama3-tiny", "--weights-int8",
+                       "--kv-int8", "--slots", "4", "--max-len", "128",
+                       "--max-burst", "8", "--open-burst", "4",
+                       "--admit-wave", "2", "--prefill-chunk", "32"],
+              "vocab": 512, "short": (24, 32), "long": (48, 64),
+              "new_tokens": 8},
+    "train": [
+        {"name": "qlora-tiny",
+         "args": _TINY_TRAIN + ["--steps", "3", "--qlora", "4",
+                                "--qlora-random-base"]},
+        {"name": "full-tiny", "args": _TINY_TRAIN + ["--steps", "4"]},
+    ],
+    "launch": {"args": _TINY_TRAIN + ["--steps", "2"]},
+}
+
+
+@pytest.fixture()
+def in_pytest(monkeypatch):
+    """pytest's process has imported JAX (on the CPU, where it holds
+    nothing against a child); the script's parent never does. The
+    children get one CPU device, not the suite's eight."""
+    monkeypatch.setattr(chip_smoke, "_parent_is_off_jax", lambda: True)
+    monkeypatch.setenv("XLA_FLAGS",
+                       "--xla_force_host_platform_device_count=1")
+
+
+def _smoke(phases, tmp_path, **kw):
+    lines = []
+    code = chip_smoke.run(phases, _TINY_PLAN, str(tmp_path), emit=lines.append,
+                          **kw)
+    return code, [json.loads(line) for line in lines]
+
+
+def test_chip_smoke_serve_and_train_rehearsal(tmp_path, in_pytest):
+    """The platform check stubbed to "cpu": both phases run their real
+    children and every check of the script holds at tiny size."""
+    code, lines = _smoke([chip_smoke.phase_serve, chip_smoke.phase_train],
+                         tmp_path, platform="cpu")
+    assert [r.get("phase") for r in lines[:-1]] == [
+        "serve", "train:qlora-tiny", "train:full-tiny"]
+    assert all(r["ok"] for r in lines), lines
+    serve = lines[0]
+    assert serve["generate_codes"] == {"200": 6}
+    assert [r["stream"] for r in serve["requests"]].count(True) == 3
+    assert serve["requests"][-1]["cache_hit"] is True
+    assert serve["repeat_diverged_at"] is None
+    assert any(p.startswith("prefill_chunk") for p in serve["programs"])
+    assert any(p.startswith("admit_wave") for p in serve["programs"])
+    assert lines[1]["losses"][-1] <= lines[1]["losses"][0]
+    assert code == 0
+    assert lines[-1] == {"ok": True, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 1}}
+
+
+def test_chip_smoke_launch_rehearsal(tmp_path, in_pytest):
+    code, lines = _smoke([chip_smoke.phase_launch], tmp_path,
+                         platform="cpu")
+    assert code == 0, lines
+    assert lines[0]["phase"] == "launch" and lines[0]["down_exit"] == 0
+    assert "(cpu: cpu)" in lines[0]["job_log_device"]
+
+
+def test_chip_smoke_refuses_a_cpu(tmp_path, in_pytest):
+    """With the real platform requirement a CPU child is killed, the
+    phase fails, no later phase starts, and the last line says so."""
+    code, lines = _smoke([chip_smoke.phase_serve, chip_smoke.phase_train],
+                         tmp_path)
+    assert code == 1
+    assert [r.get("phase") for r in lines[:-1]] == ["serve"]
+    assert lines[0]["ok"] is False and "'cpu'" in lines[0]["error"]
+    assert lines[-1] == {"ok": False, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 1}}
+
+
+def test_chip_smoke_command_never_passes_without_a_chip(tmp_path):
+    """The command itself, as the driver runs it, on this CPU-only
+    host: non-zero, last line "ok": false. (The deadline keeps it from
+    building 8B weights on the CPU first; the refusal of a CPU child
+    is the test above.) And in a directory that holds the script and
+    nothing else of the repo it prints no result at all."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--deadline",
+         "0.01", "--out", str(tmp_path / "out")],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0
+    last = json.loads(p.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False and set(last) == {"ok", "device"}
+
+    alone = tmp_path / "alone"
+    alone.mkdir()
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), alone)
+    env.pop("PYTHONPATH", None)
+    p = subprocess.run([sys.executable, "chip_smoke.py"], cwd=alone, env=env,
+                       capture_output=True, text=True, timeout=60)
+    assert p.returncode != 0 and p.stdout.strip() == ""
