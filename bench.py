@@ -78,12 +78,6 @@ def main() -> None:
                          "output JSON under 'observability' — the same "
                          "counters/histograms production scrapes from "
                          "/metrics, so BENCH records carry them")
-    ap.add_argument("--emit-trace", action="store_true", default=False,
-                    help="aggregate this run's recorded trace spans "
-                         "(engine per-request queue-wait/prefill/decode, "
-                         "train steps — observability/tracing.py) into "
-                         "the output JSON under 'trace' as per-span-name "
-                         "count/total/mean/max durations")
     args = ap.parse_args()
 
     from skypilot_tpu.utils import compile_cache
@@ -709,9 +703,6 @@ def main() -> None:
         snap = obs_metrics.REGISTRY.snapshot()
         out["observability"] = {
             name: fam for name, fam in snap.items() if _recorded(fam)}
-    if args.emit_trace:
-        from skypilot_tpu.observability import tracing
-        out["trace"] = tracing.span_summary()
     print(json.dumps(out), flush=True)
     failed = sorted(k for k in out if k.endswith("_error"))
     if failed:

@@ -91,6 +91,13 @@ _SCOPES: Dict[str, Set[str]] = {
         # work; a device fetch inside _retire would stall the very
         # completion path whose latency the ledger decomposes.
         "_retire", "_mark_stall", "_end_stall", "_observe_tail",
+        # Phase annotations (PR 25): the bodies the new
+        # ``timeline.phase`` blocks wrap moved to helpers of their own
+        # and stay in scope under their new names. ``timeline.phase``
+        # itself performs no device sync: its arguments are host ints
+        # and floats, and the profiler annotation it enters is inert
+        # unless a trace is running.
+        "_admit_pass", "_launch_wave", "_commit_burst", "_retire_impl",
     },
     # Model-backed drafter (PR 14): draft_batch/rollout run once per
     # verify round on the engine loop; everything except the draft
@@ -147,6 +154,9 @@ _SCOPES: Dict[str, Set[str]] = {
     "skypilot_tpu/infer/server.py": {
         "_loop", "_step", "_drain_inbox", "_flush_streams",
         "_complete_burst", "_on_wave",
+        # PR 25: the inbox and results bodies moved under their phase
+        # annotations.
+        "_enqueue", "_deliver_finished", "_has_work",
     },
     "skypilot_tpu/train/trainer.py": {
         "_instrument_step", "observe_loss",
@@ -210,7 +220,10 @@ class HostSyncChecker(Checker):
     # v13: crash recovery (PR 19) — the dispatch-seam bodies moved to
     #     *_impl names and recover() joined the scope; the bump
     #     rescans the renamed hot paths cold.
-    version = 13
+    # v14: phase annotations (PR 25) — the bodies wrapped by
+    #     ``timeline.phase`` blocks moved to helpers that joined the
+    #     scope (the burst's int() loop is now ``_commit_burst``'s).
+    version = 14
 
     def check_file(self, ctx: FileContext) -> List[Finding]:
         scoped = _SCOPES.get(ctx.rel)

@@ -49,10 +49,6 @@ PREFILL_SECONDS = metrics.histogram(
 PREFILL_REQUESTS = metrics.counter(
     "skytpu_prefill_requests_total",
     "Requests prefilled, by prompt bucket", labelnames=("bucket",))
-WAVE_SIZE = metrics.histogram(
-    "skytpu_admission_wave_size",
-    "Real (pre-padding) requests per admission wave",
-    buckets=(1, 2, 4, 8, 16, 32, 64))
 DECODE_STEP_SECONDS = metrics.histogram(
     "skytpu_decode_step_seconds",
     "Decode device-call latency, dispatch to token fetch (one call "
@@ -214,6 +210,10 @@ class Request:
     cached_len: int = 0
     n_chunks: int = 0
     prefill_begin_s: float = 0.0
+    # Seconds from submit to the dispatch of this request's first
+    # prefill program (wave or first chunk) — what the queue cost it;
+    # the dispatch / fetch annotations report it beside the TTFT.
+    queue_s: float = 0.0
     # Speculative-decode stats (surfaced next to the cache stats in
     # the response trailer) + per-request drafter state. ``spec_off``
     # flips when this request's acceptance collapses — it keeps riding
@@ -301,6 +301,9 @@ class BurstHandle:
     # completion record splits its host wall into dispatch vs fetch
     # at this stamp.
     dispatch_done_s: Optional[float] = None
+    # Burst sequence number: the dispatch and the fetch annotations of
+    # one burst carry it, so a trace reader pairs them exactly.
+    seq: int = 0
 
 
 class PromptTooLongError(ValueError):
@@ -1108,6 +1111,7 @@ class InferenceEngine:
         # host-side (one outstanding async burst at a time is the
         # expected pattern; the count caps the next burst).
         self._inflight_tokens = 0
+        self._burst_seq = 0      # decode bursts dispatched (annotations)
         # Static ledger components once; the dynamic ones (kv_used,
         # prefix_pinned) refresh with the slot gauges, so the ledger
         # init must follow the slot bookkeeping above. The runtime
@@ -1146,7 +1150,8 @@ class InferenceEngine:
             prefix, logits = kvcache.prefill_batch(
                 params, tokens_b, true_lens, cfg, qweights=qweights,
                 lora=lora, aid=aid, mesh=mesh, heads_axis=heads_axis)
-            first = sampling.sample(logits, sub, sp)      # [W]
+            with jax.named_scope("sample"):
+                first = sampling.sample(logits, sub, sp)      # [W]
 
             def ins(c, w):
                 pk = _lax.dynamic_index_in_dim(prefix["k"], w, 1,
@@ -1171,7 +1176,8 @@ class InferenceEngine:
                                                 qweights=qweights,
                                                 table=table, span=span,
                                                 lora=lora, aid=aid)
-            toks = sampling.sample(logits, sub, sp)
+            with jax.named_scope("sample"):
+                toks = sampling.sample(logits, sub, sp)
             cache = kvcache.commit_tokens(cache, toks, active)
             return cache, rng, toks
 
@@ -2336,6 +2342,11 @@ class InferenceEngine:
         ENGINE_WAITING.set(len(self.waiting))
 
     def _admit_impl(self, on_wave=None) -> None:
+        with timeline.phase("engine.admit.plan",
+                            n=len(self.waiting)) as plan:
+            self._admit_pass(on_wave, plan)
+
+    def _admit_pass(self, on_wave, plan: timeline.Phase) -> None:
         # Waves are grouped by prompt bucket (prefill is O(S^2): one
         # long prompt must not drag every co-admitted short prompt up
         # to its bucket) and capped at max_wave, then padded to the
@@ -2464,6 +2475,7 @@ class InferenceEngine:
         if quota_held:
             self.waiting.extendleft(reversed(quota_held))
             ENGINE_WAITING.set(len(self.waiting))
+        plan.set(held=len(quota_held), stalled=1 if stalled else 0)
 
     def _use_chunked(self, req: Request) -> bool:
         return (self.prefill_chunk is not None
@@ -2625,18 +2637,33 @@ class InferenceEngine:
         attn_span = self._span_arg(self._span_for(start))
         self.decode_programs.add(("chunk", final, attn_span))
         t0 = time.time()
-        self.cache, self.rng, tok_dev = self._prefill_chunk_fn(
-            self.params, self.cache, jnp.asarray(chunk),
-            jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_valid, jnp.int32),
-            jnp.asarray(req.slot, jnp.int32),
-            jnp.asarray(new_len, jnp.int32), self.rng,
-            self.table_device(), final=final, qweights=self.qweights,
-            span=attn_span, kernel=self.kv_kernel,
-            **self._lora_args())
+        fresh = req.first_token_s is None    # not a preemption resume
+        counts = {"chunk_tokens": n_valid, "padded_tokens": C,
+                  "final": 1 if final else 0}
+        if fresh and req.n_chunks == 0:
+            req.queue_s = max(t0 - req.submit_s, 0.0)
+            counts["queue_ms"] = round(req.queue_s * 1e3, 3)
+        with timeline.phase("engine.chunk.dispatch", **counts):
+            self.cache, self.rng, tok_dev = self._prefill_chunk_fn(
+                self.params, self.cache, jnp.asarray(chunk),
+                jnp.asarray(start, jnp.int32),
+                jnp.asarray(n_valid, jnp.int32),
+                jnp.asarray(req.slot, jnp.int32),
+                jnp.asarray(new_len, jnp.int32), self.rng,
+                self.table_device(), final=final,
+                qweights=self.qweights, span=attn_span,
+                kernel=self.kv_kernel, **self._lora_args())
         t_disp = time.time()             # dispatch returned; fetch next
         chunk_key = self.compile_watch.last_key
-        tok = int(tok_dev)               # host sync (garbage unless final)
+        with timeline.phase("engine.chunk.fetch",
+                            final=counts["final"]) as ph:
+            tok = int(tok_dev)           # host sync (garbage unless final)
+            if final and fresh:
+                # The request's first token lands here: its queue wait
+                # and its TTFT on one event, as on a wave's fetch.
+                ph.set(queue_ms=round(req.queue_s * 1e3, 3),
+                       ttft_ms=round(max(time.time() - req.submit_s,
+                                         0.0) * 1e3, 3))
         dt = time.time() - t0
         PREFILL_CHUNKS.inc()
         req.n_chunks += 1
@@ -2889,7 +2916,6 @@ class InferenceEngine:
         dispatch THROUGH first-token fetch, the latency a request
         actually experiences), and whether decode slots were active at
         dispatch (the wave then also counts as decode stall)."""
-        WAVE_SIZE.observe(len(wave))
         span = timeline.Event(
             "skytpu_prefill_seconds",
             histogram=PREFILL_SECONDS.labels(bucket=str(bucket)))
@@ -2899,10 +2925,28 @@ class InferenceEngine:
             tracing.record_span(
                 "engine.queue_wait", req.submit_s, span.begin_s,
                 parent=req.span_ctx, attrs={"rid": req.rid})
+            if req.first_token_s is None:      # not a preemption resume
+                req.queue_s = max(span.begin_s - req.submit_s, 0.0)
         if self.pad_waves:
             n = self.max_wave
         else:
             n = 1 << (len(wave) - 1).bit_length() if len(wave) > 1 else 1
+        queued = [req.queue_s * 1e3 for req in wave
+                  if req.first_token_s is None]
+        with timeline.phase(
+                "engine.wave.dispatch", rows=len(wave), padded_rows=n,
+                bucket=bucket,
+                prompt_tokens=sum(self._ctx_len(r) for r in wave),
+                queue_ms_sum=round(sum(queued), 3),
+                queue_ms_max=round(max(queued, default=0.0), 3)):
+            return self._launch_wave(wave, slots, bucket, n, span)
+
+    def _launch_wave(self, wave: List["Request"], slots: List[int],
+                     bucket: int, n: int, span: timeline.Event
+                     ) -> Tuple[jax.Array, timeline.Event, bool,
+                                float, Optional[str]]:
+        """Build one wave's padded host arrays and enqueue its
+        program (the body of :meth:`_dispatch_wave`'s annotation)."""
         tokens_b = np.zeros((n, bucket), np.int32)
         true_lens = np.ones((n,), np.int32)
         slot_ids = np.full((n,), self.n_slots, np.int32)  # spare
@@ -2935,9 +2979,19 @@ class InferenceEngine:
                        bucket: int, decode_active: bool = False,
                        dispatch_s: Optional[float] = None,
                        dev_key: Optional[str] = None) -> None:
-        first = np.asarray(first_dev)          # host sync for THIS wave
-        span.end()
-        now = time.time()
+        fresh = [r for r in wave if r.first_token_s is None]
+        with timeline.phase("engine.wave.fetch", rows=len(wave),
+                            first_tokens=len(fresh)) as ph:
+            first = np.asarray(first_dev)      # host sync for THIS wave
+            span.end()
+            now = time.time()
+            # Queue wait and TTFT of the SAME requests (resumes carry
+            # neither), so a reader can take their ratio per fetch.
+            ph.set(queue_ms_sum=round(
+                       sum(r.queue_s for r in fresh) * 1e3, 3),
+                   ttft_ms_sum=round(sum(
+                       max(now - r.submit_s, 0.0) for r in fresh) * 1e3,
+                       3))
         if decode_active:
             DECODE_STALL_SECONDS.observe(max(now - span.begin_s, 0.0))
         self._record_flight(
@@ -2978,6 +3032,12 @@ class InferenceEngine:
         return len(req.prompt) + len(req.tokens) >= self.max_len
 
     def _retire(self, req: Request) -> None:
+        with timeline.phase("engine.retire",
+                            prompt_tokens=len(req.prompt),
+                            tokens=len(req.tokens)):
+            self._retire_impl(req)
+
+    def _retire_impl(self, req: Request) -> None:
         # No cache-length scrub: ``insert`` stamps the slot's length on
         # reuse, decode's commit mask skips non-active slots, and a
         # dead slot's attention output is never read — an eager
@@ -3252,7 +3312,8 @@ class InferenceEngine:
             self.prefill_chunk_step()
         return self.decode_burst(max_burst)
 
-    def decode_burst(self, max_burst: int = 8) -> Dict[int, List[int]]:
+    def decode_burst(self, max_burst: int = 8, why: str = ""
+                     ) -> Dict[int, List[int]]:
         """Decode up to ``max_burst`` tokens per active slot in one
         device call — NO admission (callers that interleave admission
         and decode use :meth:`admit` + this).
@@ -3265,10 +3326,10 @@ class InferenceEngine:
         or out of row headroom — a tight slot alone just rides the
         verify burst with an empty draft)."""
         if self.spec_k:
-            out = self.spec_decode_burst()
+            out = self.spec_decode_burst(why)
             if out is not None:
                 return out
-        handle = self.dispatch_decode_burst(max_burst)
+        handle = self.dispatch_decode_burst(max_burst, why)
         if handle is None:
             return {}
         return self.complete_decode_burst(handle)
@@ -3323,7 +3384,8 @@ class InferenceEngine:
         req.drafter.catch_up(req.prompt, req.tokens)
         return req.drafter.draft(self.spec_k)
 
-    def spec_decode_burst(self) -> Optional[Dict[int, List[int]]]:
+    def spec_decode_burst(self, why: str = ""
+                          ) -> Optional[Dict[int, List[int]]]:
         """One draft-and-verify burst for every active slot: the host
         drafter proposes up to K tokens per slot, ONE compiled verify
         program scores the K+1 window positions, and the accepted run
@@ -3351,9 +3413,10 @@ class InferenceEngine:
         if not self.slot_req or K <= 0:
             return None
         with _dispatch_boundary("verify"):
-            return self._spec_decode_burst_impl()
+            return self._spec_decode_burst_impl(why)
 
-    def _spec_decode_burst_impl(self) -> Optional[Dict[int, List[int]]]:
+    def _spec_decode_burst_impl(self, why: str = ""
+                                ) -> Optional[Dict[int, List[int]]]:
         K = self.spec_k
         draft = np.zeros((self.n_slots + 1, K), np.int32)
         n_draft = np.zeros((self.n_slots + 1,), np.int32)
@@ -3413,6 +3476,7 @@ class InferenceEngine:
         parts = []
         part_spans: List[Optional[int]] = []
         part_keys: List[Optional[str]] = []
+        self._burst_seq += 1
         for attn_span, slots in groups:
             active = np.zeros((self.n_slots + 1,), bool)
             for s in slots:
@@ -3420,12 +3484,18 @@ class InferenceEngine:
             sarg = self._span_arg(attn_span)
             self.decode_programs.add(("verify", K, sarg))
             DECODE_ATTN_ROWS.observe(attn_span)
-            self.cache, toks_dev, commit_dev = self._verify_fn(
-                self.params, self.cache, jnp.asarray(draft),
-                jnp.asarray(n_draft), jnp.asarray(active),
-                self.table_device(), k=K, qweights=self.qweights,
-                span=sarg, kernel=self.kv_kernel,
-                **self._lora_args())
+            # A verify program computes K + 1 window positions a row.
+            with timeline.phase(
+                    "engine.decode.dispatch", seq=self._burst_seq,
+                    k=K + 1, slots=len(slots), rows=self.n_slots + 1,
+                    span=attn_span, why=why,
+                    waiting=len(self.waiting)):
+                self.cache, toks_dev, commit_dev = self._verify_fn(
+                    self.params, self.cache, jnp.asarray(draft),
+                    jnp.asarray(n_draft), jnp.asarray(active),
+                    self.table_device(), k=K, qweights=self.qweights,
+                    span=sarg, kernel=self.kv_kernel,
+                    **self._lora_args())
             parts.append((slots, toks_dev, commit_dev))
             part_spans.append(sarg)
             part_keys.append(self.compile_watch.last_key)
@@ -3460,69 +3530,75 @@ class InferenceEngine:
         # output (the next round's window input), so this is the one
         # deliberate sync of the spec path — same role as
         # complete_decode_burst's.
-        fetched = [(slots, np.asarray(t), np.asarray(c))
-                   for slots, t, c in parts]       # [B, K+1] / [B]
-        span.end()
-        end_s = time.time()
-        SPEC_VERIFY_WALL.inc(max(end_s - span.begin_s, 0.0))
-        out: Dict[int, List[int]] = {}
-        n_emitted = accepted = 0
-        model_drafted = ngram_drafted = 0
-        for part_i, ((slots, toks, n_commit), sarg) in enumerate(
-                zip(fetched, part_spans)):
-            grp_emitted = grp_drafted = grp_accepted = 0
-            grp_reqs: List[Request] = []
-            grp_kinds = set()
-            for slot in slots:
-                req = self.slot_req.get(slot)
-                if req is None or req.done:
-                    continue
-                nd = dlen.get(slot, 0)
-                nc = int(n_commit[slot])
-                emitted: List[int] = []
-                for i in range(nc):
-                    tok = int(toks[slot, i])
-                    emitted.append(tok)
-                    req.tokens.append(tok)
-                    if self._req_finished(req, tok):
-                        self._retire(req)
-                        break
-                # Accepted = matched draft tokens the request actually
-                # emitted: the first nc-1 outputs are the matched run,
-                # the nc-th the correction/bonus — an early EOS/budget
-                # retire discards the tail, and counting the full run
-                # would inflate the trailer stats and the acceptance
-                # gauge on EOS-heavy workloads.
-                acc = min(len(emitted), nc - 1)
-                req.spec_drafted += nd
-                req.spec_accepted += acc
-                req.spec_mode_drafted += nd
-                req.spec_mode_accepted += acc
-                if nd:
-                    if slot in model_reqs:
-                        model_drafted += nd
-                        grp_kinds.add("model")
-                    else:
-                        ngram_drafted += nd
-                        grp_kinds.add("ngram")
-                accepted += acc
-                out[req.rid] = emitted
-                n_emitted += len(emitted)
-                grp_emitted += len(emitted)
-                grp_drafted += nd
-                grp_accepted += acc
-                grp_reqs.append(req)
-            self._record_flight(
-                "verify", begin_s=span.begin_s, end_s=end_s,
-                program={"k": K, "span": sarg},
-                slots=slots, reqs=grp_reqs, toks=grp_emitted,
-                drafted=grp_drafted, accepted=grp_accepted,
-                drafter=("mixed" if len(grp_kinds) > 1
-                         else next(iter(grp_kinds), None)),
-                overlap_ms=round(overlap_s * 1e3, 3),
-                dispatch_s=dispatch_done_s,
-                dev_keys=[part_keys[part_i]] if part_i < len(part_keys)
-                else None)
+        n_done0 = len(self.finished)
+        with timeline.phase(
+                "engine.decode.fetch", seq=self._burst_seq, k=K + 1,
+                parts=len(parts), waiting=len(self.waiting)) as fetch_ph:
+            fetched = [(slots, np.asarray(t), np.asarray(c))
+                       for slots, t, c in parts]   # [B, K+1] / [B]
+            span.end()
+            end_s = time.time()
+            SPEC_VERIFY_WALL.inc(max(end_s - span.begin_s, 0.0))
+            out: Dict[int, List[int]] = {}
+            n_emitted = accepted = 0
+            model_drafted = ngram_drafted = 0
+            for part_i, ((slots, toks, n_commit), sarg) in enumerate(
+                    zip(fetched, part_spans)):
+                grp_emitted = grp_drafted = grp_accepted = 0
+                grp_reqs: List[Request] = []
+                grp_kinds = set()
+                for slot in slots:
+                    req = self.slot_req.get(slot)
+                    if req is None or req.done:
+                        continue
+                    nd = dlen.get(slot, 0)
+                    nc = int(n_commit[slot])
+                    emitted: List[int] = []
+                    for i in range(nc):
+                        tok = int(toks[slot, i])
+                        emitted.append(tok)
+                        req.tokens.append(tok)
+                        if self._req_finished(req, tok):
+                            self._retire(req)
+                            break
+                    # Accepted = matched draft tokens the request actually
+                    # emitted: the first nc-1 outputs are the matched run,
+                    # the nc-th the correction/bonus — an early EOS/budget
+                    # retire discards the tail, and counting the full run
+                    # would inflate the trailer stats and the acceptance
+                    # gauge on EOS-heavy workloads.
+                    acc = min(len(emitted), nc - 1)
+                    req.spec_drafted += nd
+                    req.spec_accepted += acc
+                    req.spec_mode_drafted += nd
+                    req.spec_mode_accepted += acc
+                    if nd:
+                        if slot in model_reqs:
+                            model_drafted += nd
+                            grp_kinds.add("model")
+                        else:
+                            ngram_drafted += nd
+                            grp_kinds.add("ngram")
+                    accepted += acc
+                    out[req.rid] = emitted
+                    n_emitted += len(emitted)
+                    grp_emitted += len(emitted)
+                    grp_drafted += nd
+                    grp_accepted += acc
+                    grp_reqs.append(req)
+                self._record_flight(
+                    "verify", begin_s=span.begin_s, end_s=end_s,
+                    program={"k": K, "span": sarg},
+                    slots=slots, reqs=grp_reqs, toks=grp_emitted,
+                    drafted=grp_drafted, accepted=grp_accepted,
+                    drafter=("mixed" if len(grp_kinds) > 1
+                             else next(iter(grp_kinds), None)),
+                    overlap_ms=round(overlap_s * 1e3, 3),
+                    dispatch_s=dispatch_done_s,
+                    dev_keys=[part_keys[part_i]] if part_i < len(part_keys)
+                    else None)
+            fetch_ph.set(tokens=n_emitted,
+                         retired=len(self.finished) - n_done0)
         if model_drafted:
             SPEC_DRAFT_TOKENS.labels(drafter="model").inc(model_drafted)
         if ngram_drafted:
@@ -3540,7 +3616,7 @@ class InferenceEngine:
             DECODE_TOKENS.inc(n_emitted)
         return out
 
-    def dispatch_decode_burst(self, max_burst: int = 8
+    def dispatch_decode_burst(self, max_burst: int = 8, why: str = ""
                               ) -> Optional["BurstHandle"]:
         """Enqueue one decode-burst program WITHOUT fetching its tokens;
         pass the handle to :meth:`complete_decode_burst` later.
@@ -3561,9 +3637,9 @@ class InferenceEngine:
         if not self.slot_req:
             return None
         with _dispatch_boundary("decode"):
-            return self._dispatch_decode_burst_impl(max_burst)
+            return self._dispatch_decode_burst_impl(max_burst, why)
 
-    def _dispatch_decode_burst_impl(self, max_burst: int
+    def _dispatch_decode_burst_impl(self, max_burst: int, why: str = ""
                                     ) -> Optional["BurstHandle"]:
         # Cap the burst so no active slot's cache can overflow (counting
         # dispatched-but-uncommitted tokens), then round down to a power
@@ -3594,6 +3670,7 @@ class InferenceEngine:
         parts: List[Tuple[jax.Array, List[int]]] = []
         part_spans: List[Optional[int]] = []
         part_keys: List[Optional[str]] = []
+        self._burst_seq += 1
         for attn_span, slots in groups:
             active = np.zeros((self.n_slots + 1,), bool)
             for s in slots:
@@ -3601,11 +3678,18 @@ class InferenceEngine:
             sarg = self._span_arg(attn_span)
             self.decode_programs.add(("burst", k, sarg))
             DECODE_ATTN_ROWS.observe(attn_span)
-            self.cache, self.rng, toks = self._decode_burst_fn(
-                self.params, self.cache, self.rng, jnp.asarray(active),
-                self.table_device(), k=k, qweights=self.qweights,
-                span=sarg, kernel=self.kv_kernel,
-                **self._lora_args())
+            # One annotation per PROGRAM launched: its k steps run at
+            # ``rows`` batch rows of which ``slots`` are live.
+            with timeline.phase(
+                    "engine.decode.dispatch", seq=self._burst_seq, k=k,
+                    slots=len(slots), rows=self.n_slots + 1,
+                    span=attn_span, why=why,
+                    waiting=len(self.waiting)):
+                self.cache, self.rng, toks = self._decode_burst_fn(
+                    self.params, self.cache, self.rng,
+                    jnp.asarray(active), self.table_device(), k=k,
+                    qweights=self.qweights, span=sarg,
+                    kernel=self.kv_kernel, **self._lora_args())
             parts.append((toks, slots))
             part_spans.append(sarg)
             part_keys.append(self.compile_watch.last_key)
@@ -3613,7 +3697,8 @@ class InferenceEngine:
         return BurstHandle(parts=parts, k=k,
                            slot_req=dict(self.slot_req), span=ev,
                            spans=part_spans, keys=part_keys,
-                           dispatch_done_s=time.time())
+                           dispatch_done_s=time.time(),
+                           seq=self._burst_seq)
 
     def complete_decode_burst(self, handle: "BurstHandle"
                               ) -> Dict[int, List[int]]:
@@ -3628,10 +3713,27 @@ class InferenceEngine:
 
     def _complete_decode_burst_impl(self, handle: "BurstHandle"
                                     ) -> Dict[int, List[int]]:
-        fetched = [(np.asarray(toks_dev), slots)
-                   for toks_dev, slots in handle.parts]
-        if handle.span is not None:
-            handle.span.end()
+        with timeline.phase("engine.decode.fetch", seq=handle.seq,
+                            k=handle.k, parts=len(handle.parts),
+                            waiting=len(self.waiting)) as ph:
+            fetched = [(np.asarray(toks_dev), slots)
+                       for toks_dev, slots in handle.parts]
+            if handle.span is not None:
+                handle.span.end()
+            before = len(self.finished)
+            with timeline.phase("engine.decode.commit"):
+                out, n_emitted = self._commit_burst(handle, fetched)
+            ph.set(tokens=n_emitted,
+                   retired=len(self.finished) - before)
+        if n_emitted:
+            DECODE_TOKENS.inc(n_emitted)
+        return out
+
+    def _commit_burst(self, handle: "BurstHandle", fetched
+                      ) -> Tuple[Dict[int, List[int]], int]:
+        """Host bookkeeping of a fetched burst: append / retire per
+        request and write the flight records. Returns ({rid: tokens},
+        tokens emitted and kept)."""
         end_s = time.time()
         begin_s = (handle.span.begin_s if handle.span is not None
                    else end_s)
@@ -3668,9 +3770,7 @@ class InferenceEngine:
                 dispatch_s=handle.dispatch_done_s,
                 dev_keys=([handle.keys[part_i]]
                           if part_i < len(handle.keys) else None))
-        if n_emitted:
-            DECODE_TOKENS.inc(n_emitted)
-        return out
+        return out, n_emitted
 
     def step_decode_once(self) -> Dict[int, int]:
         """One single-token decode for all active slots (no admission).
@@ -3680,12 +3780,13 @@ class InferenceEngine:
         if not self.slot_req:
             return {}
         active = np.zeros((self.n_slots + 1,), bool)
-        rows_max = 0
+        rows_max = n_live = 0
         for s, req in self.slot_req.items():
             if not self._ensure_headroom(s, req,
                                          self._slot_rows(req) + 1):
                 continue            # lazy: pool dry — sits this out
             active[s] = True
+            n_live += 1
             rows_max = max(rows_max, self._slot_rows(req))
         if not rows_max:
             # Lazy mode only (eager slots always have headroom): the
@@ -3702,27 +3803,39 @@ class InferenceEngine:
         ev = timeline.Event("skytpu_decode_step_seconds",
                             histogram=DECODE_STEP_SECONDS)
         ev.begin()
-        self.cache, self.rng, toks = self._decode_fn(
-            self.params, self.cache, self.rng, jnp.asarray(active),
-            self.table_device(), qweights=self.qweights, span=sarg,
-            **self._lora_args())
+        self._burst_seq += 1
+        with timeline.phase(
+                "engine.decode.dispatch", seq=self._burst_seq, k=1,
+                slots=n_live, rows=self.n_slots + 1,
+                span=self._span_for(rows_max), why="step",
+                waiting=len(self.waiting)):
+            self.cache, self.rng, toks = self._decode_fn(
+                self.params, self.cache, self.rng, jnp.asarray(active),
+                self.table_device(), qweights=self.qweights, span=sarg,
+                **self._lora_args())
         t_disp = time.time()
         step_key = self.compile_watch.last_key
-        toks = np.asarray(toks)
-        ev.end()
-        out: Dict[int, int] = {}
-        step_slots: List[int] = []
-        step_reqs: List[Request] = []
-        for slot, req in list(self.slot_req.items()):
-            if not active[slot]:
-                continue
-            tok = int(toks[slot])
-            req.tokens.append(tok)
-            out[req.rid] = tok
-            step_slots.append(slot)
-            step_reqs.append(req)
-            if self._req_finished(req, tok):
-                self._retire(req)
+        n_done0 = len(self.finished)
+        with timeline.phase(
+                "engine.decode.fetch", seq=self._burst_seq, k=1,
+                parts=1, waiting=len(self.waiting)) as fetch_ph:
+            toks = np.asarray(toks)
+            ev.end()
+            out: Dict[int, int] = {}
+            step_slots: List[int] = []
+            step_reqs: List[Request] = []
+            for slot, req in list(self.slot_req.items()):
+                if not active[slot]:
+                    continue
+                tok = int(toks[slot])
+                req.tokens.append(tok)
+                out[req.rid] = tok
+                step_slots.append(slot)
+                step_reqs.append(req)
+                if self._req_finished(req, tok):
+                    self._retire(req)
+            fetch_ph.set(tokens=len(out),
+                         retired=len(self.finished) - n_done0)
         DECODE_TOKENS.inc(len(out))
         self._record_flight(
             "decode1", begin_s=ev.begin_s, end_s=time.time(),

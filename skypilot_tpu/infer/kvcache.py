@@ -498,6 +498,7 @@ def _phys(cache: Cache, table, slots, idx):
     return table[slots, idx // bl], idx % bl
 
 
+@jax.named_scope("kv_gather")
 def _gather_kv_layer(cache: Cache, i, table, span=None):
     """Layer ``i``'s K/V (+ scales when int8) arranged per slot:
     k/v [B, M, G, hd], scales [B, G, M]. Contiguous reads the
@@ -546,6 +547,7 @@ def _gather_kv_layer(cache: Cache, i, table, span=None):
     return ck, cv, cks, cvs
 
 
+@jax.named_scope("kv_gather")
 def _gather_slot_kv_layer(cache: Cache, i, slot, table, span=None):
     """One slot's rows for layer ``i``: k/v [M, G, hd], scales [G, M]
     (the prefill_chunk read path). ``span``: first ``span`` logical
@@ -587,6 +589,7 @@ def _gather_slot_kv_layer(cache: Cache, i, slot, table, span=None):
     return ck, cv, cks, cvs
 
 
+@jax.named_scope("kv_gather")
 def _paged_attn_stats(cache: Cache, i, table, qh, lengths, span):
     """Big-cache attention stats via the Pallas paged-attention kernel
     (``SKYTPU_KV_KERNEL=1``): per (slot, kv-head) the kernel walks the
@@ -675,49 +678,54 @@ def prefill_batch(params: llama.Params, tokens: jax.Array,
         x = carry
         layer, qlayer, llayer = _layer_parts(layer_q, wq8,
                                              lora is not None)
-        h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
-        q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
-        k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
-        v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
-        if llayer is not None:
-            q = q + _lora_in_delta(h, llayer["wq"], aid)
-            k = k + _lora_in_delta(h, llayer["wk"], aid)
-            v = v + _lora_in_delta(h, llayer["wv"], aid)
-        q = llama.apply_rope(q, cos, sin)
-        k = llama.apply_rope(k, cos, sin)
-        o = ra.local_attention(q, k, v, mesh, causal=True,
-                               batch_axes=None, heads_axis=heads_axis)
-        y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
-        if llayer is not None:
-            y = y + _lora_out_delta(o, llayer["wo"], aid)
-        x = x + y
-        h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
-        if wq8 and not hasattr(cfg, "n_experts"):
-            g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1,
-                     cfg.dtype)
-            u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1,
-                     cfg.dtype)
-            x = x + proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer,
-                         qlayer, "w_down", 1, cfg.dtype)
-        else:
-            x = x + _ffn(cfg, h, layer)
+        with jax.named_scope("qkv_proj"):
+            h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
+            q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
+            k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
+            v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
+            if llayer is not None:
+                q = q + _lora_in_delta(h, llayer["wq"], aid)
+                k = k + _lora_in_delta(h, llayer["wk"], aid)
+                v = v + _lora_in_delta(h, llayer["wv"], aid)
+            q = llama.apply_rope(q, cos, sin)
+            k = llama.apply_rope(k, cos, sin)
+        with jax.named_scope("attn_core"):
+            o = ra.local_attention(q, k, v, mesh, causal=True,
+                                   batch_axes=None, heads_axis=heads_axis)
+        with jax.named_scope("out_ffn"):
+            y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
+            if llayer is not None:
+                y = y + _lora_out_delta(o, llayer["wo"], aid)
+            x = x + y
+            h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
+            if wq8 and not hasattr(cfg, "n_experts"):
+                g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1,
+                         cfg.dtype)
+                u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1,
+                         cfg.dtype)
+                x = x + proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer,
+                             qlayer, "w_down", 1, cfg.dtype)
+            else:
+                x = x + _ffn(cfg, h, layer)
         return x, (k, v)
 
     xs = _scan_xs(params, qweights, lora)
     x, (ks, vs) = lax.scan(body, x, xs)        # ks: [L, W, S, G, hd]
-    x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    last = jnp.take_along_axis(
-        x, (true_lens - 1)[:, None, None], axis=1)[:, 0]       # [W, D]
-    if wq8:
-        logits = qeinsum("wd,dv->wv", last, qweights["head"], 1,
-                         jnp.float32)
-    else:
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = (last @ head.astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        last = jnp.take_along_axis(
+            x, (true_lens - 1)[:, None, None], axis=1)[:, 0]       # [W, D]
+        if wq8:
+            logits = qeinsum("wd,dv->wv", last, qweights["head"], 1,
+                             jnp.float32)
+        else:
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = (last @ head.astype(cfg.dtype)).astype(jnp.float32)
     return {"k": ks, "v": vs}, logits
 
 
+@jax.named_scope("kv_write")
 def insert(cache: Cache, prefix: Cache, slot: jax.Array,
            true_len: jax.Array, first_token: jax.Array,
            table=None) -> Cache:
@@ -981,101 +989,106 @@ def prefill_chunk(params: llama.Params, cache: Cache,
         x, i = carry
         layer, qlayer, llayer = _layer_parts(layer_q, wq8,
                                              lora is not None)
-        h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
-        q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
-        k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
-        v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
-        if llayer is not None:
-            q = q + _lora_in_delta(h, llayer["wq"], aid_b)
-            k = k + _lora_in_delta(h, llayer["wk"], aid_b)
-            v = v + _lora_in_delta(h, llayer["wv"], aid_b)
-        q = llama.apply_rope(q, cos, sin)
-        k = llama.apply_rope(k, cos, sin)
-        kr, vr = k[0], v[0]                       # [C, G, hd]
-        if quant:
-            kq, ksc = quantize_rows(kr)
-            vq, vsc = quantize_rows(vr)
-            ys = (kq, vq, ksc.astype(sdt), vsc.astype(sdt))
-        else:
-            ys = (kr.astype(kdt), vr.astype(kdt))
-        # bf16 dots, fp32 accumulation — int8 converts to bf16 exactly
-        # (see decode_step's note).
-        qh = q[0].reshape(C, G, rep, hd).astype(jnp.bfloat16)
-        ss = jnp.einsum("cgrk,jgk->cgrj", qh, kr.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32) * scale
-        ss = jnp.where(intra_mask[:, None, None, :], ss, neg)
-        if kv_kernel and table is not None:
-            # Kernel big-cache block over THIS slot's table row: the
-            # chunk's C * rep query rows batch into one (slot,
-            # kv-head) grid cell each; the mask bound is ``start``
-            # (rows below this chunk are the resident prefix).
-            q_k = qh.transpose(1, 0, 2, 3).reshape(1, G, C * rep, hd)
-            acc, m, l = _paged_attn_stats(
-                cache, i, lax.dynamic_slice_in_dim(table, slot, 1, 0),
-                q_k, jnp.reshape(start, (1,)), span)
-            acc = acc.reshape(G, C, rep, hd).transpose(1, 0, 2, 3)
-            m = m.reshape(G, C, rep).transpose(1, 0, 2)
-            l = l.reshape(G, C, rep).transpose(1, 0, 2)
-            alpha, w_s, l_tot = _merge_attn_parts(acc, m, l, ss)
-            o = acc * alpha[..., None] + jnp.einsum(
-                "cgrj,jgk->cgrk", w_s.astype(jnp.bfloat16),
-                vr.astype(jnp.bfloat16),
-                preferred_element_type=jnp.float32)
-            o = o / l_tot[..., None]
-        else:
-            ck, cv, cks, cvs = _gather_slot_kv_layer(cache, i, slot,
-                                                     table, span)
-            sm = jnp.einsum("cgrk,mgk->cgrm", qh,
-                            ck.astype(jnp.bfloat16),
+        with jax.named_scope("qkv_proj"):
+            h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
+            q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
+            k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
+            v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
+            if llayer is not None:
+                q = q + _lora_in_delta(h, llayer["wq"], aid_b)
+                k = k + _lora_in_delta(h, llayer["wk"], aid_b)
+                v = v + _lora_in_delta(h, llayer["wv"], aid_b)
+            q = llama.apply_rope(q, cos, sin)
+            k = llama.apply_rope(k, cos, sin)
+        with jax.named_scope("attn_core"):
+            kr, vr = k[0], v[0]                       # [C, G, hd]
+            if quant:
+                kq, ksc = quantize_rows(kr)
+                vq, vsc = quantize_rows(vr)
+                ys = (kq, vq, ksc.astype(sdt), vsc.astype(sdt))
+            else:
+                ys = (kr.astype(kdt), vr.astype(kdt))
+            # bf16 dots, fp32 accumulation — int8 converts to bf16 exactly
+            # (see decode_step's note).
+            qh = q[0].reshape(C, G, rep, hd).astype(jnp.bfloat16)
+            ss = jnp.einsum("cgrk,jgk->cgrj", qh, kr.astype(jnp.bfloat16),
                             preferred_element_type=jnp.float32) * scale
-            if quant:
-                sm = sm * cks[None, :, None, :]
-            sm = jnp.where(col[None, None, None, :] < start, sm, neg)
-            w = jax.nn.softmax(jnp.concatenate([sm, ss], axis=-1),
-                               axis=-1)
-            wm, ws = w[..., :M], w[..., M:]
-            if quant:
-                wm = wm * cvs[None, :, None, :]
-            o = jnp.einsum("cgrm,mgk->cgrk", wm.astype(jnp.bfloat16),
-                           cv.astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32)
-            o = o + jnp.einsum("cgrj,jgk->cgrk",
-                               ws.astype(jnp.bfloat16),
-                               vr.astype(jnp.bfloat16),
+            ss = jnp.where(intra_mask[:, None, None, :], ss, neg)
+            if kv_kernel and table is not None:
+                # Kernel big-cache block over THIS slot's table row: the
+                # chunk's C * rep query rows batch into one (slot,
+                # kv-head) grid cell each; the mask bound is ``start``
+                # (rows below this chunk are the resident prefix).
+                q_k = qh.transpose(1, 0, 2, 3).reshape(1, G, C * rep, hd)
+                acc, m, l = _paged_attn_stats(
+                    cache, i, lax.dynamic_slice_in_dim(table, slot, 1, 0),
+                    q_k, jnp.reshape(start, (1,)), span)
+                acc = acc.reshape(G, C, rep, hd).transpose(1, 0, 2, 3)
+                m = m.reshape(G, C, rep).transpose(1, 0, 2)
+                l = l.reshape(G, C, rep).transpose(1, 0, 2)
+                alpha, w_s, l_tot = _merge_attn_parts(acc, m, l, ss)
+                o = acc * alpha[..., None] + jnp.einsum(
+                    "cgrj,jgk->cgrk", w_s.astype(jnp.bfloat16),
+                    vr.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+                o = o / l_tot[..., None]
+            else:
+                ck, cv, cks, cvs = _gather_slot_kv_layer(cache, i, slot,
+                                                         table, span)
+                sm = jnp.einsum("cgrk,mgk->cgrm", qh,
+                                ck.astype(jnp.bfloat16),
+                                preferred_element_type=jnp.float32) * scale
+                if quant:
+                    sm = sm * cks[None, :, None, :]
+                sm = jnp.where(col[None, None, None, :] < start, sm, neg)
+                w = jax.nn.softmax(jnp.concatenate([sm, ss], axis=-1),
+                                   axis=-1)
+                wm, ws = w[..., :M], w[..., M:]
+                if quant:
+                    wm = wm * cvs[None, :, None, :]
+                o = jnp.einsum("cgrm,mgk->cgrk", wm.astype(jnp.bfloat16),
+                               cv.astype(jnp.bfloat16),
                                preferred_element_type=jnp.float32)
-        o = o.reshape(1, C, cfg.n_heads, hd).astype(cfg.dtype)
-        y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
-        if llayer is not None:
-            y = y + _lora_out_delta(o, llayer["wo"], aid_b)
-        x = x + y
-        h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
-        if wq8 and not hasattr(cfg, "n_experts"):
-            g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1,
-                     cfg.dtype)
-            u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1,
-                     cfg.dtype)
-            x = x + proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer,
-                         qlayer, "w_down", 1, cfg.dtype)
-        else:
-            x = x + _ffn(cfg, h, layer)
+                o = o + jnp.einsum("cgrj,jgk->cgrk",
+                                   ws.astype(jnp.bfloat16),
+                                   vr.astype(jnp.bfloat16),
+                                   preferred_element_type=jnp.float32)
+            o = o.reshape(1, C, cfg.n_heads, hd).astype(cfg.dtype)
+        with jax.named_scope("out_ffn"):
+            y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
+            if llayer is not None:
+                y = y + _lora_out_delta(o, llayer["wo"], aid_b)
+            x = x + y
+            h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
+            if wq8 and not hasattr(cfg, "n_experts"):
+                g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1,
+                         cfg.dtype)
+                u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1,
+                         cfg.dtype)
+                x = x + proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer,
+                             qlayer, "w_down", 1, cfg.dtype)
+            else:
+                x = x + _ffn(cfg, h, layer)
         return (x, i + 1), ys
 
     xs = _scan_xs(params, qweights, lora)
     (x, _), ys = lax.scan(body, (x, jnp.int32(0)), xs)
 
     if final:
-        x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        last = lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
-                                        keepdims=False)      # [D]
-        if wq8:
-            logits = qeinsum("d,dv->v", last, qweights["head"], 1,
-                             jnp.float32)
-        else:
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = (last @ head.astype(cfg.dtype)).astype(jnp.float32)
-        rng, sub = jax.random.split(rng)
-        tok = sampling_mod.sample(logits, sub, sp)
+        with jax.named_scope("lm_head"):
+            x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
+            last = lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
+                                            keepdims=False)      # [D]
+            if wq8:
+                logits = qeinsum("d,dv->v", last, qweights["head"], 1,
+                                 jnp.float32)
+            else:
+                head = (params["embed"].T if cfg.tie_embeddings
+                        else params["lm_head"])
+                logits = (last @ head.astype(cfg.dtype)).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            rng, sub = jax.random.split(rng)
+            tok = sampling_mod.sample(logits, sub, sp)
     else:
         tok = jnp.zeros((), jnp.int32)
 
@@ -1084,26 +1097,27 @@ def prefill_chunk(params: llama.Params, cache: Cache,
     # past max_len, and scatter DROPS out-of-bounds indices instead of
     # clamping the whole window backwards over valid rows (paged: the
     # overflow maps to the sentinel block, dropped the same way).
-    idx = start + jnp.arange(C)
-    blk, off = _phys(cache, table, slot, idx)
-    out = dict(cache)
-    if quant:
-        kq_l, vq_l, ks_l, vs_l = ys       # [L,C,G,hd] / [L,C,G]
-        out["k"] = cache["k"].at[:, blk, off].set(kq_l)
-        out["v"] = cache["v"].at[:, blk, off].set(vq_l)
-        # Non-adjacent advanced indices put the broadcast dim first:
-        # update shape is [C, L, G].
-        out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
-            ks_l.transpose(1, 0, 2))
-        out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
-            vs_l.transpose(1, 0, 2))
-    else:
-        k_l, v_l = ys
-        out["k"] = cache["k"].at[:, blk, off].set(k_l)
-        out["v"] = cache["v"].at[:, blk, off].set(v_l)
-    out["length"] = cache["length"].at[slot].set(new_len)
-    if final:
-        out["last_token"] = cache["last_token"].at[slot].set(tok)
+    with jax.named_scope("kv_write"):
+        idx = start + jnp.arange(C)
+        blk, off = _phys(cache, table, slot, idx)
+        out = dict(cache)
+        if quant:
+            kq_l, vq_l, ks_l, vs_l = ys       # [L,C,G,hd] / [L,C,G]
+            out["k"] = cache["k"].at[:, blk, off].set(kq_l)
+            out["v"] = cache["v"].at[:, blk, off].set(vq_l)
+            # Non-adjacent advanced indices put the broadcast dim first:
+            # update shape is [C, L, G].
+            out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
+                ks_l.transpose(1, 0, 2))
+            out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
+                vs_l.transpose(1, 0, 2))
+        else:
+            k_l, v_l = ys
+            out["k"] = cache["k"].at[:, blk, off].set(k_l)
+            out["v"] = cache["v"].at[:, blk, off].set(v_l)
+        out["length"] = cache["length"].at[slot].set(new_len)
+        if final:
+            out["last_token"] = cache["last_token"].at[slot].set(tok)
     return out, rng, tok
 
 
@@ -1111,6 +1125,7 @@ def prefill_chunk(params: llama.Params, cache: Cache,
 # Decode
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("qkv_proj")
 def _decode_qkv(cfg, layer, qlayer, x, cos, sin, llayer=None,
                 aid=None):
     """Shared decode-layer front half: norm + q/k/v projections + rope
@@ -1132,6 +1147,7 @@ def _decode_qkv(cfg, layer, qlayer, x, cos, sin, llayer=None,
     return q, k, v
 
 
+@jax.named_scope("out_ffn")
 def _decode_out_ffn(cfg, layer, qlayer, wq8, x, o, llayer=None,
                     aid=None):
     """Shared decode-layer back half: output projection + residual +
@@ -1154,6 +1170,7 @@ def _decode_out_ffn(cfg, layer, qlayer, wq8, x, o, llayer=None,
     return x + _ffn(cfg, h, layer)
 
 
+@jax.named_scope("lm_head")
 def _decode_head(cfg, params, qweights, x):
     """Shared final-norm + LM head (fp or w8a8)."""
     x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -1229,47 +1246,48 @@ def decode_step(params: llama.Params, cache: Cache,
                                              lora is not None)
         q, k, v = _decode_qkv(cfg, layer, qlayer, x, cos, sin,
                               llayer, aid)
-        if quant:
-            kq, ks = quantize_rows(k[:, 0])     # ks/vs: [B, G]
-            vq, vs = quantize_rows(v[:, 0])
-            ks, vs = ks.astype(sdt), vs.astype(sdt)
-            k_new = kq.astype(jnp.bfloat16)     # exact: int8 fits bf16
-            v_new = vq.astype(jnp.float32) * vs.astype(jnp.float32)[..., None]
-            ys = (kq, vq, ks, vs)
-        else:
-            kq, vq = k[:, 0], v[:, 0]
-            ks = vs = None
-            k_new = kq.astype(jnp.bfloat16)
-            v_new = vq.astype(jnp.float32)
-            ys = (kq, vq)
-        ck, cv, cks, cvs = _gather_kv_layer(cache, i, table, span)
-        # The attention dots run in bf16 with fp32 ACCUMULATION. The
-        # int8 cache converts to bf16 EXACTLY (integers <= 127 carry no
-        # rounding in an 8-bit mantissa) and each bf16xbf16 product is
-        # exact in the fp32 accumulator, so the scores match a full
-        # fp32 dot while the materialized cache-sized intermediate is
-        # half the size. Per-row scales stay linear in the contraction:
-        # K's scale applies to the SCORES and V's folds into the
-        # softmax weights — nothing dequantized at cache shape ever
-        # hits fp32.
-        qh = q[:, 0].reshape(B, G, rep, hd).astype(jnp.bfloat16)
-        s = jnp.einsum("bgrk,bmgk->bgrm", qh, ck.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32) * scale
-        s_self = jnp.einsum("bgrk,bgk->bgr", qh, k_new,
-                            preferred_element_type=jnp.float32) * scale
-        if quant:
-            s = s * cks[:, :, None, :]
-            s_self = s_self * ks.astype(jnp.float32)[:, :, None]
-        s = jnp.where(valid[:, None, None, :], s, neg)
-        w = jax.nn.softmax(jnp.concatenate([s, s_self[..., None]], -1),
-                           axis=-1)
-        wm, w_self = w[..., :M], w[..., M]
-        if quant:
-            wm = wm * cvs[:, :, None, :]
-        o = jnp.einsum("bgrm,bmgk->bgrk", wm.astype(jnp.bfloat16),
-                       cv.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
-        o = o + w_self[..., None] * v_new[:, :, None, :]
+        with jax.named_scope("attn_core"):
+            if quant:
+                kq, ks = quantize_rows(k[:, 0])     # ks/vs: [B, G]
+                vq, vs = quantize_rows(v[:, 0])
+                ks, vs = ks.astype(sdt), vs.astype(sdt)
+                k_new = kq.astype(jnp.bfloat16)     # exact: int8 fits bf16
+                v_new = vq.astype(jnp.float32) * vs.astype(jnp.float32)[..., None]
+                ys = (kq, vq, ks, vs)
+            else:
+                kq, vq = k[:, 0], v[:, 0]
+                ks = vs = None
+                k_new = kq.astype(jnp.bfloat16)
+                v_new = vq.astype(jnp.float32)
+                ys = (kq, vq)
+            ck, cv, cks, cvs = _gather_kv_layer(cache, i, table, span)
+            # The attention dots run in bf16 with fp32 ACCUMULATION. The
+            # int8 cache converts to bf16 EXACTLY (integers <= 127 carry no
+            # rounding in an 8-bit mantissa) and each bf16xbf16 product is
+            # exact in the fp32 accumulator, so the scores match a full
+            # fp32 dot while the materialized cache-sized intermediate is
+            # half the size. Per-row scales stay linear in the contraction:
+            # K's scale applies to the SCORES and V's folds into the
+            # softmax weights — nothing dequantized at cache shape ever
+            # hits fp32.
+            qh = q[:, 0].reshape(B, G, rep, hd).astype(jnp.bfloat16)
+            s = jnp.einsum("bgrk,bmgk->bgrm", qh, ck.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32) * scale
+            s_self = jnp.einsum("bgrk,bgk->bgr", qh, k_new,
+                                preferred_element_type=jnp.float32) * scale
+            if quant:
+                s = s * cks[:, :, None, :]
+                s_self = s_self * ks.astype(jnp.float32)[:, :, None]
+            s = jnp.where(valid[:, None, None, :], s, neg)
+            w = jax.nn.softmax(jnp.concatenate([s, s_self[..., None]], -1),
+                               axis=-1)
+            wm, w_self = w[..., :M], w[..., M]
+            if quant:
+                wm = wm * cvs[:, :, None, :]
+            o = jnp.einsum("bgrm,bmgk->bgrk", wm.astype(jnp.bfloat16),
+                           cv.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
+            o = o + w_self[..., None] * v_new[:, :, None, :]
         x = _decode_out_ffn(cfg, layer, qlayer, wq8, x, o, llayer, aid)
         return (x, i + 1), ys
 
@@ -1280,25 +1298,27 @@ def decode_step(params: llama.Params, cache: Cache,
     # lands at logical [l, b, pos[b]] (the ys stacks are megabyte-scale
     # next to the gigabyte-scale cache, and the donated cache aliases
     # through).
-    blk, off = _phys(cache, table, batch_ix, pos)
-    out = dict(cache)
-    if quant:
-        kq_l, vq_l, ks_l, vs_l = ys           # [L,B,G,hd] / [L,B,G]
-        out["k"] = cache["k"].at[:, blk, off].set(kq_l)
-        out["v"] = cache["v"].at[:, blk, off].set(vq_l)
-        # Non-adjacent advanced indices put the broadcast dim first:
-        # update shape is [B, L, G].
-        out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
-            ks_l.transpose(1, 0, 2))
-        out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
-            vs_l.transpose(1, 0, 2))
-    else:
-        k_l, v_l = ys
-        out["k"] = cache["k"].at[:, blk, off].set(k_l)
-        out["v"] = cache["v"].at[:, blk, off].set(v_l)
+    with jax.named_scope("kv_write"):
+        blk, off = _phys(cache, table, batch_ix, pos)
+        out = dict(cache)
+        if quant:
+            kq_l, vq_l, ks_l, vs_l = ys           # [L,B,G,hd] / [L,B,G]
+            out["k"] = cache["k"].at[:, blk, off].set(kq_l)
+            out["v"] = cache["v"].at[:, blk, off].set(vq_l)
+            # Non-adjacent advanced indices put the broadcast dim first:
+            # update shape is [B, L, G].
+            out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
+                ks_l.transpose(1, 0, 2))
+            out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
+                vs_l.transpose(1, 0, 2))
+        else:
+            k_l, v_l = ys
+            out["k"] = cache["k"].at[:, blk, off].set(k_l)
+            out["v"] = cache["v"].at[:, blk, off].set(v_l)
     return out, logits
 
 
+@jax.named_scope("kv_write")
 def commit_tokens(cache: Cache, tokens: jax.Array,
                   active: jax.Array) -> Cache:
     """Append sampled tokens on active slots: bump lengths, set last."""
@@ -1348,65 +1368,67 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
 
     q, kk, v = _decode_qkv(cfg, layer, qlayer, x, cos, sin, llayer,
                            aid)
-    if quant:
-        kq, ksc = quantize_rows(kk[:, 0])
-        vq, vsc = quantize_rows(v[:, 0])
-        ksc, vsc = ksc.astype(sdt), vsc.astype(sdt)
-        sk = sk.at[i, batch_ix, s].set(kq)
-        sv = sv.at[i, batch_ix, s].set(vq)
-        sks = sks.at[i, batch_ix, s].set(ksc)
-        svs = svs.at[i, batch_ix, s].set(vsc)
-    else:
-        sk = sk.at[i, batch_ix, s].set(kk[:, 0].astype(kdt))
-        sv = sv.at[i, batch_ix, s].set(v[:, 0].astype(kdt))
-    lk = lax.dynamic_index_in_dim(sk, i, 0, False)
-    lv = lax.dynamic_index_in_dim(sv, i, 0, False)
-    # bf16 dots, fp32 accumulation — int8 converts to bf16 exactly
-    # (see decode_step's note).
-    qh = q[:, 0].reshape(B, G, rep, hd).astype(jnp.bfloat16)
-    ss = jnp.einsum("bgrk,bjgk->bgrj", qh,
-                    lk.astype(jnp.bfloat16),
-                    preferred_element_type=jnp.float32) * scale
-    lvs = None
-    if quant:
-        lks = lax.dynamic_index_in_dim(sks, i, 0, False)
-        lvs = lax.dynamic_index_in_dim(svs, i, 0, False)
-        ss = ss * lks.transpose(0, 2, 1)[:, :, None, :]
-    ss = jnp.where(stage_valid[:, None, None, :], ss, neg)
-    if kv_kernel and table is not None:
-        acc, m, l = _paged_attn_stats(cache, i, table, qh, pos0, span)
-        alpha, w_s, l_tot = _merge_attn_parts(acc, m, l, ss)
+    with jax.named_scope("attn_core"):
         if quant:
-            w_s = w_s * lvs.transpose(0, 2, 1)[:, :, None, :]
-        o = acc * alpha[..., None] + jnp.einsum(
-            "bgrj,bjgk->bgrk", w_s.astype(jnp.bfloat16),
-            lv.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32)
-        o = o / l_tot[..., None]
-    else:
-        ck, cv, cks, cvs = _gather_kv_layer(cache, i, table, span)
-        sm = jnp.einsum("bgrk,bmgk->bgrm", qh,
-                        ck.astype(jnp.bfloat16),
+            kq, ksc = quantize_rows(kk[:, 0])
+            vq, vsc = quantize_rows(v[:, 0])
+            ksc, vsc = ksc.astype(sdt), vsc.astype(sdt)
+            sk = sk.at[i, batch_ix, s].set(kq)
+            sv = sv.at[i, batch_ix, s].set(vq)
+            sks = sks.at[i, batch_ix, s].set(ksc)
+            svs = svs.at[i, batch_ix, s].set(vsc)
+        else:
+            sk = sk.at[i, batch_ix, s].set(kk[:, 0].astype(kdt))
+            sv = sv.at[i, batch_ix, s].set(v[:, 0].astype(kdt))
+        lk = lax.dynamic_index_in_dim(sk, i, 0, False)
+        lv = lax.dynamic_index_in_dim(sv, i, 0, False)
+        # bf16 dots, fp32 accumulation — int8 converts to bf16 exactly
+        # (see decode_step's note).
+        qh = q[:, 0].reshape(B, G, rep, hd).astype(jnp.bfloat16)
+        ss = jnp.einsum("bgrk,bjgk->bgrj", qh,
+                        lk.astype(jnp.bfloat16),
                         preferred_element_type=jnp.float32) * scale
+        lvs = None
         if quant:
-            sm = sm * cks[:, :, None, :]
-        sm = jnp.where(valid_cache[:, None, None, :], sm, neg)
-        w = jax.nn.softmax(jnp.concatenate([sm, ss], axis=-1), axis=-1)
-        wm, ws = w[..., :M], w[..., M:]
-        if quant:
-            wm = wm * cvs[:, :, None, :]
-            ws = ws * lvs.transpose(0, 2, 1)[:, :, None, :]
-        o = jnp.einsum("bgrm,bmgk->bgrk", wm.astype(jnp.bfloat16),
-                       cv.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
-        o = o + jnp.einsum("bgrj,bjgk->bgrk",
-                           ws.astype(jnp.bfloat16),
-                           lv.astype(jnp.bfloat16),
+            lks = lax.dynamic_index_in_dim(sks, i, 0, False)
+            lvs = lax.dynamic_index_in_dim(svs, i, 0, False)
+            ss = ss * lks.transpose(0, 2, 1)[:, :, None, :]
+        ss = jnp.where(stage_valid[:, None, None, :], ss, neg)
+        if kv_kernel and table is not None:
+            acc, m, l = _paged_attn_stats(cache, i, table, qh, pos0, span)
+            alpha, w_s, l_tot = _merge_attn_parts(acc, m, l, ss)
+            if quant:
+                w_s = w_s * lvs.transpose(0, 2, 1)[:, :, None, :]
+            o = acc * alpha[..., None] + jnp.einsum(
+                "bgrj,bjgk->bgrk", w_s.astype(jnp.bfloat16),
+                lv.astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32)
+            o = o / l_tot[..., None]
+        else:
+            ck, cv, cks, cvs = _gather_kv_layer(cache, i, table, span)
+            sm = jnp.einsum("bgrk,bmgk->bgrm", qh,
+                            ck.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32) * scale
+            if quant:
+                sm = sm * cks[:, :, None, :]
+            sm = jnp.where(valid_cache[:, None, None, :], sm, neg)
+            w = jax.nn.softmax(jnp.concatenate([sm, ss], axis=-1), axis=-1)
+            wm, ws = w[..., :M], w[..., M:]
+            if quant:
+                wm = wm * cvs[:, :, None, :]
+                ws = ws * lvs.transpose(0, 2, 1)[:, :, None, :]
+            o = jnp.einsum("bgrm,bmgk->bgrk", wm.astype(jnp.bfloat16),
+                           cv.astype(jnp.bfloat16),
                            preferred_element_type=jnp.float32)
+            o = o + jnp.einsum("bgrj,bjgk->bgrk",
+                               ws.astype(jnp.bfloat16),
+                               lv.astype(jnp.bfloat16),
+                               preferred_element_type=jnp.float32)
     x = _decode_out_ffn(cfg, layer, qlayer, wq8, x, o, llayer, aid)
     return x, sk, sv, sks, svs
 
 
+@jax.named_scope("kv_write")
 def _flush_staged_rows(cache: Cache, table, pos0, batch_ix,
                        sk, sv, sks, svs) -> Cache:
     """One batched scatter per cache array: every staged window row
@@ -1498,29 +1520,31 @@ def decode_burst_staged(params: llama.Params, cache: Cache,
     stage_vs = jnp.zeros((L, B, k, G), sdt) if quant else zero
 
     def step(carry, key_s):
-        key, s = key_s
-        last, sk, sv, sks, svs = carry
-        x = params["embed"].astype(cfg.dtype)[last[:, None]]
-        pos = pos0 + s
-        cos, sin = llama.rope_frequencies(cfg, pos[:, None])
-        stage_valid = jnp.arange(k)[None, :] <= s     # [1, k]
+        with jax.named_scope("decode_step"):
+            key, s = key_s
+            last, sk, sv, sks, svs = carry
+            x = params["embed"].astype(cfg.dtype)[last[:, None]]
+            pos = pos0 + s
+            cos, sin = llama.rope_frequencies(cfg, pos[:, None])
+            stage_valid = jnp.arange(k)[None, :] <= s     # [1, k]
 
-        def body(carry2, layer_q):
-            x, i, sk, sv, sks, svs = carry2
-            layer, qlayer, llayer = _layer_parts(layer_q, wq8,
-                                                 lora is not None)
-            x, sk, sv, sks, svs = _staged_attn_layer(
-                cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
-                sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
-                span, pos0, kv_kernel, llayer, aid)
-            return (x, i + 1, sk, sv, sks, svs), None
+            def body(carry2, layer_q):
+                x, i, sk, sv, sks, svs = carry2
+                layer, qlayer, llayer = _layer_parts(layer_q, wq8,
+                                                     lora is not None)
+                x, sk, sv, sks, svs = _staged_attn_layer(
+                    cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
+                    sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
+                    span, pos0, kv_kernel, llayer, aid)
+                return (x, i + 1, sk, sv, sks, svs), None
 
-        xs = _scan_xs(params, qweights, lora)
-        (x, _, sk, sv, sks, svs), _ = lax.scan(
-            body, (x, jnp.int32(0), sk, sv, sks, svs), xs)
-        logits = _decode_head(cfg, params, qweights, x)
-        tok = sampling_mod.sample(logits, key, sp)
-        last = jnp.where(active, tok, last)
+            xs = _scan_xs(params, qweights, lora)
+            (x, _, sk, sv, sks, svs), _ = lax.scan(
+                body, (x, jnp.int32(0), sk, sv, sks, svs), xs)
+            logits = _decode_head(cfg, params, qweights, x)
+            with jax.named_scope("sample"):
+                tok = sampling_mod.sample(logits, key, sp)
+            last = jnp.where(active, tok, last)
         return (last, sk, sv, sks, svs), tok
 
     init = (cache["last_token"], stage_k, stage_v, stage_ks, stage_vs)
@@ -1620,28 +1644,30 @@ def verify_draft_staged(params: llama.Params, cache: Cache,
     stage_vs = jnp.zeros((L, B, W, G), sdt) if quant else zero
 
     def step(carry, tok_s):
-        tok, s = tok_s
-        sk, sv, sks, svs = carry
-        x = params["embed"].astype(cfg.dtype)[tok[:, None]]
-        pos = pos0 + s
-        cos, sin = llama.rope_frequencies(cfg, pos[:, None])
-        stage_valid = jnp.arange(W)[None, :] <= s     # [1, W]
+        with jax.named_scope("decode_step"):
+            tok, s = tok_s
+            sk, sv, sks, svs = carry
+            x = params["embed"].astype(cfg.dtype)[tok[:, None]]
+            pos = pos0 + s
+            cos, sin = llama.rope_frequencies(cfg, pos[:, None])
+            stage_valid = jnp.arange(W)[None, :] <= s     # [1, W]
 
-        def body(carry2, layer_q):
-            x, i, sk, sv, sks, svs = carry2
-            layer, qlayer, llayer = _layer_parts(layer_q, wq8,
-                                                 lora is not None)
-            x, sk, sv, sks, svs = _staged_attn_layer(
-                cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
-                sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
-                span, pos0, kv_kernel, llayer, aid)
-            return (x, i + 1, sk, sv, sks, svs), None
+            def body(carry2, layer_q):
+                x, i, sk, sv, sks, svs = carry2
+                layer, qlayer, llayer = _layer_parts(layer_q, wq8,
+                                                     lora is not None)
+                x, sk, sv, sks, svs = _staged_attn_layer(
+                    cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
+                    sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
+                    span, pos0, kv_kernel, llayer, aid)
+                return (x, i + 1, sk, sv, sks, svs), None
 
-        xs = _scan_xs(params, qweights, lora)
-        (x, _, sk, sv, sks, svs), _ = lax.scan(
-            body, (x, jnp.int32(0), sk, sv, sks, svs), xs)
-        logits = _decode_head(cfg, params, qweights, x)
-        out_tok = sampling_mod.argmax_tokens(logits)
+            xs = _scan_xs(params, qweights, lora)
+            (x, _, sk, sv, sks, svs), _ = lax.scan(
+                body, (x, jnp.int32(0), sk, sv, sks, svs), xs)
+            logits = _decode_head(cfg, params, qweights, x)
+            with jax.named_scope("sample"):
+                out_tok = sampling_mod.argmax_tokens(logits)
         return (sk, sv, sks, svs), out_tok
 
     init = (stage_k, stage_v, stage_ks, stage_vs)

@@ -29,6 +29,7 @@ JetStream benchmark measures (examples/tpu/v6e/README.md TTFT).
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import os
 import queue
@@ -160,6 +161,10 @@ class ModelServer:
         self._burst = None
         self._async_decode = (hasattr(engine, "dispatch_decode_burst")
                               and not getattr(engine, "spec_k", 0))
+        # The real engine's decode entry points take ``why`` (the burst
+        # policy's reason, for its dispatch annotation); simple doubles
+        # exposing only decode_burst(max_burst) keep working.
+        self._takes_why = hasattr(engine, "dispatch_decode_burst")
         # Component health detail behind GET /healthz: "" while
         # serving; a reason string while warming or after a failed
         # engine reset (the two _ready-unset states a probe must tell
@@ -372,7 +377,17 @@ class ModelServer:
                 PENDING_REQUESTS.set(0)
                 busy = False
             if not busy:
-                time.sleep(0.002)
+                # One phase per idle STRETCH (capped, so that a trace
+                # started mid-stretch soon sees one), not per 2 ms poll:
+                # an idle server must not flood a trace or the Chrome
+                # buffer with ticks.
+                with timeline.phase("server.idle"):
+                    until = time.monotonic() + 0.25
+                    while True:
+                        time.sleep(0.002)
+                        if (self._has_work() or self._stop.is_set()
+                                or time.monotonic() >= until):
+                            break
 
     def _try_recover(self, e: BaseException) -> bool:
         """Attempt engine crash recovery for a typed recoverable
@@ -411,6 +426,13 @@ class ModelServer:
         with self._inbox_lock:
             new, self._inbox = self._inbox, []
             INBOX_DEPTH.set(0)
+        if new:
+            with timeline.phase("server.inbox", n=len(new)):
+                self._enqueue(new)
+            PENDING_REQUESTS.set(len(self._pending))
+
+    def _enqueue(self, new: list) -> None:
+        """Hand drained inbox entries to the engine (loop thread)."""
         for tokens, max_new, p, trace_ctx, tenant, priority, adapter, \
                 handoff in new:
             # Optional kwargs only when they carry signal: simple
@@ -455,21 +477,24 @@ class ModelServer:
             # not when the loop got around to admitting it.
             p.req.submit_s = p.enqueued_s
             self._pending[rid] = p
-        if new:
-            PENDING_REQUESTS.set(len(self._pending))
 
     def _flush_streams(self) -> None:
         """Push newly decoded tokens to every pending stream. Works for
         admission-time first tokens and burst tokens alike — it diffs
         req.tokens against the cursor. Blocking requests skip the chunk
         queue entirely (nobody drains it)."""
-        for p in self._pending.values():
-            if p.req is None or not p.stream:
-                continue
-            new = p.req.tokens[p.cursor:]
-            if new:
-                p.cursor += len(new)
-                p.chunks.put({"tokens": list(new)})
+        with timeline.phase("server.streams",
+                            n=len(self._pending)) as ph:
+            pushed = 0
+            for p in self._pending.values():
+                if p.req is None or not p.stream:
+                    continue
+                new = p.req.tokens[p.cursor:]
+                if new:
+                    p.cursor += len(new)
+                    p.chunks.put({"tokens": list(new)})
+                    pushed += len(new)
+            ph.set(tokens=pushed)
 
     @timeline.event(name="skytpu_server_wave_flush_seconds",
                     histogram=WAVE_FLUSH_SECONDS)
@@ -491,12 +516,19 @@ class ModelServer:
             BURST_FLUSHES.inc()
             self._flush_streams()
 
+    def _has_work(self) -> bool:
+        """Anything for :meth:`_step` to do? (The racy inbox read is
+        benign, as in :meth:`queue_depth`: a miss costs one 2 ms poll.)"""
+        eng = self.engine
+        return bool(self._inbox or eng.waiting or eng.slot_req
+                    or getattr(eng, "chunking", None)
+                    or self._burst is not None)
+
     def _step(self) -> bool:
         self._drain_inbox()
         eng = self.engine
         chunking = getattr(eng, "chunking", None)
-        if not (eng.waiting or eng.slot_req or chunking
-                or self._burst is not None):
+        if not self._has_work():
             return False
         # Coalesce a filling wave: more arrivals are in flight when the
         # last one is only milliseconds old. Never waits when the wave
@@ -507,12 +539,14 @@ class ModelServer:
                          or len(eng.free_slots),
                          len(eng.free_slots))
             deadline = time.monotonic() + self.coalesce_s
-            while (len(eng.waiting) < target
-                   and time.monotonic() < deadline
-                   and time.monotonic() - self._last_arrival
-                       < self.coalesce_s):
-                time.sleep(0.002)
-                self._drain_inbox()
+            with timeline.phase("server.coalesce",
+                                waiting=len(eng.waiting)):
+                while (len(eng.waiting) < target
+                       and time.monotonic() < deadline
+                       and time.monotonic() - self._last_arrival
+                           < self.coalesce_s):
+                    time.sleep(0.002)
+                    self._drain_inbox()
         # Admission has strict priority over decode — but it needs
         # accurate slot state, so the outstanding burst lands first
         # (retirements there may free the very slots admission wants).
@@ -551,17 +585,34 @@ class ModelServer:
             k = (self.max_burst
                  if (not eng.free_slots or quiet) and not chunking
                  else self.open_burst)
+            why = {}
+            if self._takes_why:
+                # Why this burst has the length it has: it rides the
+                # engine's dispatch annotation.
+                why["why"] = ("chunking" if chunking
+                              else "full" if not eng.free_slots
+                              else "quiet" if quiet else "open")
             if self._async_decode:
                 # Dispatch the NEXT burst before fetching the previous
                 # one: the device decodes while this thread streams.
-                nxt = eng.dispatch_decode_burst(max_burst=k)
+                nxt = eng.dispatch_decode_burst(max_burst=k, **why)
                 self._complete_burst()
                 self._burst = nxt
             else:
-                eng.decode_burst(max_burst=k)
+                eng.decode_burst(max_burst=k, **why)
                 self._flush_streams()
         else:
             self._complete_burst()
+        if self.engine.finished:
+            with timeline.phase("server.results",
+                                n=len(self.engine.finished)):
+                self._deliver_finished()
+            PENDING_REQUESTS.set(len(self._pending))
+        self.engine.finished.clear()
+        return True
+
+    def _deliver_finished(self) -> None:
+        """Hand every finished request's result to its waiter."""
         for req in self.engine.finished:
             p = self._pending.pop(req.rid, None)
             if p is None:
@@ -638,10 +689,6 @@ class ModelServer:
                               "recoveries":
                                   getattr(req, "recoveries", 0)})
             p.event.set()
-        if self.engine.finished:
-            PENDING_REQUESTS.set(len(self._pending))
-        self.engine.finished.clear()
-        return True
 
     def shutdown(self) -> None:
         self._stop.set()
@@ -1272,6 +1319,13 @@ def main() -> None:
                          "skytpu_unexpected_compiles_total (env "
                          "SKYTPU_WARM_GRID=1). Off by default: "
                          "startup pays the full compile sweep")
+    ap.add_argument("--profiler-port", type=int, default=0,
+                    help="start the JAX profiler's server on this port so "
+                         "a device trace (with the loop's and the "
+                         "engine's phase annotations on the same "
+                         "clock) can be captured from the running "
+                         "server; 0 = off (docs/observability.md "
+                         "§Device trace)")
     args = ap.parse_args()
 
     # Long-lived serving daemon: sever any inherited trace root. A
@@ -1285,6 +1339,8 @@ def main() -> None:
     tracing.set_process_name("model-server")
 
     import jax
+    if args.profiler_port:
+        jax.profiler.start_server(args.profiler_port)
 
     from skypilot_tpu.infer import engine as eng, sampling
     from skypilot_tpu.models import llama
@@ -1384,6 +1440,16 @@ def main() -> None:
             "server.programs_warmed",
             {"programs": n,
              "warm_s": round(time.time() - t0, 2)}, echo=True)
+    # Startup leaves some hundreds of thousands of live objects behind
+    # (JAX itself, every traced program). A full collection walks them
+    # all: ~0.1 s with the loop thread stopped, and WHERE it lands is an
+    # accident of allocation counts — on the v5e one such pause, falling
+    # between two admission waves with the device idle, moved the chat
+    # cell's TTFT p95 by 13 % (PERF.md §6, PR 25). Park them in the
+    # permanent generation: later collections see only what serving
+    # allocates.
+    gc.collect()
+    gc.freeze()
     model, httpd = serve(engine, port=args.port,
                          max_burst=args.max_burst,
                          open_burst=args.open_burst,

@@ -178,6 +178,7 @@ def remat_policy(cfg: LlamaConfig):
     return jax.checkpoint_policies.nothing_saveable
 
 
+@jax.named_scope("norm")
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     dtype = x.dtype
     x = x.astype(jnp.float32)
@@ -258,23 +259,26 @@ def decoder_layer(cfg: LlamaConfig, x: jax.Array, layer: Params,
     """One pre-norm decoder block. x: [B, S, D]."""
     B, S, D = x.shape
     h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(cfg.dtype))
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    q = constrain(q, ("batch", "seq", "heads", "head_dim"))
-    k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
-    o = _attention(q, k, v, cfg, mesh, rules, segment_ids)
-    o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(cfg.dtype))
-    x = x + constrain(o, ("batch", "seq", "embed"))
+    with jax.named_scope("attn"):
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(cfg.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(cfg.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(cfg.dtype))
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        q = constrain(q, ("batch", "seq", "heads", "head_dim"))
+        k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
+        o = _attention(q, k, v, cfg, mesh, rules, segment_ids)
+        o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(cfg.dtype))
+        x = x + constrain(o, ("batch", "seq", "embed"))
 
     h = rms_norm(x, layer["ln2"], cfg.norm_eps)
-    g = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(cfg.dtype))
-    u = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(cfg.dtype))
-    m = jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u,
-                   layer["w_down"].astype(cfg.dtype))
-    return x + constrain(m, ("batch", "seq", "embed"))
+    with jax.named_scope("mlp"):
+        g = jnp.einsum("bsd,df->bsf", h,
+                       layer["w_gate"].astype(cfg.dtype))
+        u = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(cfg.dtype))
+        m = jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u,
+                       layer["w_down"].astype(cfg.dtype))
+        return x + constrain(m, ("batch", "seq", "embed"))
 
 
 # ---------------------------------------------------------------------------
@@ -300,10 +304,11 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     # sharding straight to [batch, seq, embed-replicated], which IS the
     # activation layout; no cross-layout transition (and no involuntary
     # full rematerialization from the SPMD partitioner).
-    table = constrain(params["embed"].astype(cfg.dtype),
-                      ("vocab", "embed"))
-    x = table[tokens]
-    x = constrain(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        table = constrain(params["embed"].astype(cfg.dtype),
+                          ("vocab", "embed"))
+        x = table[tokens]
+        x = constrain(x, ("batch", "seq", "embed"))
     if positions is None:
         positions = jnp.arange(S)
 
@@ -387,6 +392,7 @@ def packed_loss_mask(batch: Dict[str, jax.Array]):
             else mask * seg_mask.astype(mask.dtype))
 
 
+@jax.named_scope("xent")
 def xent_metrics(params: Params, h: jax.Array, tokens: jax.Array,
                  mask: Optional[jax.Array], cfg: LlamaConfig,
                  constrain=lambda x, axes: x, head: Optional[jax.Array] = None):

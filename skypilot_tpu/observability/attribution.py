@@ -50,6 +50,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from skypilot_tpu.observability import metrics, tracing
+from skypilot_tpu.utils import timeline
 
 DEVICE_FLOPS = metrics.counter(
     "skytpu_device_flops_total",
@@ -265,10 +266,14 @@ class DeviceTimeCalibrator:
         of the returned arrays is already materialized, so the caller's
         own fetch is then free."""
         import jax
-        t0 = time.monotonic()
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-        dt = time.monotonic() - t0
+        # On the device trace the bracket shows as a phase of its own:
+        # the idle it causes (the loop blocked on THIS dispatch instead
+        # of running ahead) is attributable to it.
+        with timeline.phase("engine.devtime_bracket", program=key):
+            t0 = time.monotonic()
+            out = fn(*args, **kwargs)
+            jax.block_until_ready(out)
+            dt = time.monotonic() - t0
         self.update(key, dt)
         return out
 

@@ -59,6 +59,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from skypilot_tpu.observability import attribution, flight, forensics, \
     metrics, tracing
+from skypilot_tpu.utils import timeline
 
 GOODPUT_RATIO = metrics.gauge(
     "skytpu_train_goodput_ratio",
@@ -120,6 +121,9 @@ _PHASE_BUCKET = {
     "anomaly_pause": "anomaly_pause",
     "host_other": "host_other",
 }
+
+# Phases whose annotation on the device trace is not "train.<phase>".
+_PHASE_ANNOTATION = {"compute": "train.step", "ckpt_save": "train.save"}
 
 STAMPS_FILE = "goodput.json"
 
@@ -247,21 +251,29 @@ class GoodputRecorder:
         self._phases = {}
 
     @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        """Time one named slice of the open step. Disabled or
-        outside a step it is a bare yield — the loop body never
-        branches on recorder state."""
+    def phase(self, name: str, tokens: int = 0) -> Iterator[None]:
+        """Time one named slice of the open step. One ``with`` yields
+        the ledger entry AND the ``train.*`` annotation on the device
+        trace (``compute`` is the profiler's step marker, numbered by
+        the open step). Disabled or outside a step only the ledger
+        entry is skipped — the loop body never branches on recorder
+        state."""
         if name not in _PHASE_BUCKET:
             raise ValueError(f"unknown step phase: {name}")
-        if not self.enabled or self._step is None:
-            yield
-            return
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            dt = time.monotonic() - t0
-            self._phases[name] = self._phases.get(name, 0.0) + dt
+        counts = {"tokens": tokens} if tokens else {}
+        with timeline.phase(
+                _PHASE_ANNOTATION.get(name, "train." + name),
+                step_num=self._step if name == "compute" else None,
+                **counts):
+            if not self.enabled or self._step is None:
+                yield
+                return
+            t0 = time.monotonic()
+            try:
+                yield
+            finally:
+                dt = time.monotonic() - t0
+                self._phases[name] = self._phases.get(name, 0.0) + dt
 
     def step_end(self, tokens: int = 0, loss: Optional[float] = None,
                  grad_norm: Optional[float] = None

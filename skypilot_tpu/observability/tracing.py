@@ -381,27 +381,7 @@ class start_span:
 
 
 # ---------------------------------------------------------------------------
-# Introspection (bench --emit-trace, tests).
-
-def span_summary() -> Dict[str, Dict[str, Any]]:
-    """Aggregate the in-memory buffer's spans by name:
-    ``{name: {count, total_s, mean_s, max_s}}`` — the per-request span
-    summary BENCH artifacts carry under ``--emit-trace``."""
-    spans = [r for r in _RING.snapshot() if r.get("kind") == "span"]
-    out: Dict[str, Dict[str, Any]] = {}
-    for s in spans:
-        dur = max(float(s["end_s"]) - float(s["start_s"]), 0.0)
-        agg = out.setdefault(s["name"],
-                             {"count": 0, "total_s": 0.0, "max_s": 0.0})
-        agg["count"] += 1
-        agg["total_s"] += dur
-        agg["max_s"] = max(agg["max_s"], dur)
-    for agg in out.values():
-        agg["mean_s"] = round(agg["total_s"] / agg["count"], 6)
-        agg["total_s"] = round(agg["total_s"], 6)
-        agg["max_s"] = round(agg["max_s"], 6)
-    return out
-
+# Introspection (tests).
 
 def buffered_records() -> List[Dict[str, Any]]:
     """Snapshot of the in-memory buffer (tests)."""

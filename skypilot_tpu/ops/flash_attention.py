@@ -159,6 +159,7 @@ def _fwd(q, k, v, segments, *, causal: bool, block_q: int, block_k: int,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, s, LANES), jnp.float32)],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return o, lse
 
@@ -309,6 +310,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g):
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, g, lse, delta, *seg_args)
 
     dq_kernel = functools.partial(
@@ -326,6 +328,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, g):
         out_specs=q_blocked,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, g, lse, delta, *seg_args)
     return dq, dk, dv, None
 

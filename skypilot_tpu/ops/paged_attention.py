@@ -247,6 +247,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             jax.ShapeDtypeStruct((B, G, R, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_decode",
     )(layer_arr, table, lengths, *args)
     # Stats are lane-replicated (the Mosaic tiling idiom); one lane is
     # the value.

@@ -48,6 +48,7 @@ def dequant_weight(qw: Dict[str, jax.Array], n_contract: int,
     return (qw["w"].astype(jnp.float32) * s).astype(dtype)
 
 
+@jax.named_scope("lora")
 def _lora_in(h, ab, scale):
     """Delta for an embed->heads/kv projection. h: [B,S,D]."""
     u = jnp.einsum("bsd,dr->bsr", h, ab["a"].astype(h.dtype))
@@ -55,6 +56,7 @@ def _lora_in(h, ab, scale):
                               ab["b"].astype(h.dtype))
 
 
+@jax.named_scope("lora")
 def _lora_out(o, ab, scale):
     """Delta for the heads->embed (wo) projection. o: [B,S,H,K]."""
     u = jnp.einsum("bshk,hkr->bsr", o, ab["a"].astype(o.dtype))
@@ -68,36 +70,40 @@ def _qdecoder_layer(cfg: llama.LlamaConfig, lc: LoRAConfig, x, qlayer,
     dt = cfg.dtype
 
     def proj(name, h, eq, n_contract):
-        w = dequant_weight(qlayer[name], n_contract, dt)
-        return jnp.einsum(eq, h, w)
+        # The frozen weight dequantised and multiplied, under ONE name
+        # on the device timeline (forward, and backward through it).
+        with jax.named_scope("base_matmul"):
+            w = dequant_weight(qlayer[name], n_contract, dt)
+            return jnp.einsum(eq, h, w)
 
     h = llama.rms_norm(x, norms["ln1"], cfg.norm_eps)
-    q = proj("wq", h, "bsd,dhk->bshk", 1)
-    k = proj("wk", h, "bsd,dhk->bshk", 1)
-    v = proj("wv", h, "bsd,dhk->bshk", 1)
-    sc = lc.scale
-    if "wq" in adapters:
-        q = q + _lora_in(h, adapters["wq"], sc)
-    if "wk" in adapters:
-        k = k + _lora_in(h, adapters["wk"], sc)
-    if "wv" in adapters:
-        v = v + _lora_in(h, adapters["wv"], sc)
-    q = llama.apply_rope(q, cos, sin)
-    k = llama.apply_rope(k, cos, sin)
-    q = constrain(q, ("batch", "seq", "heads", "head_dim"))
-    k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
-    o = llama._attention(q, k, v, cfg, mesh, rules, segment_ids)
-    y = proj("wo", o, "bshk,hkd->bsd", 2)
-    if "wo" in adapters:
-        y = y + _lora_out(o, adapters["wo"], sc)
-    x = x + constrain(y, ("batch", "seq", "embed"))
+    with jax.named_scope("attn"):
+        q = proj("wq", h, "bsd,dhk->bshk", 1)
+        k = proj("wk", h, "bsd,dhk->bshk", 1)
+        v = proj("wv", h, "bsd,dhk->bshk", 1)
+        sc = lc.scale
+        if "wq" in adapters:
+            q = q + _lora_in(h, adapters["wq"], sc)
+        if "wk" in adapters:
+            k = k + _lora_in(h, adapters["wk"], sc)
+        if "wv" in adapters:
+            v = v + _lora_in(h, adapters["wv"], sc)
+        q = llama.apply_rope(q, cos, sin)
+        k = llama.apply_rope(k, cos, sin)
+        q = constrain(q, ("batch", "seq", "heads", "head_dim"))
+        k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
+        o = llama._attention(q, k, v, cfg, mesh, rules, segment_ids)
+        y = proj("wo", o, "bshk,hkd->bsd", 2)
+        if "wo" in adapters:
+            y = y + _lora_out(o, adapters["wo"], sc)
+        x = x + constrain(y, ("batch", "seq", "embed"))
 
     h = llama.rms_norm(x, norms["ln2"], cfg.norm_eps)
-    g = proj("w_gate", h, "bsd,df->bsf", 1)
-    u = proj("w_up", h, "bsd,df->bsf", 1)
-    m = jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u,
-                   dequant_weight(qlayer["w_down"], 1, dt))
-    return x + constrain(m, ("batch", "seq", "embed"))
+    with jax.named_scope("mlp"):
+        g = proj("w_gate", h, "bsd,df->bsf", 1)
+        u = proj("w_up", h, "bsd,df->bsf", 1)
+        m = proj("w_down", jax.nn.silu(g) * u, "bsf,fd->bsd", 1)
+        return x + constrain(m, ("batch", "seq", "embed"))
 
 
 def forward_hidden(qweights: Params, fp_params: Params, adapters: Params,
@@ -113,10 +119,11 @@ def forward_hidden(qweights: Params, fp_params: Params, adapters: Params,
         constrain = lambda x, axes: x
     B, S = tokens.shape
     tokens = constrain(tokens, ("batch", "seq"))
-    table = constrain(fp_params["embed"].astype(cfg.dtype),
-                      ("vocab", "embed"))
-    x = table[tokens]
-    x = constrain(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        table = constrain(fp_params["embed"].astype(cfg.dtype),
+                          ("vocab", "embed"))
+        x = table[tokens]
+        x = constrain(x, ("batch", "seq", "embed"))
     if positions is None:
         positions = jnp.arange(S)
     from skypilot_tpu.parallel import ring_attention as ra
@@ -153,7 +160,10 @@ def loss_fn(qweights: Params, fp_params: Params, adapters: Params,
                        constrain, mesh, rules,
                        positions=batch.get("positions"),
                        segment_ids=batch.get("segment_ids"))
-    head = dequant_weight(qweights["head"], 1, cfg.dtype)
+    # The head's dequantisation is a base matmul's too (the einsum that
+    # consumes it sits in xent_metrics' chunk loop, under "xent").
+    with jax.named_scope("base_matmul"):
+        head = dequant_weight(qweights["head"], 1, cfg.dtype)
     loss, acc, denom = llama.xent_metrics(
         fp_params, h, tokens, llama.packed_loss_mask(batch), cfg,
         constrain, head=head)
@@ -179,10 +189,12 @@ def make_qlora_train_step(cfg: llama.LlamaConfig, lc: LoRAConfig,
 
         (loss, metrics), grads = jax.value_and_grad(
             lossf, has_aux=True)(state["params"])
-        updates, new_opt = opt.update(grads, state["opt_state"],
-                                      state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
-        metrics = dict(metrics, grad_norm=optax.global_norm(grads))
+        with jax.named_scope("optimizer"):
+            updates, new_opt = opt.update(grads, state["opt_state"],
+                                          state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
+            metrics = dict(metrics,
+                           grad_norm=optax.global_norm(grads))
         return {"params": new_params, "opt_state": new_opt,
                 "step": state["step"] + 1}, metrics
 

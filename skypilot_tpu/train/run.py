@@ -76,6 +76,11 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--profiler-port", type=int, default=0,
+                    help="start the JAX profiler's server on this port so "
+                         "a device trace (with the loop's train.* "
+                         "phases on the same clock) can be captured "
+                         "from the running job; 0 = off")
     args = ap.parse_args()
     if args.packed and args.model == "pipeline":
         ap.error("--packed is not supported with --model pipeline")
@@ -103,11 +108,14 @@ def main() -> None:
     initialize_from_env()
 
     import jax
+    if args.profiler_port:
+        jax.profiler.start_server(args.profiler_port)
 
     import skypilot_tpu.callbacks as sky_callback
     from skypilot_tpu.parallel import mesh as mesh_lib
     from skypilot_tpu.parallel import sharding as sh_rules
     from skypilot_tpu.train import trainer
+    from skypilot_tpu.utils import timeline
 
     if args.model == "llama":
         from skypilot_tpu.models import llama as model
@@ -299,8 +307,10 @@ def main() -> None:
         if batches is not None:
             with gp.phase("data_wait"):
                 batch_data = next(batches)
+        tokens = getattr(batch_data.get("tokens"), "size", 0) \
+            if hasattr(batch_data, "get") else 0
         with sky_callback.step():
-            with gp.phase("compute"):
+            with gp.phase("compute", tokens=tokens):
                 state, metrics = step_fn(state, batch_data)
         if step == start_step:
             # Every program the loop can reach is compiled now; from
@@ -311,7 +321,8 @@ def main() -> None:
             with gp.phase("eval"):
                 # The deliberate host fetch the logging cadence always
                 # paid; grad_norm rides the same sync.
-                loss = float(metrics["loss"])
+                with timeline.phase("train.loss_fetch"):
+                    loss = float(metrics["loss"])
                 gn = metrics.get("grad_norm") \
                     if hasattr(metrics, "get") else None
                 grad_norm = float(gn) if gn is not None else None
@@ -325,8 +336,6 @@ def main() -> None:
             with gp.phase("ckpt_save"):
                 mgr.save(step + 1, state)
                 gp.persist(mgr.directory)
-        tokens = getattr(batch_data.get("tokens"), "size", 0) \
-            if hasattr(batch_data, "get") else 0
         gp.step_end(tokens=tokens, loss=loss, grad_norm=grad_norm)
     loss = float(metrics["loss"])  # host fetch = real sync
     wall = time.time() - t0

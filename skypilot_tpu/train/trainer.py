@@ -272,10 +272,11 @@ def make_train_step(cfg: llama.LlamaConfig, tc: TrainConfig,
 
         (loss, metrics), grads = jax.value_and_grad(lossf, has_aux=True)(
             state["params"])
-        updates, new_opt = opt.update(grads, state["opt_state"],
-                                      state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = opt.update(grads, state["opt_state"],
+                                          state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         new_state = _train_state(new_params, new_opt, state["step"] + 1)
         metrics = dict(metrics, grad_norm=gnorm)
         return new_state, metrics

@@ -5,6 +5,14 @@ Events are buffered in-process and flushed as Chrome trace-format JSON
 ``SKYTPU_TIMELINE_FILE_PATH`` at process exit. Zero overhead when the
 env var is unset.
 
+Device-trace bridge: :func:`phase` is the scoped form the serve loop,
+the engine and the trainer put round their host phases. With JAX loaded
+it enters a JAX profiler ``TraceAnnotation`` carrying the phase's counts
+(inert unless a profiler trace is running: one flag check), so the
+program's own phases sit on the same clock as the device timeline; with
+``SKYTPU_TIMELINE_FILE_PATH`` set it also feeds the Chrome file. This
+module stays stdlib-only at import (``python -S`` safe).
+
 Metrics bridge: an :class:`Event` (or ``@event`` decorator) given a
 ``histogram=`` — anything with ``observe(seconds)``, i.e. an
 ``observability.metrics`` histogram child — records its duration there
@@ -22,6 +30,7 @@ import atexit
 import functools
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -128,8 +137,12 @@ def _append(evt: Dict[str, Any]) -> None:
             spans = [e for e in _events if e.get("ph") != "M"]
             del spans[:len(spans) // 2]
             kept_tids = {e["tid"] for e in spans}
+            # ...and of a recycled ident only its CURRENT name: CPython
+            # reuses idents, so under churn every dead thread's name
+            # would otherwise ride the one live tid for ever.
             meta = [e for e in _events
-                    if e.get("ph") == "M" and e["tid"] in kept_tids]
+                    if e.get("ph") == "M" and e["tid"] in kept_tids
+                    and _named_tids.get(e["tid"]) == e["args"]["name"]]
             _events[:] = meta + spans
             for t in list(_named_tids):
                 if t not in kept_tids:
@@ -142,10 +155,12 @@ class Event:
     that histogram child regardless of tracing state."""
 
     def __init__(self, name: str, message: Optional[str] = None,
-                 histogram: Optional[Any] = None):
+                 histogram: Optional[Any] = None,
+                 args: Optional[Dict[str, Any]] = None):
         self._name = name
         self._message = message
         self._histogram = histogram
+        self._args = args        # read at end(): the owner may add to it
         self._begin_us = 0.0
 
     def begin(self) -> None:
@@ -176,8 +191,10 @@ class Event:
             # their spans into nonsense.
             "tid": threading.get_ident(),
         }
-        if self._message:
-            evt["args"] = {"message": self._message}
+        if self._message or self._args:
+            evt["args"] = dict(self._args or {})
+            if self._message:
+                evt["args"]["message"] = self._message
         _append(evt)
 
     def __enter__(self) -> "Event":
@@ -206,6 +223,61 @@ def event(fn: Optional[Callable] = None, name: Optional[str] = None,
             return fn(*args, **kwargs)
 
     return wrapper
+
+
+class Phase:
+    """One scoped host phase (see :func:`phase`). ``set`` adds counts
+    known only at the end of the phase (tokens kept, requests retired);
+    they land on the same trace event."""
+
+    __slots__ = ("_ann", "_event", "_counts")
+
+    def __init__(self, name: str, step_num: Optional[int],
+                 counts: Dict[str, Any]):
+        self._ann = None
+        self._event = None
+        self._counts = counts
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            # The ONE place the package touches the JAX profiler's
+            # annotations. Never imports JAX itself: a process that has
+            # not loaded it has no device timeline to join.
+            if step_num is None:
+                self._ann = jax.profiler.TraceAnnotation(name, **counts)
+            else:
+                self._ann = jax.profiler.StepTraceAnnotation(
+                    name, step_num=step_num, **counts)
+        if enabled():
+            self._event = Event(name, args=counts)
+
+    def set(self, **counts) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**counts)
+        self._counts.update(counts)
+
+    def __enter__(self) -> "Phase":
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._event is not None:
+            self._event.begin()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._event is not None:
+            self._event.end()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+def phase(name: str, step_num: Optional[int] = None, **counts) -> Phase:
+    """``with timeline.phase("engine.decode.dispatch", k=4, slots=17):``
+    — a host phase on the profiler's clock. ``counts`` are ints, floats
+    and short strings the host ALREADY holds (never a device fetch: the
+    annotation must not be what stalls the loop it describes).
+    ``step_num`` makes it a ``StepTraceAnnotation`` (the trainer's
+    step). No trace running and no timeline file: two attribute checks
+    and a flag test."""
+    return Phase(name, step_num, counts)
 
 
 class FileLockEvent:

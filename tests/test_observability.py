@@ -172,7 +172,10 @@ def test_engine_records_ttft_and_slot_occupancy():
     assert _hist_count(eng.TPOT_SECONDS) >= 2
 
 
-def test_engine_wave_size_and_prefill_bucket_labels():
+def test_engine_prefill_bucket_labels():
+    # (The wave-size histogram went with ISSUE 25: a wave's real and
+    # padded rows are arguments of its ``engine.wave.dispatch``
+    # annotation — tests/test_trace_annotations.py.)
     import jax
 
     from skypilot_tpu.infer import engine as eng
@@ -180,11 +183,9 @@ def test_engine_wave_size_and_prefill_bucket_labels():
 
     cfg = llama.CONFIGS["llama3-tiny"]
     params = llama.init_params(jax.random.key(1), cfg)
-    wave0 = _hist_count(eng.WAVE_SIZE)
     e = eng.InferenceEngine(params, cfg, n_slots=4, max_len=64,
                             prompt_buckets=(8, 16))
     e.generate([[1, 2, 3], [4, 5]], max_new_tokens=2)
-    assert _hist_count(eng.WAVE_SIZE) > wave0
     # Prefill latency histograms are labeled by prompt bucket.
     labels = {v for v, _ in eng.PREFILL_SECONDS.children()}
     assert ("8",) in labels
