@@ -585,7 +585,7 @@ def main() -> None:
             out["serve_adapter_error"] = str(e)[:200]
         # Flight recorder + compile watch phase: the introspection
         # contract over the full mixed workload (chunked admission +
-        # spec decode + span regrouping, paged + contiguous). Gates:
+        # spec decode + span selection, paged + contiguous). Gates:
         # nothing may compile inside the timed serving window, every
         # burst must carry a matching flight record, and the recorder
         # must be a no-op guard when off (<1% TPOT).

@@ -51,7 +51,7 @@ _SCOPES: Dict[str, Set[str]] = {
         # selection and block headroom run per burst from HOST state
         # (request token lists, the numpy block table) — a device
         # fetch to pick a span would stall every dispatch.
-        "_span_groups", "_span_for", "_span_arg", "_slot_rows",
+        "_round_slots", "_span_for", "_span_arg", "_slot_rows",
         "_ensure_headroom",
         # Flight recorder (PR 10): the per-burst record is assembled
         # from host bookkeeping inside the step/burst/chunk loops — a
@@ -223,7 +223,9 @@ class HostSyncChecker(Checker):
     # v14: phase annotations (PR 25) — the bodies wrapped by
     #     ``timeline.phase`` blocks moved to helpers that joined the
     #     scope (the burst's int() loop is now ``_commit_burst``'s).
-    version = 14
+    # v15: one decode program a round (PR 26) — ``_span_groups`` became
+    #     ``_round_slots``; the bump rescans the renamed helper cold.
+    version = 15
 
     def check_file(self, ctx: FileContext) -> List[Finding]:
         scoped = _SCOPES.get(ctx.rel)

@@ -1631,7 +1631,7 @@ def run_flight(config=None, requests=None, new_tokens=None,
                weights_int8=False, smoke=False) -> dict:
     """Flight recorder + compile watch bench over the FULL mixed
     workload: chunked admission with prefix reuse + speculative decode
-    + span regrouping, on a paged engine AND a contiguous twin.
+    + span selection, on a paged engine AND a contiguous twin.
 
     Per layout:
 
@@ -2979,7 +2979,7 @@ def main() -> None:
     ap.add_argument("--flight", action="store_true",
                     help="flight recorder + compile watch bench: the "
                          "full mixed workload (chunked admission + "
-                         "spec decode + span regrouping, paged + "
+                         "spec decode + span selection, paged + "
                          "contiguous) with warm-grid startup — gates "
                          "zero unexpected compiles in the timed "
                          "window, per-burst record coverage, and the "

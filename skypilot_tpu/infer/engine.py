@@ -279,27 +279,24 @@ class Request:
 @dataclasses.dataclass
 class BurstHandle:
     """A dispatched-but-unfetched decode burst (see
-    :meth:`InferenceEngine.dispatch_decode_burst`). One handle covers
-    the whole burst round: span regrouping may split it over several
-    device programs — ``parts`` pairs each program's token array with
-    the slots it decoded for."""
-    parts: List[Tuple[jax.Array, List[int]]]  # [(toks [k, slots+1], slots)]
+    :meth:`InferenceEngine.dispatch_decode_burst`): ONE device program
+    over every slot that had headroom, at the span rung covering the
+    longest of them."""
+    toks: jax.Array                   # [k, slots+1], still on device
+    slots: List[int]                  # the slots the program decoded for
     k: int
     slot_req: Dict[int, "Request"]    # slot->request snapshot at dispatch
     # Span opened at dispatch, closed when the tokens are fetched —
     # double-records into skytpu_decode_step_seconds.
     span: Optional[timeline.Event] = None
-    # Per-part span rungs (parallel to ``parts``; None = full view):
-    # the flight record written at completion carries each part's
-    # program identity.
-    spans: List[Optional[int]] = dataclasses.field(default_factory=list)
-    # Per-part compile-watch program keys (parallel to ``parts``) —
-    # the completion record's dev_ms_est looks each part's calibrated
-    # device-time EWMA up by this identity.
-    keys: List[Optional[str]] = dataclasses.field(default_factory=list)
-    # Wall clock when the last part's dispatch returned: the
-    # completion record splits its host wall into dispatch vs fetch
-    # at this stamp.
+    # The program's static span argument (None = full view): the
+    # flight record written at completion carries the program identity.
+    span_arg: Optional[int] = None
+    # Compile-watch program key — the completion record's dev_ms_est
+    # looks the calibrated device-time EWMA up by this identity.
+    key: Optional[str] = None
+    # Wall clock when the dispatch returned: the completion record
+    # splits its host wall into dispatch vs fetch at this stamp.
     dispatch_done_s: Optional[float] = None
     # Burst sequence number: the dispatch and the fetch annotations of
     # one burst carry it, so a trace reader pairs them exactly.
@@ -860,12 +857,15 @@ class InferenceEngine:
         # compile per SPAN BUCKET (a power-of-two ladder whose largest
         # rung is max_len — the full view) and gather only the first
         # span logical rows, so decode KV bandwidth tracks the ACTIVE
-        # span of the burst, not the engine's worst-case length. The
-        # ladder is the entire new retrace surface: selection, and the
-        # regrouping that keeps one long slot from pinning everyone to
-        # its bucket, are host-side. Knob: SKYTPU_SPAN_BUCKETS (ctor
-        # arg wins) — a comma-separated explicit ladder, or 0 to
-        # disable (full view only).
+        # span of the round, not the engine's worst-case length. The
+        # ladder is the entire new retrace surface; selection is
+        # host-side: a decode round is ONE program at the rung that
+        # covers its longest live slot (a program runs every batch
+        # row whatever it holds and its time does not fall as its
+        # span grows, so one program at the widest span present is
+        # never slower than one per span present). Knob:
+        # SKYTPU_SPAN_BUCKETS (ctor arg wins) — a comma-separated
+        # explicit ladder, or 0 to disable (full view only).
         if span_buckets is None:
             env = os.environ.get("SKYTPU_SPAN_BUCKETS", "").strip()
             if env:
@@ -1909,27 +1909,25 @@ class InferenceEngine:
         return (len(req.prompt) + len(req.tokens)
                 + self._inflight_tokens)
 
-    def _span_groups(self, width: int
-                     ) -> List[Tuple[int, List[int]]]:
-        """Active slots grouped by the span bucket covering their
-        rows — the REGROUPING step: one mixed-length burst would
-        otherwise ride the longest slot's bucket, so a single long
-        conversation would drag every short neighbor back to
-        worst-case reads. Each group dispatches its own burst at its
-        own span (programs chain on the donated cache; a group's
-        garbage writes for other groups' slots land past their
-        committed lengths and are overwritten before any read, the
-        standard dead-row net). ``width``: rows the burst will write
-        per slot — lazy growth must back them; a slot the pool cannot
-        grow is left out and retries once retirements free blocks.
-        Returns [(span, [slot, ...])], ascending spans."""
-        groups: Dict[int, List[int]] = {}
+    def _round_slots(self, width: int
+                     ) -> Tuple[int, List[int], int]:
+        """The decode round's ONE program: every active slot the pool
+        can back, at the ladder rung covering the longest of them.
+        ``width``: rows the round will write per slot — lazy growth
+        must back them; a slot the pool cannot grow is left out and
+        retries once retirements free blocks. Returns (span, slots,
+        promoted): ``promoted`` counts the slots whose own rung lies
+        below the program's span — what riding one program costs them
+        in rows read (their extra rows carry exact-zero softmax
+        weight). ``slots`` empty: nothing can run this round."""
+        rungs: Dict[int, int] = {}
         for slot, req in self.slot_req.items():
             rows = self._slot_rows(req)
-            if not self._ensure_headroom(slot, req, rows + width):
-                continue
-            groups.setdefault(self._span_for(rows), []).append(slot)
-        return sorted(groups.items())
+            if self._ensure_headroom(slot, req, rows + width):
+                rungs[slot] = self._span_for(rows)
+        span = max(rungs.values(), default=0)
+        return (span, list(rungs),
+                sum(1 for r in rungs.values() if r < span))
 
     def _alloc_blocks(self, n: int) -> Optional[List[int]]:
         """n fresh blocks, evicting LRU prefix-cache entries on a dry
@@ -2368,7 +2366,7 @@ class InferenceEngine:
             # per-tenant subqueues, high priority first), then evict
             # outranked decode slots for queued high-priority work.
             # Both are host bookkeeping; wave building below is
-            # unchanged and span regrouping downstream never sees
+            # unchanged and span selection downstream never sees
             # tenants.
             self.qos.reorder(self.waiting)
             if self._preempt_for_waiting() and self.waiting:
@@ -3458,13 +3456,10 @@ class InferenceEngine:
                     dlen[slot] = len(d)
         if not dlen:
             return None
-        # Span regrouping, exactly as the plain burst: one verify
-        # program per span bucket present among the active slots —
-        # a slot verifies at ITS group's span, so a long conversation
-        # never drags short neighbors back to worst-case reads.
-        groups = self._span_groups(K + 1)
-        drafted = sum(dlen.get(s, 0)
-                      for _, slots in groups for s in slots)
+        # One verify program for the round, exactly as the plain
+        # burst: every backable slot, at the longest one's rung.
+        attn_span, slots, promoted = self._round_slots(K + 1)
+        drafted = sum(dlen.get(s, 0) for s in slots)
         if not drafted:
             # Every drafting slot was kept out (lazy dry pool): a
             # K+1-wide verify for the rest would be strictly worse
@@ -3473,34 +3468,27 @@ class InferenceEngine:
         span = timeline.Event("skytpu_decode_step_seconds",
                               histogram=DECODE_STEP_SECONDS)
         span.begin()
-        parts = []
-        part_spans: List[Optional[int]] = []
-        part_keys: List[Optional[str]] = []
         self._burst_seq += 1
-        for attn_span, slots in groups:
-            active = np.zeros((self.n_slots + 1,), bool)
-            for s in slots:
-                active[s] = True
-            sarg = self._span_arg(attn_span)
-            self.decode_programs.add(("verify", K, sarg))
-            DECODE_ATTN_ROWS.observe(attn_span)
-            # A verify program computes K + 1 window positions a row.
-            with timeline.phase(
-                    "engine.decode.dispatch", seq=self._burst_seq,
-                    k=K + 1, slots=len(slots), rows=self.n_slots + 1,
-                    span=attn_span, why=why,
-                    waiting=len(self.waiting)):
-                self.cache, toks_dev, commit_dev = self._verify_fn(
-                    self.params, self.cache, jnp.asarray(draft),
-                    jnp.asarray(n_draft), jnp.asarray(active),
-                    self.table_device(), k=K, qweights=self.qweights,
-                    span=sarg, kernel=self.kv_kernel,
-                    **self._lora_args())
-            parts.append((slots, toks_dev, commit_dev))
-            part_spans.append(sarg)
-            part_keys.append(self.compile_watch.last_key)
-        dispatch_done_s = time.time()   # verify programs all enqueued
-        # Pipelined predraft: with the verify program(s) now in
+        active = np.zeros((self.n_slots + 1,), bool)
+        active[slots] = True
+        sarg = self._span_arg(attn_span)
+        self.decode_programs.add(("verify", K, sarg))
+        DECODE_ATTN_ROWS.observe(attn_span)
+        # A verify program computes K + 1 window positions a row.
+        with timeline.phase(
+                "engine.decode.dispatch", seq=self._burst_seq,
+                k=K + 1, slots=len(slots), rows=self.n_slots + 1,
+                span=attn_span, promoted=promoted, why=why,
+                waiting=len(self.waiting)):
+            self.cache, toks_dev, commit_dev = self._verify_fn(
+                self.params, self.cache, jnp.asarray(draft),
+                jnp.asarray(n_draft), jnp.asarray(active),
+                self.table_device(), k=K, qweights=self.qweights,
+                span=sarg, kernel=self.kv_kernel,
+                **self._lora_args())
+        verify_key = self.compile_watch.last_key
+        dispatch_done_s = time.time()   # verify program enqueued
+        # Pipelined predraft: with the verify program now in
         # flight, roll the draft model forward K+1 steps for the
         # model-drafting slots — its prediction of the verifier's
         # bonus/correction token plus the NEXT round's K drafts. The
@@ -3533,70 +3521,60 @@ class InferenceEngine:
         n_done0 = len(self.finished)
         with timeline.phase(
                 "engine.decode.fetch", seq=self._burst_seq, k=K + 1,
-                parts=len(parts), waiting=len(self.waiting)) as fetch_ph:
-            fetched = [(slots, np.asarray(t), np.asarray(c))
-                       for slots, t, c in parts]   # [B, K+1] / [B]
+                parts=1, waiting=len(self.waiting)) as fetch_ph:
+            toks = np.asarray(toks_dev)          # [B, K+1]
+            n_commit = np.asarray(commit_dev)    # [B]
             span.end()
             end_s = time.time()
             SPEC_VERIFY_WALL.inc(max(end_s - span.begin_s, 0.0))
             out: Dict[int, List[int]] = {}
             n_emitted = accepted = 0
             model_drafted = ngram_drafted = 0
-            for part_i, ((slots, toks, n_commit), sarg) in enumerate(
-                    zip(fetched, part_spans)):
-                grp_emitted = grp_drafted = grp_accepted = 0
-                grp_reqs: List[Request] = []
-                grp_kinds = set()
-                for slot in slots:
-                    req = self.slot_req.get(slot)
-                    if req is None or req.done:
-                        continue
-                    nd = dlen.get(slot, 0)
-                    nc = int(n_commit[slot])
-                    emitted: List[int] = []
-                    for i in range(nc):
-                        tok = int(toks[slot, i])
-                        emitted.append(tok)
-                        req.tokens.append(tok)
-                        if self._req_finished(req, tok):
-                            self._retire(req)
-                            break
-                    # Accepted = matched draft tokens the request actually
-                    # emitted: the first nc-1 outputs are the matched run,
-                    # the nc-th the correction/bonus — an early EOS/budget
-                    # retire discards the tail, and counting the full run
-                    # would inflate the trailer stats and the acceptance
-                    # gauge on EOS-heavy workloads.
-                    acc = min(len(emitted), nc - 1)
-                    req.spec_drafted += nd
-                    req.spec_accepted += acc
-                    req.spec_mode_drafted += nd
-                    req.spec_mode_accepted += acc
-                    if nd:
-                        if slot in model_reqs:
-                            model_drafted += nd
-                            grp_kinds.add("model")
-                        else:
-                            ngram_drafted += nd
-                            grp_kinds.add("ngram")
-                    accepted += acc
-                    out[req.rid] = emitted
-                    n_emitted += len(emitted)
-                    grp_emitted += len(emitted)
-                    grp_drafted += nd
-                    grp_accepted += acc
-                    grp_reqs.append(req)
-                self._record_flight(
-                    "verify", begin_s=span.begin_s, end_s=end_s,
-                    program={"k": K, "span": sarg},
-                    slots=slots, reqs=grp_reqs, toks=grp_emitted,
-                    drafted=grp_drafted, accepted=grp_accepted,
-                    drafter=("mixed" if len(grp_kinds) > 1
-                             else next(iter(grp_kinds), None)),
-                    overlap_ms=round(overlap_s * 1e3, 3),
-                    dispatch_s=dispatch_done_s,
-                    dev_keys=[part_keys[part_i]] if part_i < len(part_keys)
-                    else None)
+            live_reqs: List[Request] = []
+            for slot in slots:
+                req = self.slot_req.get(slot)
+                if req is None or req.done:
+                    continue
+                nd = dlen.get(slot, 0)
+                nc = int(n_commit[slot])
+                emitted: List[int] = []
+                for i in range(nc):
+                    tok = int(toks[slot, i])
+                    emitted.append(tok)
+                    req.tokens.append(tok)
+                    if self._req_finished(req, tok):
+                        self._retire(req)
+                        break
+                # Accepted = matched draft tokens the request actually
+                # emitted: the first nc-1 outputs are the matched run,
+                # the nc-th the correction/bonus — an early EOS/budget
+                # retire discards the tail, and counting the full run
+                # would inflate the trailer stats and the acceptance
+                # gauge on EOS-heavy workloads.
+                acc = min(len(emitted), nc - 1)
+                req.spec_drafted += nd
+                req.spec_accepted += acc
+                req.spec_mode_drafted += nd
+                req.spec_mode_accepted += acc
+                if slot in model_reqs:
+                    model_drafted += nd
+                else:
+                    ngram_drafted += nd
+                accepted += acc
+                out[req.rid] = emitted
+                n_emitted += len(emitted)
+                live_reqs.append(req)
+            self._record_flight(
+                "verify", begin_s=span.begin_s, end_s=end_s,
+                program={"k": K, "span": sarg},
+                slots=slots, reqs=live_reqs, toks=n_emitted,
+                drafted=model_drafted + ngram_drafted,
+                accepted=accepted,
+                drafter=("mixed" if model_drafted and ngram_drafted
+                         else "model" if model_drafted
+                         else "ngram" if ngram_drafted else None),
+                overlap_ms=round(overlap_s * 1e3, 3),
+                dispatch_s=dispatch_done_s, dev_keys=[verify_key])
             fetch_ph.set(tokens=n_emitted,
                          retired=len(self.finished) - n_done0)
         if model_drafted:
@@ -3657,46 +3635,38 @@ class InferenceEngine:
         if k < 1 or need < 1:
             return None
         k = 1 << (k.bit_length() - 1)
-        # Span regrouping: one program per span bucket present among
-        # the active slots, so a single long conversation promotes
-        # only ITS group to the big gather (lazy mode also grows each
-        # slot's blocks here; unbackable slots sit the round out).
-        groups = self._span_groups(k)
-        if not groups:
+        # ONE program for the round: every slot the pool backs (lazy
+        # mode grows each slot's blocks here; unbackable slots sit the
+        # round out), at the rung covering the longest of them.
+        attn_span, slots, promoted = self._round_slots(k)
+        if not slots:
             return None            # lazy: pool dry — retry next round
         ev = timeline.Event("skytpu_decode_step_seconds",
                             histogram=DECODE_STEP_SECONDS)
         ev.begin()
-        parts: List[Tuple[jax.Array, List[int]]] = []
-        part_spans: List[Optional[int]] = []
-        part_keys: List[Optional[str]] = []
         self._burst_seq += 1
-        for attn_span, slots in groups:
-            active = np.zeros((self.n_slots + 1,), bool)
-            for s in slots:
-                active[s] = True
-            sarg = self._span_arg(attn_span)
-            self.decode_programs.add(("burst", k, sarg))
-            DECODE_ATTN_ROWS.observe(attn_span)
-            # One annotation per PROGRAM launched: its k steps run at
-            # ``rows`` batch rows of which ``slots`` are live.
-            with timeline.phase(
-                    "engine.decode.dispatch", seq=self._burst_seq, k=k,
-                    slots=len(slots), rows=self.n_slots + 1,
-                    span=attn_span, why=why,
-                    waiting=len(self.waiting)):
-                self.cache, self.rng, toks = self._decode_burst_fn(
-                    self.params, self.cache, self.rng,
-                    jnp.asarray(active), self.table_device(), k=k,
-                    qweights=self.qweights, span=sarg,
-                    kernel=self.kv_kernel, **self._lora_args())
-            parts.append((toks, slots))
-            part_spans.append(sarg)
-            part_keys.append(self.compile_watch.last_key)
+        active = np.zeros((self.n_slots + 1,), bool)
+        active[slots] = True
+        sarg = self._span_arg(attn_span)
+        self.decode_programs.add(("burst", k, sarg))
+        DECODE_ATTN_ROWS.observe(attn_span)
+        # The program's k steps run at ``rows`` batch rows of which
+        # ``slots`` are live, ``promoted`` of them above their own rung.
+        with timeline.phase(
+                "engine.decode.dispatch", seq=self._burst_seq, k=k,
+                slots=len(slots), rows=self.n_slots + 1,
+                span=attn_span, promoted=promoted, why=why,
+                waiting=len(self.waiting)):
+            self.cache, self.rng, toks = self._decode_burst_fn(
+                self.params, self.cache, self.rng,
+                jnp.asarray(active), self.table_device(), k=k,
+                qweights=self.qweights, span=sarg,
+                kernel=self.kv_kernel, **self._lora_args())
         self._inflight_tokens += k
-        return BurstHandle(parts=parts, k=k,
+        return BurstHandle(toks=toks, slots=slots, k=k,
                            slot_req=dict(self.slot_req), span=ev,
-                           spans=part_spans, keys=part_keys,
+                           span_arg=sarg,
+                           key=self.compile_watch.last_key,
                            dispatch_done_s=time.time(),
                            seq=self._burst_seq)
 
@@ -3706,33 +3676,32 @@ class InferenceEngine:
         bookkeeping: append/retire per request, using the slot->request
         snapshot taken at dispatch. Requests retired by an earlier
         completion are skipped (their surplus tokens are discarded);
-        slots a lazy dry pool kept out of the burst simply have no
-        part and emit nothing this round."""
+        slots a lazy dry pool kept out of the burst are not in the
+        handle and emit nothing this round."""
         with _dispatch_boundary("decode"):
             return self._complete_decode_burst_impl(handle)
 
     def _complete_decode_burst_impl(self, handle: "BurstHandle"
                                     ) -> Dict[int, List[int]]:
         with timeline.phase("engine.decode.fetch", seq=handle.seq,
-                            k=handle.k, parts=len(handle.parts),
+                            k=handle.k, parts=1,
                             waiting=len(self.waiting)) as ph:
-            fetched = [(np.asarray(toks_dev), slots)
-                       for toks_dev, slots in handle.parts]
+            toks = np.asarray(handle.toks)       # [k, slots+1]
             if handle.span is not None:
                 handle.span.end()
             before = len(self.finished)
             with timeline.phase("engine.decode.commit"):
-                out, n_emitted = self._commit_burst(handle, fetched)
+                out, n_emitted = self._commit_burst(handle, toks)
             ph.set(tokens=n_emitted,
                    retired=len(self.finished) - before)
         if n_emitted:
             DECODE_TOKENS.inc(n_emitted)
         return out
 
-    def _commit_burst(self, handle: "BurstHandle", fetched
+    def _commit_burst(self, handle: "BurstHandle", toks: np.ndarray
                       ) -> Tuple[Dict[int, List[int]], int]:
         """Host bookkeeping of a fetched burst: append / retire per
-        request and write the flight records. Returns ({rid: tokens},
+        request and write the flight record. Returns ({rid: tokens},
         tokens emitted and kept)."""
         end_s = time.time()
         begin_s = (handle.span.begin_s if handle.span is not None
@@ -3740,55 +3709,37 @@ class InferenceEngine:
         self._inflight_tokens -= handle.k
         out: Dict[int, List[int]] = {}
         n_emitted = 0
-        for part_i, (toks, slots) in enumerate(fetched):
-            # toks: [k, slots+1]
-            part_emitted = 0
-            part_reqs: List[Request] = []
-            for slot in slots:
-                req = handle.slot_req.get(slot)
-                if req is None or req.done:
-                    continue
-                emitted = []
-                for i in range(handle.k):
-                    tok = int(toks[i, slot])
-                    emitted.append(tok)
-                    req.tokens.append(tok)
-                    if self._req_finished(req, tok):
-                        self._retire(req)
-                        break
-                out[req.rid] = emitted
-                part_emitted += len(emitted)
-                part_reqs.append(req)
-            n_emitted += part_emitted
-            self._record_flight(
-                "decode", begin_s=begin_s, end_s=end_s,
-                program={"k": handle.k,
-                         "span": (handle.spans[part_i]
-                                  if part_i < len(handle.spans)
-                                  else None)},
-                slots=slots, reqs=part_reqs, toks=part_emitted,
-                dispatch_s=handle.dispatch_done_s,
-                dev_keys=([handle.keys[part_i]]
-                          if part_i < len(handle.keys) else None))
+        live_reqs: List[Request] = []
+        for slot in handle.slots:
+            req = handle.slot_req.get(slot)
+            if req is None or req.done:
+                continue
+            emitted = []
+            for i in range(handle.k):
+                tok = int(toks[i, slot])
+                emitted.append(tok)
+                req.tokens.append(tok)
+                if self._req_finished(req, tok):
+                    self._retire(req)
+                    break
+            out[req.rid] = emitted
+            n_emitted += len(emitted)
+            live_reqs.append(req)
+        self._record_flight(
+            "decode", begin_s=begin_s, end_s=end_s,
+            program={"k": handle.k, "span": handle.span_arg},
+            slots=handle.slots, reqs=live_reqs, toks=n_emitted,
+            dispatch_s=handle.dispatch_done_s, dev_keys=[handle.key])
         return out, n_emitted
 
     def step_decode_once(self) -> Dict[int, int]:
-        """One single-token decode for all active slots (no admission).
-        Runs at ONE span — the bucket covering the longest active slot
-        (the single-step path is the classic-semantics fallback; the
-        burst path is where regrouping pays)."""
+        """One single-token decode for all active slots (no admission)
+        — the classic-semantics fallback, at the same one span a burst
+        round takes."""
         if not self.slot_req:
             return {}
-        active = np.zeros((self.n_slots + 1,), bool)
-        rows_max = n_live = 0
-        for s, req in self.slot_req.items():
-            if not self._ensure_headroom(s, req,
-                                         self._slot_rows(req) + 1):
-                continue            # lazy: pool dry — sits this out
-            active[s] = True
-            n_live += 1
-            rows_max = max(rows_max, self._slot_rows(req))
-        if not rows_max:
+        attn_span, slots, promoted = self._round_slots(1)
+        if not slots:
             # Lazy mode only (eager slots always have headroom): the
             # sync single-step path has no outstanding burst whose
             # completion could free blocks, so an all-slots-unbackable
@@ -3798,7 +3749,9 @@ class InferenceEngine:
                 "KV block pool exhausted: lazy growth cannot back any "
                 "active slot — size SKYTPU_KV_BLOCKS for the live "
                 "working set or disable SKYTPU_KV_LAZY")
-        sarg = self._span_arg(self._span_for(rows_max))
+        active = np.zeros((self.n_slots + 1,), bool)
+        active[slots] = True
+        sarg = self._span_arg(attn_span)
         self.decode_programs.add(("decode1", 1, sarg))
         ev = timeline.Event("skytpu_decode_step_seconds",
                             histogram=DECODE_STEP_SECONDS)
@@ -3806,8 +3759,8 @@ class InferenceEngine:
         self._burst_seq += 1
         with timeline.phase(
                 "engine.decode.dispatch", seq=self._burst_seq, k=1,
-                slots=n_live, rows=self.n_slots + 1,
-                span=self._span_for(rows_max), why="step",
+                slots=len(slots), rows=self.n_slots + 1,
+                span=attn_span, promoted=promoted, why="step",
                 waiting=len(self.waiting)):
             self.cache, self.rng, toks = self._decode_fn(
                 self.params, self.cache, self.rng, jnp.asarray(active),
@@ -3822,16 +3775,11 @@ class InferenceEngine:
             toks = np.asarray(toks)
             ev.end()
             out: Dict[int, int] = {}
-            step_slots: List[int] = []
-            step_reqs: List[Request] = []
-            for slot, req in list(self.slot_req.items()):
-                if not active[slot]:
-                    continue
+            step_reqs = [self.slot_req[s] for s in slots]
+            for slot, req in zip(slots, step_reqs):
                 tok = int(toks[slot])
                 req.tokens.append(tok)
                 out[req.rid] = tok
-                step_slots.append(slot)
-                step_reqs.append(req)
                 if self._req_finished(req, tok):
                     self._retire(req)
             fetch_ph.set(tokens=len(out),
@@ -3840,7 +3788,7 @@ class InferenceEngine:
         self._record_flight(
             "decode1", begin_s=ev.begin_s, end_s=time.time(),
             program={"k": 1, "span": sarg},
-            slots=step_slots, reqs=step_reqs, toks=len(out),
+            slots=slots, reqs=step_reqs, toks=len(out),
             dispatch_s=t_disp, dev_keys=[step_key])
         return out
 

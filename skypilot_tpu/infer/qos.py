@@ -20,7 +20,7 @@ count can never enter program identity):
   one tenant's hundred queued requests cannot starve a neighbor's one.
   Priority lanes sort strictly above the DRR interleave; WFQ applies
   within a lane. The scheduler only REORDERS the deque before an
-  admission pass — bucketed waves, chunked claims and span regrouping
+  admission pass — bucketed waves, chunked claims and span selection
   downstream are untouched.
 
 * Priority preemption-by-eviction lives in the engine

@@ -8,13 +8,14 @@ import numpy as np
 
 
 class InferenceEngine:
-    def _span_groups(self, width):
+    def _round_slots(self, width):
         lengths = np.asarray(self.cache["length"])  # expect: host-sync
-        groups = {}
+        rungs = {}
         for slot in self.slot_req:
             rows = int(self.cache["length"][slot])  # expect: host-sync
-            groups.setdefault(self._span_for(rows), []).append(slot)
-        return sorted(groups.items()), lengths
+            rungs[slot] = self._span_for(rows)
+        span = max(rungs.values(), default=0)
+        return span, list(rungs), lengths
 
     def _ensure_headroom(self, slot, req, need_rows):
         used = self.cache["length"].item()          # expect: host-sync

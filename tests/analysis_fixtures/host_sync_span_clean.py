@@ -9,14 +9,15 @@ class InferenceEngine:
         return (len(req.prompt) + len(req.tokens)
                 + self._inflight_tokens)
 
-    def _span_groups(self, width):
-        groups = {}
+    def _round_slots(self, width):
+        rungs = {}
         for slot, req in self.slot_req.items():
             rows = self._slot_rows(req)
-            if not self._ensure_headroom(slot, req, rows + width):
-                continue
-            groups.setdefault(self._span_for(rows), []).append(slot)
-        return sorted(groups.items())
+            if self._ensure_headroom(slot, req, rows + width):
+                rungs[slot] = self._span_for(rows)
+        span = max(rungs.values(), default=0)
+        return (span, list(rungs),
+                sum(1 for r in rungs.values() if r < span))
 
     def _ensure_headroom(self, slot, req, need_rows):
         row = self.block_table[slot]

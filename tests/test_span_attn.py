@@ -1,5 +1,6 @@
 """Span-bucketed decode attention: ladder selection, bit parity vs
-the full view, retrace discipline, regrouping, lazy block growth.
+the full view, retrace discipline, one program a round, lazy block
+growth.
 
 Tier-1 guards for the PR-9 bandwidth refactor (ROADMAP item 1's
 follow-up to the paged cache):
@@ -11,8 +12,9 @@ follow-up to the paged cache):
 * Retrace discipline: a mixed-length run compiles at most one
   decode/verify program per span-ladder rung — never one per observed
   length.
-* Regrouping: a single long slot in a burst promotes only ITS group's
-  bucket; short neighbors keep their small-span reads.
+* One program a round: a mixed-length round dispatches ONE burst or
+  verify program, at the rung of its longest live slot, for every
+  slot the pool backs; its tokens equal the full view's.
 * Lazy growth (SKYTPU_KV_LAZY): admission reserves prompt + one burst
   of blocks, growth happens at dispatch, and the existing block-leak
   audits still hold (admit/retire -> clear -> 0 blocks used).
@@ -172,36 +174,131 @@ def test_program_count_bounded_by_ladder(params, cfg):
         assert span is None or span in e.span_ladder
 
 
-# -- regrouping -------------------------------------------------------------
+# -- one program a round ----------------------------------------------------
 
-def test_single_long_slot_promotes_only_its_group(params, cfg):
-    """One long conversation in a mixed burst rides the big bucket
-    ALONE; its short neighbors keep their small-span programs."""
-    rng = np.random.default_rng(2)
-    short = [rng.integers(1, cfg.vocab_size, 4).tolist()
+def _short_and_long(cfg, seed=2):
+    rng = np.random.default_rng(seed)
+    return ([rng.integers(1, cfg.vocab_size, 4).tolist()
              for _ in range(3)]
-    long_p = rng.integers(1, cfg.vocab_size, 30).tolist()
+            + [rng.integers(1, cfg.vocab_size, 30).tolist()])
+
+
+def _count_calls(engine, attr):
+    """Count the launches of one of the engine's device programs."""
+    calls = []
+    fn = getattr(engine, attr)
+
+    def counted(*a, **kw):
+        calls.append(kw.get("span"))
+        return fn(*a, **kw)
+
+    setattr(engine, attr, counted)
+    return calls
+
+
+def test_mixed_round_is_one_program_at_the_longest_rung(params, cfg):
+    """Three short conversations and one long one decode in ONE burst
+    program, at the long slot's rung, and all four get their tokens."""
     e = _engine(params, cfg, span_buckets=(8, 16), slots=4)
     assert e.span_ladder == (8, 16, 64)
+    *short, long_p = _short_and_long(cfg)
     for p in short:
-        e.add_request(p, max_new_tokens=8)
-    e.add_request(long_p, max_new_tokens=8)
+        e.add_request(p, max_new_tokens=12)
+    long_rid = e.add_request(long_p, max_new_tokens=4)
     e.admit()
     while e.chunking:
         e.prefill_chunk_step()
-    groups = e._span_groups(8)
-    assert len(groups) == 2
-    (span_s, slots_s), (span_l, slots_l) = groups
-    assert span_s in (8, 16) and len(slots_s) == 3
-    assert span_l == 64 and len(slots_l) == 1
-    # Dispatch + complete: the short group really ran a small-span
-    # program, the long group the full view; outputs land for all.
+    span, slots, promoted = e._round_slots(4)
+    assert span == 64 and sorted(slots) == sorted(e.slot_req)
+    assert promoted == 3           # the short slots ride above their rung
+    launches = _count_calls(e, "_decode_burst_fn")
     handle = e.dispatch_decode_burst(max_burst=4)
+    assert launches == [None]      # one launch: the max_len rung
+    assert sorted(handle.slots) == sorted(slots)
     out = e.complete_decode_burst(handle)
     assert len(out) == 4
-    kinds = {(k, s) for k, _, s in e.decode_programs if k == "burst"}
-    assert ("burst", span_s) in kinds
-    assert ("burst", None) in kinds          # long slot: max_len rung
+    assert all(len(t) == 4 for rid, t in out.items() if rid != long_rid)
+    # The long conversation has retired at its budget: the next round
+    # of short slots runs a small-span program again, nobody promoted.
+    assert len(e.slot_req) == 3
+    span, slots, promoted = e._round_slots(4)
+    assert (span, promoted) == (16, 0)
+    assert sorted(slots) == sorted(e.slot_req)
+    handle = e.dispatch_decode_burst(max_burst=4)
+    assert launches == [None, 16]
+    assert len(e.complete_decode_burst(handle)) == 3
+
+
+@pytest.mark.parametrize("kv_block", [8, 0], ids=["paged", "contig"])
+@pytest.mark.parametrize("spec_k", [0, 3], ids=["spec-off", "spec-on"])
+def test_one_program_round_matches_full_view(params, cfg, kv_block,
+                                             spec_k):
+    """The mixed round's greedy tokens equal the full-view engine's:
+    which rung a slot rode does not reach its logits. Every decode
+    round of the span engine is one launch."""
+    prompts = _short_and_long(cfg)
+
+    def run(span_buckets):
+        e = _engine(params, cfg, span_buckets=span_buckets,
+                    kv_block=kv_block, spec_k=spec_k, slots=4)
+        bursts = _count_calls(e, "_decode_burst_fn")
+        verifies = _count_calls(e, "_verify_fn")
+        outs = e.generate(prompts, max_new_tokens=12)
+        return outs, bursts + verifies, e._burst_seq
+
+    out_span, launches, rounds = run((8, 16))
+    out_full, _, _ = run(0)
+    assert out_span == out_full
+    assert len(launches) == rounds > 0
+    assert None in launches and any(s is not None for s in launches)
+
+
+def test_lazy_unbackable_slot_sits_one_round_out(params, cfg):
+    """A lazy engine whose pool cannot back one slot leaves that slot
+    out of the round's one program and serves it once a retirement
+    has freed blocks; the tokens equal the eager engine's."""
+    prompts = _mixed_prompts(cfg, lengths=(5, 9))
+    budgets = (24, 40)
+
+    def admitted(**kw):
+        e = _engine(params, cfg, kv_block=8, slots=2, prefix_pool=0,
+                    **kw)
+        for p, m in zip(prompts, budgets):
+            e.add_request(p, max_new_tokens=m)
+        e.admit()
+        while e.chunking:
+            e.prefill_chunk_step()
+        return e
+
+    # 8 blocks: admission takes 3 + 4 (prompt + 16 rows of headroom),
+    # the short request grows into the last one, and the long one
+    # finds the pool dry until the short one retires.
+    lazy = admitted(kv_lazy=True, kv_blocks=8)
+    launches = _count_calls(lazy, "_decode_burst_fn")
+    sat_out = served_after = None
+    rounds = 0
+    while lazy.slot_req:
+        live = dict(lazy.slot_req)
+        handle = lazy.dispatch_decode_burst(max_burst=4)
+        assert handle is not None, "pool wedged"
+        rounds += 1
+        out = lazy.complete_decode_burst(handle)
+        left = set(live) - set(handle.slots)
+        if left and sat_out is None:
+            (slot,) = left
+            sat_out = live[slot]
+            assert sat_out.rid not in out
+        elif sat_out is not None and served_after is None \
+                and sat_out.rid in out:
+            served_after = rounds
+    assert sat_out is not None and served_after is not None
+    assert len(launches) == rounds          # one program a round
+    eager = admitted(kv_lazy=False)
+    while eager.slot_req:
+        eager.decode_burst(max_burst=4)
+    assert ({r.rid: r.tokens for r in lazy.finished}
+            == {r.rid: r.tokens for r in eager.finished})
+    assert lazy.blocks_used == 0
 
 
 # -- lazy block growth ------------------------------------------------------

@@ -159,6 +159,29 @@ def test_decode_dispatches_pair_with_their_fetches_by_seq(served):
                for a in _named(anns, "engine.decode.dispatch"))
 
 
+def test_a_decode_round_is_one_program(served):
+    anns, _, _ = served
+    fetches = _named(anns, "engine.decode.fetch")
+    assert fetches and all(a[4]["parts"] == 1 for a in fetches)
+    seqs = [a[4]["seq"] for a in _named(anns, "engine.decode.dispatch")]
+    assert sorted(seqs) == sorted(a[4]["seq"] for a in fetches)
+    assert len(set(seqs)) == len(seqs)
+
+
+def test_promoted_counts_the_slots_above_their_own_rung(served):
+    anns, _, _ = served
+    dispatches = _named(anns, "engine.decode.dispatch")
+    for a in dispatches:
+        assert 0 <= a[4]["promoted"] < a[4]["slots"]
+        assert a[4]["span"] in (16, 32, 64, 128)
+    # prompts of 5 to 70 tokens decode side by side on a ladder of
+    # 16 / 32 / 64 / 128 rows: some round mixes rungs
+    assert any(a[4]["promoted"] > 0 for a in dispatches)
+    # a slot alone in its round rides its own rung
+    assert all(a[4]["promoted"] == 0 for a in dispatches
+               if a[4]["slots"] == 1)
+
+
 def test_loop_annotations_come_from_one_thread(served):
     anns, _, _ = served
     loop = {a[3] for a in anns if a[0].startswith(("server.", "engine."))}
