@@ -31,6 +31,16 @@ from skypilot_tpu.analysis.findings import Finding
 
 _ROOT_DIRS = ("skypilot_tpu/infer/", "skypilot_tpu/train/")
 
+# The serve programs of a model family are reached through a HANDLE,
+# not a module alias: ``progs = kvcache.programs_for(cfg)`` and then
+# ``progs.prefill_batch(...)`` inside the engine's jitted entry points.
+# A call on such a handle may land in any of these modules (and, so
+# that a fixture can stand alone, in the calling module itself): the
+# call graph follows it into all of them.
+_FAMILY_SELECTOR = "programs_for"
+_FAMILY_MODULES = ("skypilot_tpu.infer.kvcache",
+                   "skypilot_tpu.infer.latent")
+
 # jnp constructors whose first argument is a shape.
 _SHAPE_CTORS = {"zeros", "ones", "full", "empty", "eye"}
 _RANGE_CTORS = {"arange", "linspace"}
@@ -71,6 +81,20 @@ def _has_jit_decorator(func: ast.AST) -> bool:
     return False
 
 
+def _family_handles(tree: ast.AST) -> Set[str]:
+    """Names bound to ``<module>.programs_for(...)`` anywhere in a
+    file (``self._progs = progs = kvcache.programs_for(cfg)``)."""
+    out: Set[str] = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Assign) \
+                and isinstance(node.value, ast.Call) \
+                and (_util.dotted(node.value.func) or "").split(
+                    ".")[-1] == _FAMILY_SELECTOR:
+            out.update(t.id for t in node.targets
+                       if isinstance(t, ast.Name))
+    return out
+
+
 def _module_key(rel: str) -> str:
     return rel[:-3].replace("/", ".")
 
@@ -109,13 +133,19 @@ class RetraceSafetyChecker(Checker):
     # dir and kvcache.sync_slots joined the reachable surface; the
     # bump rescans the edited spec programs and the new draft
     # fixtures cold.
-    version = 5
+    # v6: a second family of serve programs (infer/latent.py: the MLA
+    # latent cache and the dropless expert layer of models/glm_moe.py)
+    # is reached through ``kvcache.programs_for(cfg)``; calls on that
+    # handle are followed into every family module, which keeps
+    # kvcache's own programs reachable from the engine's roots too.
+    version = 6
 
     def check_project(self, ctxs: Sequence[FileContext],
                       root: str) -> List[Finding]:
         # Symbol table: dotted module -> {func name -> _FuncInfo}.
         by_module: Dict[str, Dict[str, _FuncInfo]] = {}
         aliases: Dict[str, Dict[str, str]] = {}
+        handles: Dict[str, Set[str]] = {}
         for ctx in ctxs:
             mod = _module_key(ctx.rel)
             funcs: Dict[str, _FuncInfo] = {}
@@ -126,6 +156,7 @@ class RetraceSafetyChecker(Checker):
                     funcs[leaf] = _FuncInfo(ctx, qual, node)
             by_module[mod] = funcs
             aliases[ctx.rel] = ctx.import_aliases
+            handles[ctx.rel] = _family_handles(ctx.tree)
 
         # Roots: jitted functions/lambdas in infer/ and train/.
         roots: List[Tuple[FileContext, str, ast.AST]] = []
@@ -174,11 +205,17 @@ class RetraceSafetyChecker(Checker):
                 if name.split(".")[-1] == "partial" and sub.args \
                         and not _is_jit_expr(sub.args[0]):
                     target = sub.args[0]
-                info = self._resolve(target, mod, file_aliases,
-                                     by_module)
-                if info is not None and id(info.node) not in seen:
-                    seen.add(id(info.node))
-                    queue.append((info.ctx, info.qual, info.node))
+                found = [self._resolve(target, mod, file_aliases,
+                                       by_module)]
+                if isinstance(target, ast.Attribute) \
+                        and isinstance(target.value, ast.Name) \
+                        and target.value.id in handles.get(ctx.rel, ()):
+                    found += [by_module.get(m, {}).get(target.attr)
+                              for m in _FAMILY_MODULES + (mod,)]
+                for info in found:
+                    if info is not None and id(info.node) not in seen:
+                        seen.add(id(info.node))
+                        queue.append((info.ctx, info.qual, info.node))
 
         findings: List[Finding] = []
         for ctx, qual, node in reached:

@@ -32,7 +32,7 @@ from skypilot_tpu import chaos
 from skypilot_tpu.infer import adapters as adapters_lib
 from skypilot_tpu.infer import kvcache, sampling
 from skypilot_tpu.infer import qos as qos_lib
-from skypilot_tpu.models import llama
+from skypilot_tpu.models import llama, registry
 from skypilot_tpu.observability import attribution as attribution_lib
 from skypilot_tpu.observability import flight as flight_lib
 from skypilot_tpu.observability import forensics as forensics_lib
@@ -105,6 +105,11 @@ KV_BLOCKS_TOTAL = metrics.gauge(
     "skytpu_kv_blocks_total",
     "Paged KV cache: physical blocks in the pool (0 when the engine "
     "runs the contiguous layout)")
+KV_TOKEN_BYTES = metrics.gauge(
+    "skytpu_kv_cache_bytes_per_token",
+    "Cache bytes one token holds over all layers, from the cache's own "
+    "tensors: 2 x layers x kv_heads x head_dim (+ scales) for per-head "
+    "K/V, layers x (kv_lora_rank + rope dim) for a latent (MLA) cache")
 KV_BLOCKS_USED = metrics.gauge(
     "skytpu_kv_blocks_used",
     "Paged KV cache: blocks currently referenced by decode slots "
@@ -381,6 +386,43 @@ class WeightsDoNotFitError(ValueError):
             "need_bytes": need_bytes,
             "limit_bytes": limit_bytes,
         }
+
+
+class UnsupportedOptionError(ValueError):
+    """An engine option the configuration's model family does not
+    serve (yet): a typed start-up refusal naming the option, raised
+    before anything is allocated — never a silent fallback to a path
+    that computes something else."""
+
+    def __init__(self, option: str, family: str, why: str):
+        super().__init__(
+            f"{option} is not supported for the {family} family: {why}")
+        self.typed_error = {
+            "type": "unsupported_option",
+            "message": str(self),
+            "option": option,
+            "family": family,
+        }
+
+
+def refuse_latent_options(**given) -> None:
+    """The latent-cache family (``infer/latent.py``) serves float
+    weights from a paged latent cache on one device; everything else
+    is refused by name (docs/serving.md §Latent cache lists them)."""
+    why = {
+        "kv_block=0": "the latent cache is paged only",
+        "kv_int8": "latent rows have no int8 form",
+        "weights_int8": "the expert and MLA matrices have no int8 form",
+        "tp": "no latent cache or expert layer under a mesh",
+        "adapters": "no LoRA targets in the MLA projections",
+        "spec_k": "no verify program over the latent cache",
+        "draft_model": "no verify program over the latent cache",
+        "kv_kernel": "the paged-attention kernel reads per-head K/V",
+    }
+    for option, on in given.items():
+        if on:
+            raise UnsupportedOptionError(option, "latent-cache (MLA)",
+                                         why[option])
 
 
 class KvPoolWedgedError(RuntimeError):
@@ -702,6 +744,27 @@ class InferenceEngine:
         # admission order stays pure FIFO and nothing ever preempts.
         self.qos = qos
         self.cfg = cfg
+        # The serve programs of the config's family: kvcache's own
+        # (GQA rows) or infer/latent.py's (MLA latent rows). Every
+        # jitted entry point below calls through this, and everything
+        # that moves blocks without reading rows is shared.
+        self._progs = progs = kvcache.programs_for(cfg)
+        self.latent = progs is not kvcache
+        if self.latent:
+            refuse_latent_options(**{
+                "kv_block=0": kv_block == 0 or (
+                    kv_block is None and os.environ.get(
+                        "SKYTPU_KV_BLOCK", "256") in ("", "0")),
+                "kv_int8": kv_int8,
+                "weights_int8": weights_int8 or qweights is not None,
+                "tp": mesh is not None,
+                "adapters": adapters is not None,
+                "spec_k": (spec_k if spec_k is not None else int(
+                    os.environ.get("SKYTPU_SPEC_K", "0") or 0)) > 0,
+                "draft_model": draft_engine is not None,
+                "kv_kernel": bool(kv_kernel) or (
+                    kv_kernel is None
+                    and os.environ.get("SKYTPU_KV_KERNEL", "") == "1")})
         self.n_slots = n_slots
         self.max_len = max_len
         self.buckets = tuple(b for b in prompt_buckets if b <= max_len)
@@ -956,7 +1019,7 @@ class InferenceEngine:
         # table row stays all-sentinel — dummy writes drop, zero block
         # cost.)
         if self.paged:
-            build_cache = lambda: kvcache.init_paged_cache(
+            build_cache = lambda: progs.init_paged_cache(
                 cfg, n_slots + 1, self.n_kv_blocks, self.kv_block,
                 kv_int8=kv_int8)
         else:
@@ -1053,10 +1116,18 @@ class InferenceEngine:
         # model behind the MFU / bandwidth-utilization columns. KV
         # bytes-per-token is computed from the ACTUAL cache dtypes
         # (int8 KV counts its fp32 scales).
-        itemsize = self.cache["k"].dtype.itemsize
-        G, hd, L = cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
-        self._kv_token_bytes = 2 * L * G * hd * itemsize \
-            + (2 * L * G * 4 if "k_scale" in self.cache else 0)
+        L = cfg.n_layers
+        if self.latent:
+            hd = cfg.qk_head_dim
+            self._kv_token_bytes = progs.token_bytes(cfg)
+            param_count = cfg.active_params()
+        else:
+            itemsize = self.cache["k"].dtype.itemsize
+            G, hd = cfg.n_kv_heads, cfg.head_dim
+            self._kv_token_bytes = 2 * L * G * hd * itemsize \
+                + (2 * L * G * 4 if "k_scale" in self.cache else 0)
+            param_count = cfg.num_params()
+        KV_TOKEN_BYTES.set(self._kv_token_bytes)
         self._kv_block_bytes = (self._kv_token_bytes * self.kv_block
                                 if self.paged else 0)
         weight_bytes = (attribution_lib.tensor_bytes(self.params)
@@ -1064,7 +1135,7 @@ class InferenceEngine:
         self.hbm_ledger = attribution_lib.HbmLedger()
         self._weight_bytes = weight_bytes
         self.roofline = attribution_lib.Roofline(
-            param_count=cfg.num_params(), weight_bytes=weight_bytes,
+            param_count=param_count, weight_bytes=weight_bytes,
             kv_token_bytes=self._kv_token_bytes, d_model=cfg.d_model,
             n_layers=L, n_heads=cfg.n_heads, head_dim=hd,
             max_len=max_len, chunk_tokens=self.prefill_chunk)
@@ -1147,19 +1218,17 @@ class InferenceEngine:
             del bucket
             from jax import lax as _lax
             rng, sub = jax.random.split(rng)
-            prefix, logits = kvcache.prefill_batch(
+            prefix, logits = progs.prefill_batch(
                 params, tokens_b, true_lens, cfg, qweights=qweights,
                 lora=lora, aid=aid, mesh=mesh, heads_axis=heads_axis)
             with jax.named_scope("sample"):
                 first = sampling.sample(logits, sub, sp)      # [W]
 
             def ins(c, w):
-                pk = _lax.dynamic_index_in_dim(prefix["k"], w, 1,
-                                               keepdims=False)
-                pv = _lax.dynamic_index_in_dim(prefix["v"], w, 1,
-                                               keepdims=False)
-                c = kvcache.insert(c, {"k": pk, "v": pv}, slots[w],
-                                   true_lens[w], first[w], table=table)
+                rows = {name: _lax.dynamic_index_in_dim(
+                    t, w, 1, keepdims=False) for name, t in prefix.items()}
+                c = progs.insert(c, rows, slots[w], true_lens[w],
+                                 first[w], table=table)
                 return c, None
 
             cache, _ = _lax.scan(ins, cache,
@@ -1172,7 +1241,7 @@ class InferenceEngine:
         def _decode(params, cache, rng, active, table=None,
                     lora=None, aid=None, qweights=None, *, span=None):
             rng, sub = jax.random.split(rng)
-            cache, logits = kvcache.decode_step(params, cache, cfg,
+            cache, logits = progs.decode_step(params, cache, cfg,
                                                 qweights=qweights,
                                                 table=table, span=span,
                                                 lora=lora, aid=aid)
@@ -1194,7 +1263,7 @@ class InferenceEngine:
         def _decode_burst(params, cache, rng, active, table=None,
                           lora=None, aid=None, *, k,
                           qweights=None, span=None, kernel=False):
-            return kvcache.decode_burst_staged(
+            return progs.decode_burst_staged(
                 params, cache, rng, active, k, cfg, sp,
                 qweights=qweights, table=table, span=span,
                 kv_kernel=kernel, lora=lora, aid=aid)
@@ -1209,7 +1278,7 @@ class InferenceEngine:
         def _verify(params, cache, draft, n_draft, active, table=None,
                     lora=None, aid=None, *, k, qweights=None,
                     span=None, kernel=False):
-            return kvcache.verify_draft_staged(
+            return progs.verify_draft_staged(
                 params, cache, draft, n_draft, active, k, cfg,
                 qweights=qweights, table=table, span=span,
                 kv_kernel=kernel, lora=lora, aid=aid)
@@ -1224,7 +1293,7 @@ class InferenceEngine:
                            slot, new_len, rng, table=None, lora=None,
                            aid=None, *, final,
                            qweights=None, span=None, kernel=False):
-            return kvcache.prefill_chunk(
+            return progs.prefill_chunk(
                 params, cache, tokens_c, start, n_valid, slot, new_len,
                 rng, cfg, sp, final=final, qweights=qweights,
                 table=table, span=span, kv_kernel=kernel, lora=lora,
@@ -1314,8 +1383,10 @@ class InferenceEngine:
         InferenceEngine (its device_put then no-ops)."""
         from skypilot_tpu.parallel import sharding as sh
         return sh.init_sharded(
-            lambda: llama.init_params(jax.random.key(seed), cfg),
-            lambda _: llama.param_logical_axes(cfg), mesh,
+            lambda: registry.model_for(cfg).init_params(
+                jax.random.key(seed), cfg),
+            lambda _: registry.model_for(cfg).param_logical_axes(cfg),
+            mesh,
             rules or sh.INFER_TP_RULES)
 
     def add_request(self, prompt: List[int],
@@ -1377,8 +1448,17 @@ class InferenceEngine:
         family with no host-authoritative array to read."""
         led = self.hbm_ledger
         led.set_bytes("weights", self._weight_bytes)
-        led.set_bytes("kv_pool",
-                      attribution_lib.tensor_bytes(self.cache))
+        # A latent cache and a layer's routed experts are rows of
+        # their own: what the first holds a token and how much of the
+        # weights the second is are what sizes such a deployment.
+        # ("expert_weights" is a view INSIDE "weights", as kv_used is
+        # inside its pool.)
+        pool_row = "latent_kv_pool" if self.latent else "kv_pool"
+        led.set_bytes(pool_row, attribution_lib.tensor_bytes(self.cache))
+        if self.latent:
+            led.set_bytes("expert_weights", sum(
+                attribution_lib.tensor_bytes(self.params["moe"][n])
+                for n in ("we_gate", "we_up", "we_down")))
         led.set_bytes("prefix_pool",
                       attribution_lib.tensor_bytes(self.pool))
         led.set_bytes("draft_pool",
@@ -1653,6 +1733,14 @@ class InferenceEngine:
                     rows_ladder.append(r)
                     r <<= 1
             for bucket in self.buckets:
+                if self.prefill_chunk and bucket > self.prefill_chunk \
+                        and min(self.buckets) <= self.prefill_chunk:
+                    # Unreachable: a context longer than the chunk
+                    # never rides a bucketed wave (_use_chunked), so a
+                    # bucket wider than the chunk admits nothing — and
+                    # at a long --max-len its program is the largest
+                    # the engine could build.
+                    continue
                 for rows in rows_ladder:
                     tokens_b = np.ones((rows, bucket), np.int32)
                     true_lens = np.ones((rows,), np.int32)
@@ -3846,6 +3934,10 @@ def random_serving_weights(cfg: llama.LlamaConfig, *,
     ``bytes_limit`` first (where the backend reports one):
     :class:`WeightsDoNotFitError` names both numbers."""
     from skypilot_tpu.parallel import sharding as sh
+    model = registry.model_for(cfg)
+    if model is not llama and (weights_int8 or mesh is not None):
+        refuse_latent_options(weights_int8=weights_int8,
+                               tp=mesh is not None)
     if weights_int8:
         def build():
             params, qweights = kvcache.random_quantized_params(cfg, seed)
@@ -3854,10 +3946,10 @@ def random_serving_weights(cfg: llama.LlamaConfig, *,
                 "qweights": kvcache.qweight_logical_axes(cfg)}
     else:
         def build():
-            params = llama.init_params(jax.random.key(seed), cfg)
+            params = model.init_params(jax.random.key(seed), cfg)
             return {"params": jax.tree.map(
                 lambda w: w.astype(cfg.dtype), params)}
-        axes = {"params": llama.param_logical_axes(cfg)}
+        axes = {"params": model.param_logical_axes(cfg)}
     abstract = jax.eval_shape(build)
     rules = rules or sh.INFER_TP_RULES
     leaves = jax.tree.leaves(abstract)

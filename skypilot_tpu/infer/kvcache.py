@@ -31,6 +31,7 @@ JetStream section). This module is the in-tree TPU-native engine core.
 
 from __future__ import annotations
 
+import sys
 from typing import Dict, Tuple
 
 import jax
@@ -43,6 +44,43 @@ from skypilot_tpu.ops import paged_attention as paged_attn_ops
 from skypilot_tpu.parallel import ring_attention as ra
 
 Cache = Dict[str, jax.Array]
+
+# The tensors of a cache that hold one ROW per token, in either layout:
+# dims (layer, slot | block, row-in-slot | row-in-block, ...). Two
+# kinds of cache exist and a cache says which it is by the tensors it
+# holds: per-head keys and values ("k", "v", int8 with their scales),
+# or the latent rows of an MLA model ("c_kv", "k_pe": one compressed
+# row and one shared rope key per token, no heads axis —
+# infer/latent.py). Whatever moves rows or blocks without looking
+# inside them (copy-on-write, handoff, addressing) goes through
+# :func:`row_tensors`, so it serves both.
+ROW_TENSORS = ("k", "v", "k_scale", "v_scale", "c_kv", "k_pe")
+
+
+def row_tensors(cache: Cache) -> Tuple[str, ...]:
+    return tuple(n for n in ROW_TENSORS if n in cache)
+
+
+def is_latent(cache: Cache) -> bool:
+    return "c_kv" in cache
+
+
+def _rows_per_unit(cache: Cache) -> int:
+    """Dim 2 of the row tensors: max_len (contiguous) or block_len."""
+    return cache[row_tensors(cache)[0]].shape[2]
+
+
+def programs_for(cfg):
+    """The module holding the serve programs of ``cfg``'s family
+    (``prefill_batch``, ``insert``, ``prefill_chunk``, ``decode_step``,
+    ``decode_burst_staged``, ``init_paged_cache``): this module for
+    the GQA decoders, ``infer/latent.py`` for the latent-cache family.
+    The engine's jitted entry points call through it and are otherwise
+    one code path."""
+    if hasattr(cfg, "kv_lora_rank"):
+        from skypilot_tpu.infer import latent
+        return latent
+    return sys.modules[__name__]
 
 
 def _ffn(cfg: llama.LlamaConfig, h: jax.Array, layer: Dict) -> jax.Array:
@@ -350,6 +388,10 @@ def cache_logical_axes(cache: Cache | None = None) -> Dict[str, Tuple]:
     if cache is not None and "k_scale" in cache:
         axes["k_scale"] = ("layer", "batch", "kv_heads", "seq_cache")
         axes["v_scale"] = ("layer", "batch", "kv_heads", "seq_cache")
+    if cache is not None and is_latent(cache):
+        del axes["k"], axes["v"]
+        axes["c_kv"] = ("layer", "batch", "seq_cache", None)
+        axes["k_pe"] = ("layer", "batch", "seq_cache", None)
     return axes
 
 
@@ -467,9 +509,7 @@ def copy_block(cache: Cache, src: jax.Array, dst: jax.Array) -> Cache:
     shape); rows past the shared prefix are garbage in BOTH blocks and
     stay unreadable until the new owner overwrites them."""
     out = dict(cache)
-    for name in ("k", "v", "k_scale", "v_scale"):
-        if name not in cache:
-            continue
+    for name in row_tensors(cache):
         rows = lax.dynamic_index_in_dim(cache[name], src, 1,
                                         keepdims=False)
         out[name] = lax.dynamic_update_index_in_dim(cache[name], rows,
@@ -482,8 +522,8 @@ def _logical_rows(cache: Cache, table) -> int:
     blocks_per_slot * block_len (paged; the table's last column is the
     sentinel and holds no rows)."""
     if table is None:
-        return cache["k"].shape[2]
-    return (table.shape[1] - 1) * cache["k"].shape[2]
+        return _rows_per_unit(cache)
+    return (table.shape[1] - 1) * _rows_per_unit(cache)
 
 
 def _phys(cache: Cache, table, slots, idx):
@@ -494,7 +534,7 @@ def _phys(cache: Cache, table, slots, idx):
     scatter drops the write."""
     if table is None:
         return slots, idx
-    bl = cache["k"].shape[2]
+    bl = _rows_per_unit(cache)
     return table[slots, idx // bl], idx % bl
 
 
@@ -870,9 +910,7 @@ def export_blocks(cache: Cache, idx: jax.Array) -> Dict[str, jax.Array]:
     (== n_blocks); gathers CLAMP out-of-bounds indices, so padding rows
     come back as garbage the host masks by the true block count — one
     compiled program regardless of how many blocks transfer."""
-    return {name: cache[name][:, idx]
-            for name in ("k", "v", "k_scale", "v_scale")
-            if name in cache}
+    return {name: cache[name][:, idx] for name in row_tensors(cache)}
 
 
 def import_blocks(cache: Cache, idx: jax.Array,

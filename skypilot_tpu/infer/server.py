@@ -1177,7 +1177,7 @@ def serve(engine, host: str = "0.0.0.0", port: int = 8080,
     return model, httpd
 
 
-def main() -> None:
+def _main() -> None:
     compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="llama3-400m")
@@ -1342,10 +1342,24 @@ def main() -> None:
     if args.profiler_port:
         jax.profiler.start_server(args.profiler_port)
 
-    from skypilot_tpu.infer import engine as eng, sampling
-    from skypilot_tpu.models import llama
+    from skypilot_tpu.infer import engine as eng, kvcache, sampling
+    from skypilot_tpu.models import registry
 
-    cfg = llama.CONFIGS[args.config]
+    try:
+        cfg = registry.get_config(args.config)
+    except KeyError as e:
+        raise SystemExit(e.args[0])
+    # A family without a verify program serves with speculation off
+    # unless the operator asks for it (and is then refused by name,
+    # like every option the family does not serve: main()).
+    can_verify = kvcache.programs_for(cfg) is kvcache
+    if not can_verify:
+        # Before either is built from a config object of another family.
+        eng.refuse_latent_options(
+            adapters=bool(args.adapters or os.environ.get(
+                "SKYTPU_ADAPTERS", "").strip()),
+            draft_model=bool(args.draft_model or os.environ.get(
+                "SKYTPU_DRAFT_MODEL", "").strip()))
     mesh = None
     if args.tp > 1:
         import numpy as np
@@ -1359,13 +1373,8 @@ def main() -> None:
     # int8 without the float tree they would quantize from, float in
     # the compute dtype, sharded at init under --tp. A tree that
     # cannot fit is a typed start-up error naming the bytes.
-    try:
-        params, qweights = eng.random_serving_weights(
-            cfg, weights_int8=args.weights_int8, mesh=mesh)
-    except eng.WeightsDoNotFitError as e:
-        tracing.add_event("server.weights_do_not_fit", e.typed_error,
-                          echo=True)
-        raise SystemExit(str(e))
+    params, qweights = eng.random_serving_weights(
+        cfg, weights_int8=args.weights_int8, mesh=mesh)
     # "--span-buckets 0" disables bucketing; a comma list is an
     # explicit ladder; unset falls through to the engine default /
     # SKYTPU_SPAN_BUCKETS.
@@ -1416,7 +1425,8 @@ def main() -> None:
         # the engine-level default stays 0 so library users opt in.
         spec_k=(args.spec_k
                 if args.spec_k is not None
-                else int(os.environ.get("SKYTPU_SPEC_K", "4") or 0)),
+                else int(os.environ.get(
+                    "SKYTPU_SPEC_K", "4" if can_verify else "0") or 0)),
         draft_engine=draft_engine,
         spec_pipeline=(bool(args.spec_pipeline)
                        if args.spec_pipeline is not None else None),
@@ -1464,6 +1474,22 @@ def main() -> None:
         httpd.serve_forever()
     finally:
         model.shutdown()
+
+
+def main() -> None:
+    """The server's entry point (``python -m skypilot_tpu.infer.server``,
+    and what the benchmark's serve child calls). An operator error that
+    carries a ``typed_error`` (weights that cannot fit, an option the
+    configuration's family does not serve) ends the process with its
+    message and the typed event ``server.<type>``, not a traceback."""
+    try:
+        _main()
+    except ValueError as e:
+        typed = getattr(e, "typed_error", None)
+        if typed is None:
+            raise
+        tracing.add_event(f"server.{typed['type']}", typed, echo=True)
+        raise SystemExit(str(e))
 
 
 if __name__ == "__main__":

@@ -1,0 +1,38 @@
+"""Which model file a serving configuration belongs to.
+
+``--config <name>`` names a configuration of ANY decoder family the
+serve path runs; the family's model module brings ``CONFIGS``,
+``init_params`` and ``param_logical_axes``. The dictionaries are read
+at call time, so a configuration registered after import (the
+benchmark's families do that) is found.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+from skypilot_tpu.models import glm_moe, llama
+
+# Served decoder families, in lookup order.
+FAMILIES = (llama, glm_moe)
+
+
+def serving_configs() -> Dict[str, Any]:
+    out: Dict[str, Any] = {}
+    for module in reversed(FAMILIES):
+        out.update(module.CONFIGS)
+    return out
+
+
+def get_config(name: str):
+    for module in FAMILIES:
+        if name in module.CONFIGS:
+            return module.CONFIGS[name]
+    raise KeyError(
+        f"unknown serving config {name!r}; known: "
+        f"{sorted(serving_configs())}")
+
+
+def model_for(cfg):
+    """The model module of a config object."""
+    return glm_moe if isinstance(cfg, glm_moe.GlmMoeConfig) else llama

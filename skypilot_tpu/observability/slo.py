@@ -146,14 +146,16 @@ DEFAULT_RULES: List[SloRule] = [
     SloRule("component-alive", "component_dead", threshold=0.0),
     # Analytical HBM pressure from the engine's ledger: capacity
     # components (weights, pools, workspace) summed against the
-    # published limit. Occupancy views (kv_used, prefix_pinned) are
-    # excluded — they live INSIDE kv_pool/prefix_pool and would
-    # double-count. Pages before the allocator does, while there is
+    # published limit. Views (kv_used, prefix_pinned; expert_weights,
+    # the routed experts' part of weights) are excluded — they live
+    # INSIDE a pool or the weights and would double-count. Pages
+    # before the allocator does, while there is
     # still headroom to act (evict prefixes, shrink max_batch).
     SloRule("hbm-headroom", "hbm_headroom", threshold=0.92,
             metric="skytpu_hbm_bytes",
             baseline_metric="skytpu_hbm_limit_bytes",
-            exclude_labels={"component": ["kv_used", "prefix_pinned"]}),
+            exclude_labels={"component": ["kv_used", "prefix_pinned",
+                                          "expert_weights"]}),
 ]
 
 

@@ -300,8 +300,9 @@ def _hbm_fams(capacity_frac, occupancy_frac=0.3, limit=1000.0):
 def test_hbm_headroom_rule_is_default_and_instant():
     rule = _hbm_rule()
     assert rule.kind in slo._INSTANT_KINDS
-    assert rule.exclude_labels == {"component": ["kv_used",
-                                                 "prefix_pinned"]}
+    # the views: occupancy inside a pool, the experts inside weights
+    assert rule.exclude_labels == {"component": [
+        "kv_used", "prefix_pinned", "expert_weights"]}
 
 
 def test_hbm_headroom_excludes_occupancy_views():

@@ -17,6 +17,7 @@ never gets a passing last line.
 import dataclasses
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -200,6 +201,63 @@ def test_top_bucket_prefill_takes_flash_at_1280(one_chip,
         S((4, 1280), jnp.int32), S((4,), jnp.int32), S((4,), jnp.int32),
         rng, table, bucket=1280, qweights=qw)
     assert n == 1
+
+
+@pytest.fixture(scope="module")
+def latent_engine_2layers():
+    """The latent-cache family at every published GLM-4.7-Flash width,
+    depth cut to one dense + one expert layer (weights are shapes only;
+    the pool is real: 8 slots of 8704 rows, ~0.2 GB of host memory)."""
+    from skypilot_tpu.models import glm_moe
+    cfg = dataclasses.replace(glm_moe.CONFIGS["glm-4.7-flash"], n_layers=2)
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(cfg.dtype),
+        glm_moe.init_params(jax.random.key(0), cfg)))
+    return eng.InferenceEngine(
+        params, cfg, n_slots=7, max_len=8704,
+        prompt_buckets=(128, 512, 8704), max_wave=4, pad_waves=True,
+        prefix_pool=8, spec_k=0)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_chunk",
+                                     "admit_wave"])
+def test_latent_programs_compile_for_v5e(one_chip, latent_engine_2layers,
+                                         program):
+    """The MLA / expert programs lower for the chip, the grouped
+    expert products of a chunk or wave become the TPU's ragged-dot
+    kernels (a decode step's few rows take none), and the donated
+    latent pool is written IN PLACE: a scatter with the layer as a
+    window dim made the compiler transpose the whole pool into another
+    layout and back (temporaries of the pool's own size)."""
+    e = latent_engine_2layers
+    params, _, cache, rng, table, S = _engine_args(e, one_chip)
+    i32 = S((), jnp.int32)
+    if program == "decode_burst":
+        lowered = e._decode_burst_fn.__wrapped__.lower(
+            params, cache, rng, S((e.n_slots + 1,), jnp.bool_), table,
+            k=4, qweights=None, span=None, kernel=False)
+        kernels = 0
+    elif program == "prefill_chunk":
+        lowered = e._prefill_chunk_fn.__wrapped__.lower(
+            params, cache, S((512,), jnp.int32), i32, i32, i32, i32, rng,
+            table, final=True, qweights=None, span=4352, kernel=False)
+        kernels = 4          # group metadata + gate, up, down
+    else:
+        lowered = e._admit_wave_fn.__wrapped__.lower(
+            params, cache, S((4, 512), jnp.int32), S((4,), jnp.int32),
+            S((4,), jnp.int32), rng, table, bucket=512, qweights=None)
+        kernels = 4
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels
+    pool = sum(e.cache[n].nbytes for n in ("c_kv", "k_pe"))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool
+    # (``k_pe``, an eighth of the bytes, IS still copied: its 64 values
+    # a row are half a lane tile and the compiler re-lays it out before
+    # the gather — ROADMAP M3.)
+    shape = ",".join(str(n) for n in e.cache["c_kv"].shape)
+    assert not re.search(rf"bf16\[{shape}\]\S* copy\(", text), \
+        "the c_kv pool is copied"
 
 
 # ---------------------------------------------------------------------------

@@ -123,6 +123,12 @@ _GOLDEN = [
     # contiguous idiom.
     ("retrace-safety", "retrace_paged_bad.py", "retrace_paged_clean.py",
      "skypilot_tpu/infer/fixture_retrace_paged.py"),
+    # Program-family shape (PR 28): serve programs reached through the
+    # ``programs_for`` handle — the latent cache and the dropless
+    # expert layer (retrace v6).
+    ("retrace-safety", "retrace_family_bad.py",
+     "retrace_family_clean.py",
+     "skypilot_tpu/infer/fixture_retrace_family.py"),
     ("host-sync", "host_sync_bad.py", "host_sync_clean.py",
      "skypilot_tpu/infer/engine.py"),
     ("host-sync", "host_sync_paged_bad.py", "host_sync_paged_clean.py",
