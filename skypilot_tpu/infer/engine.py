@@ -287,7 +287,9 @@ class BurstHandle:
     :meth:`InferenceEngine.dispatch_decode_burst`): ONE device program
     over every slot that had headroom, at the span rung covering the
     longest of them."""
-    toks: jax.Array                   # [k, slots+1], still on device
+    # [k, slots+1], still on device; the spare slot's column (the last)
+    # carries the family's own count where it has one (``SPARE_COLUMN``).
+    toks: jax.Array
     slots: List[int]                  # the slots the program decoded for
     k: int
     slot_req: Dict[int, "Request"]    # slot->request snapshot at dispatch
@@ -3780,8 +3782,14 @@ class InferenceEngine:
             before = len(self.finished)
             with timeline.phase("engine.decode.commit"):
                 out, n_emitted = self._commit_burst(handle, toks)
+            counts = {}
+            if self._progs.SPARE_COLUMN is not None:
+                # The family's own count rides the spare slot's column.
+                name, counter = self._progs.SPARE_COLUMN
+                counts[name] = int(toks[:, self.n_slots].sum())
+                counter.inc(counts[name])
             ph.set(tokens=n_emitted,
-                   retired=len(self.finished) - before)
+                   retired=len(self.finished) - before, **counts)
         if n_emitted:
             DECODE_TOKENS.inc(n_emitted)
         return out

@@ -70,6 +70,12 @@ def _rows_per_unit(cache: Cache) -> int:
     return cache[row_tensors(cache)[0]].shape[2]
 
 
+# What the hidden spare slot's column of a decode burst's ``toks``
+# carries instead of that slot's token, which is nobody's: nothing in
+# this family; ``infer/latent.py`` puts ``experts_read`` there.
+SPARE_COLUMN = None
+
+
 def programs_for(cfg):
     """The module holding the serve programs of ``cfg``'s family
     (``prefill_batch``, ``insert``, ``prefill_chunk``, ``decode_step``,

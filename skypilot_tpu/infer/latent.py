@@ -38,6 +38,7 @@ from jax import lax
 from skypilot_tpu.infer import kvcache
 from skypilot_tpu.infer import sampling as sampling_mod
 from skypilot_tpu.models import glm_moe as glm
+from skypilot_tpu.observability import metrics
 
 Cache = kvcache.Cache
 
@@ -47,6 +48,19 @@ Cache = kvcache.Cache
 # materialised); the absorbed form makes no [S, heads, nope + v]
 # transient and shares decode's code path.
 CHUNK_ABSORBED = True
+
+EXPERTS_READ = metrics.counter(
+    "skytpu_experts_read_total",
+    "Routed experts whose weights decode steps read: per step and expert "
+    "layer, the distinct experts the live rows chose (expert models only)")
+# What the hidden spare slot's column (the last) of a decode burst's
+# ``toks`` carries instead of that slot's token, which is nobody's: a
+# step's routed experts visited, summed over its expert layers, so the
+# count reaches the host in the fetch of the tokens. (Field of the
+# ``engine.decode.fetch`` annotation, its /metrics counter.) Over a
+# burst's k steps, ``experts_read / (k x expert layers x
+# n_routed_experts)`` is the share of the expert weights it streamed.
+SPARE_COLUMN = ("experts_read", EXPERTS_READ)
 
 
 def init_paged_cache(cfg: glm.GlmMoeConfig, n_slots: int, n_blocks: int,
@@ -217,7 +231,7 @@ def prefill_chunk(params, cache: Cache, tokens_c, start, n_valid, slot,
             o = glm.latent_attention(
                 cfg, layer["wkv_b"], q_nope, q_pe,
                 [(rc, rp, resident), (c_kv, k_pe, intra)], CHUNK_ABSORBED)
-        return glm.out_ffn(cfg, layer, x, o, moe), (c_kv[0], k_pe[0])
+        return glm.out_ffn(cfg, layer, x, o, moe)[0], (c_kv[0], k_pe[0])
 
     x, (c_l, p_l) = glm.scan_layers(cfg, params, x, layer_fn)
     if final:
@@ -245,16 +259,19 @@ def prefill_chunk(params, cache: Cache, tokens_c, start, n_valid, slot,
 # ---------------------------------------------------------------------------
 
 def _staged_steps(params, cache: Cache, cfg: glm.GlmMoeConfig, table, span,
-                  k: int, first_tokens, next_token):
+                  k: int, first_tokens, next_token, live=None):
     """``k`` decode steps for every slot with the big cache a read-only
     invariant (``kvcache.decode_burst_staged``'s formulation): a step's
     latent rows land in a staging buffer [L, B, k, width]; attention is
     the resident rows (``< length`` at the start, a constant mask) and
     the staged columns ``<= step`` under one softmax; ONE scatter per
     tensor flushes all ``k`` rows afterwards. ``next_token(logits, s,
-    last) -> (token fed to step s + 1, what the step emits)``. Returns
+    last) -> (token fed to step s + 1, what the step emits)``. ``live``
+    [B] bool: the rows whose tokens anyone keeps — the expert layers
+    read only the experts THEY chose (absent: every row counts). Returns
     (cache with the rows flushed — bookkeeping untouched —, last token
-    [B], emitted [k, ...])."""
+    [B], emitted [k, ...], routed experts read [k]: a step's sum over
+    its expert layers)."""
     _need_table(table)
     B = cache["length"].shape[0]
     M = span if span is not None else kvcache._logical_rows(cache, table)
@@ -263,6 +280,7 @@ def _staged_steps(params, cache: Cache, cfg: glm.GlmMoeConfig, table, span,
     pos0 = cache["length"]
     resident = (jnp.arange(M)[None, :] < pos0[:, None])[:, None, :]
     batch_ix = jnp.arange(B)
+    live = None if live is None else live[:, None]
 
     def step(carry, s):
         with jax.named_scope("decode_step"):
@@ -285,21 +303,22 @@ def _staged_steps(params, cache: Cache, cfg: glm.GlmMoeConfig, table, span,
                          (lax.dynamic_index_in_dim(sc, i, 0, False),
                           lax.dynamic_index_in_dim(sp_, i, 0, False),
                           staged)], True)
-                return (glm.out_ffn(cfg, layer, x, o, moe), sc, sp_), None
+                x, read = glm.out_ffn(cfg, layer, x, o, moe, live)
+                return (x, sc, sp_), read
 
-            (x, sc, sp_), _ = glm.scan_layers(cfg, params, (x, sc, sp_),
-                                              layer_fn)
+            (x, sc, sp_), reads = glm.scan_layers(
+                cfg, params, (x, sc, sp_), layer_fn)
             logits = glm.head_logits(cfg, params, x[:, 0])
             last, emitted = next_token(logits, s, last)
-        return (last, sc, sp_), emitted
+        return (last, sc, sp_), (emitted, jnp.sum(reads))
 
     init = (first_tokens,
             jnp.zeros((L, B, k, cfg.kv_lora_rank), dt),
             jnp.zeros((L, B, k, cfg.qk_rope_head_dim), dt))
-    (last, sc, sp_), emitted = lax.scan(step, init, jnp.arange(k))
+    (last, sc, sp_), (emitted, reads) = lax.scan(step, init, jnp.arange(k))
     blk, off = kvcache._phys(cache, table, batch_ix[:, None],
                              pos0[:, None] + jnp.arange(k)[None, :])
-    return _append_rows(cache, blk, off, sc, sp_), last, emitted
+    return _append_rows(cache, blk, off, sc, sp_), last, emitted, reads
 
 
 def decode_step(params, cache: Cache, cfg: glm.GlmMoeConfig,
@@ -309,7 +328,7 @@ def decode_step(params, cache: Cache, cfg: glm.GlmMoeConfig,
     logits [slots, vocab]). The caller samples and commits
     (``kvcache.commit_tokens``)."""
     _no_extras(qweights, lora)
-    out, _, logits = _staged_steps(
+    out, _, logits, _ = _staged_steps(
         params, cache, cfg, table, span, 1, cache["last_token"],
         lambda logits, s, last: (last, logits))
     return out, logits[0]
@@ -321,7 +340,11 @@ def decode_burst_staged(params, cache: Cache, rng, active, k: int,
                         aid=None):
     """``k`` decode steps in one program, the cache flushed once
     (``kvcache.decode_burst_staged``'s contract and RNG discipline).
-    Returns (cache', rng', toks [k, slots])."""
+    Only the ``active`` rows' expert choices are read; a dead row's
+    token is discarded here and its cache rows drop at the sentinel
+    block, as before. Returns (cache', rng', toks [k, slots]: the last
+    column, the spare slot's, holds the step's experts read —
+    :data:`SPARE_COLUMN`)."""
     _no_extras(qweights, lora)
     rng, sub = jax.random.split(rng)
     keys = jax.random.split(sub, k)
@@ -331,11 +354,12 @@ def decode_burst_staged(params, cache: Cache, rng, active, k: int,
             tok = sampling_mod.sample(logits, keys[s], sp)
         return jnp.where(active, tok, last), tok
 
-    out, last, toks = _staged_steps(params, cache, cfg, table, span, k,
-                                    cache["last_token"], next_token)
+    out, last, toks, reads = _staged_steps(
+        params, cache, cfg, table, span, k, cache["last_token"], next_token,
+        live=active)
     out["length"] = cache["length"] + k * active.astype(jnp.int32)
     out["last_token"] = last
-    return out, rng, toks
+    return out, rng, toks.at[:, -1].set(reads.astype(toks.dtype))
 
 
 def verify_draft_staged(*_, **__):

@@ -19,13 +19,17 @@ residual adds a layer; untied embedding and head):
   ``s[chosen] / sum(s[chosen]) * routed_scaling_factor``; the weighted
   sum of the chosen experts' SwiGLUs plus one shared SwiGLU. DROPLESS:
   no capacity factor, every token-choice is computed. Two formulations,
-  chosen from the static row count (:data:`DENSE_EXPERT_MAX_TOKENS`):
-  few rows (a decode step) run every expert over every row and weight
-  the unchosen by zero — the step streams the expert weights whichever
-  way and there is no sort, gather or scatter in it; many rows (a
-  prefill chunk or wave) sort the token-choices by expert and multiply
-  by groups (``lax.ragged_dot``, which the TPU compiler lowers to its
-  grouped-matmul kernel at these sizes).
+  chosen from the static row count (:data:`DENSE_EXPERT_MAX_TOKENS`).
+  Few rows (a decode step, which may say which of its rows are LIVE):
+  the experts the live rows chose are found (a presence vector, no
+  sort) and visited one by one, each read once where it lies in the
+  stacked tensor — an expert nobody live chose is not read, so a step
+  of one or two live rows streams a tenth of the expert weights
+  (:func:`experts_few_rows`). Many rows (a prefill chunk or wave) sort
+  the token-choices by expert and multiply by groups
+  (``lax.ragged_dot``, which the TPU compiler lowers to its
+  grouped-matmul kernel at these sizes and to the all-expert form
+  below ~1 k rows).
 
 The heterogeneous stack is TWO parameter groups, ``dense`` and ``moe``,
 each stacked on a leading layer axis and each one ``lax.scan``
@@ -49,8 +53,8 @@ from skypilot_tpu.parallel import ring_attention as ra
 
 Params = Dict[str, Any]
 
-# Rows at or below which the expert layer runs every expert over every
-# row (see the module docstring); above it, sort + grouped products.
+# Rows at or below which the expert layer takes its few-row form (see
+# the module docstring); above it, sort + grouped products.
 DENSE_EXPERT_MAX_TOKENS = 64
 # The routed experts' matrices, stacked [layers, E, ...] in the tree.
 EXPERT_TENSORS = ("we_gate", "we_up", "we_down")
@@ -406,18 +410,71 @@ def route(cfg: GlmMoeConfig, h: jax.Array, layer: Params):
     return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
-@jax.named_scope("moe_experts")
-def experts_dense(cfg: GlmMoeConfig, h, idx, w, layer) -> jax.Array:
-    """Every expert over every row, the unchosen weighted by zero (few
-    rows: the weights are streamed once whichever way)."""
+def touched_experts(cfg: GlmMoeConfig, idx: jax.Array, live=None):
+    """The distinct experts the LIVE rows of ``idx`` [T, K] chose:
+    ``(ids [E] int32 — ascending, the first n of them meant, zeros
+    after —, n)``. ``live`` [T] bool; absent = every row counts. A
+    presence vector over the E experts and its running sum place the
+    ids: no sort and no scatter of the T x K choices."""
+    E = cfg.n_routed_experts
+    experts = jnp.arange(E, dtype=jnp.int32)
+    hit = idx[:, :, None] == experts
+    if live is not None:
+        hit = hit & live[:, None, None]
+    chosen = hit.any(axis=(0, 1))                            # [E]
+    place = jnp.cumsum(chosen.astype(jnp.int32)) - 1
+    ids = jnp.sum(jnp.where(chosen[:, None] & (place[:, None] == experts),
+                            experts[:, None], 0), axis=0)
+    return ids, jnp.sum(chosen.astype(jnp.int32))
+
+
+def combine_weights(cfg: GlmMoeConfig, idx, w, live=None) -> jax.Array:
+    """Router weights as a float32 ``[E, T]`` table: row ``e`` holds
+    each token's weight for expert ``e``, zero where the token did not
+    choose it or is not ``live`` (top-k ids of a row are distinct, so
+    the sum over K places a weight and adds none)."""
+    if live is not None:
+        w = jnp.where(live[:, None], w, 0.0)
+    experts = jnp.arange(cfg.n_routed_experts, dtype=jnp.int32)
+    return jnp.sum(jnp.where(idx[None] == experts[:, None, None],
+                             w[None], 0.0), axis=-1)
+
+
+def experts_visited(cfg: GlmMoeConfig, h, combine, ids, n, layer
+                    ) -> jax.Array:
+    """Visit experts ``ids[:n]``, one a turn of a loop whose trip count
+    is ``n``, and read nothing of any other. A turn takes the expert's
+    three matrices where they lie in the stack (at
+    ``layer["expert_base"] + id``, as :func:`experts_grouped` addresses
+    it), runs the SwiGLU over ALL T rows (bf16 products) and adds it,
+    weighted by the expert's combine row, into a float32 [T, D]."""
     dt = cfg.dtype
-    T = h.shape[0]
-    combine = jnp.zeros((T, cfg.n_routed_experts), jnp.float32).at[
-        jnp.arange(T)[:, None], idx].set(w)
-    g = jnp.einsum("td,edf->etf", h, layer["we_gate"].astype(dt))
-    u = jnp.einsum("td,edf->etf", h, layer["we_up"].astype(dt))
-    a = jax.nn.silu(g) * u * combine.T[:, :, None].astype(dt)
-    return jnp.einsum("etf,efd->td", a, layer["we_down"].astype(dt))
+    base = layer.get("expert_base", 0)
+
+    def turn(t, acc):
+        e = ids[t]
+        w_gate, w_up, w_down = (
+            lax.dynamic_index_in_dim(layer[name], base + e, 0,
+                                     keepdims=False).astype(dt)
+            for name in EXPERT_TENSORS)
+        a = jax.nn.silu(jnp.dot(h, w_gate)) * jnp.dot(h, w_up)
+        scale = lax.dynamic_index_in_dim(combine, e, 0, keepdims=False)
+        return acc + jnp.dot(
+            a, w_down, preferred_element_type=jnp.float32) * scale[:, None]
+
+    return lax.fori_loop(0, n, turn, jnp.zeros(h.shape, jnp.float32))
+
+
+@jax.named_scope("moe_experts")
+def experts_few_rows(cfg: GlmMoeConfig, h, idx, w, layer, live=None):
+    """Few rows (a decode step): compute the experts the LIVE rows
+    chose, visited one by one (:func:`experts_visited`), and read no
+    other. ``live`` [T] bool (absent: every row counts); a row that is
+    not live gets a zero routed output. Every live token-choice is
+    computed. Returns ``(y [T, D] float32, experts read)``."""
+    ids, n = touched_experts(cfg, idx, live)
+    combine = combine_weights(cfg, idx, w, live)
+    return experts_visited(cfg, h, combine, ids, n, layer), n
 
 
 @jax.named_scope("moe_experts")
@@ -449,45 +506,46 @@ def experts_grouped(cfg: GlmMoeConfig, h, idx, w, layer) -> jax.Array:
     return y[jnp.argsort(order)].reshape(T, K, -1).sum(axis=1)
 
 
-def _own_experts(cfg: GlmMoeConfig, layer: Params) -> Params:
-    """This layer's experts out of the whole stack (see
-    :func:`experts_grouped`), for a formulation that takes them by
-    layer: a slice that feeds plain products is read in place."""
-    if "expert_base" not in layer:
-        return layer
-    e = cfg.n_routed_experts
-    return dict(layer, **{
-        n: lax.dynamic_slice_in_dim(layer[n], layer["expert_base"], e)
-        for n in EXPERT_TENSORS})
-
-
-def moe_ffn(cfg: GlmMoeConfig, h: jax.Array, layer: Params) -> jax.Array:
-    """Shared + routed experts over rows h [B, S, D] (post-norm)."""
+def moe_ffn(cfg: GlmMoeConfig, h: jax.Array, layer: Params, live=None):
+    """Shared + routed experts over rows h [B, S, D] (post-norm).
+    ``live`` [B, S] bool (few rows only: a decode step's live slots):
+    a row that is not live chooses no expert and gets a zero routed
+    output; absent = every row counts. Returns ``(y [B, S, D], routed
+    experts read)``: :func:`experts_few_rows`' count, zero for many
+    rows (nobody reads it there)."""
     B, S, D = h.shape
     rows = h.reshape(B * S, D)
     idx, w = route(cfg, rows, layer)
     if B * S <= DENSE_EXPERT_MAX_TOKENS:
-        y = experts_dense(cfg, rows, idx, w, _own_experts(cfg, layer))
+        y, n = experts_few_rows(cfg, rows, idx, w, layer,
+                                None if live is None else live.reshape(-1))
     else:
+        if live is not None:
+            raise ValueError("a row mask is a few-row (decode) argument")
         y = experts_grouped(cfg, rows, idx, w, layer)
+        n = jnp.zeros((), jnp.int32)
     with jax.named_scope("shared_expert"):
-        y = y + _swiglu(rows, layer["ws_gate"], layer["ws_up"],
-                        layer["ws_down"], cfg.dtype)
-    return y.reshape(B, S, D)
+        y = y.astype(cfg.dtype) + _swiglu(
+            rows, layer["ws_gate"], layer["ws_up"], layer["ws_down"],
+            cfg.dtype)
+    return y.reshape(B, S, D), n
 
 
 @jax.named_scope("out_ffn")
 def out_ffn(cfg: GlmMoeConfig, layer: Params, x: jax.Array, o: jax.Array,
-            moe: bool) -> jax.Array:
+            moe: bool, live=None):
     """The back half of a layer: output projection of the attention
-    result ``o`` [B, S, H, v], residual, norm, feed-forward, residual."""
+    result ``o`` [B, S, H, v], residual, norm, feed-forward, residual.
+    Returns ``(x', routed experts read)`` — :func:`moe_ffn`'s count,
+    zero in a dense layer."""
     x = x + jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
                        layer["wo"].astype(cfg.dtype))
     h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
     if moe:
-        return x + moe_ffn(cfg, h, layer)
+        y, n = moe_ffn(cfg, h, layer, live)
+        return x + y, n
     return x + _swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"],
-                       cfg.dtype)
+                       cfg.dtype), jnp.zeros((), jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -555,7 +613,7 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: GlmMoeConfig,
         with jax.named_scope("attn_core"):
             o = causal_attention(cfg, layer["wkv_b"], q_nope, q_pe, c_kv,
                                  k_pe, mesh, heads_axis)
-        return out_ffn(cfg, layer, x, o, moe), (c_kv, k_pe)
+        return out_ffn(cfg, layer, x, o, moe)[0], (c_kv, k_pe)
 
     x, (c_kv, k_pe) = scan_layers(cfg, params, x, layer_fn)
     return x, {"c_kv": c_kv, "k_pe": k_pe}

@@ -260,6 +260,31 @@ def test_latent_programs_compile_for_v5e(one_chip, latent_engine_2layers,
         "the c_kv pool is copied"
 
 
+def test_latent_decode_reads_the_expert_stack_in_place(
+        one_chip, latent_engine_2layers):
+    """A decode step visits the experts its live rows chose, a turn of
+    a loop each, and takes the expert's three matrices where they lie
+    in the stack: the compiled burst holds no temporary the size of a
+    layer's experts (1.2 GB) nor of one tensor's layer slice (403 MB) —
+    the whole of its temporaries is less than one such slice — and no
+    expert tensor is copied or sliced out by the layer."""
+    e = latent_engine_2layers
+    params, _, cache, rng, table, S = _engine_args(e, one_chip)
+    compiled = e._decode_burst_fn.__wrapped__.lower(
+        params, cache, rng, S((e.n_slots + 1,), jnp.bool_), table,
+        k=4, qweights=None, span=None, kernel=False).compile()
+    gate = e.params["moe"]["we_gate"]
+    one_slice = gate.size // gate.shape[0] * gate.dtype.itemsize
+    assert one_slice == 64 * 2048 * 1536 * 2           # 403 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < one_slice
+    text = compiled.as_text()
+    assert text.count(" while(") >= 3      # steps, layers, expert turns
+    for rows, cols in ((2048, 1536), (1536, 2048)):
+        assert not re.search(
+            rf"= bf16\[64,{rows},{cols}\]\S* (copy|dynamic-slice)\(", text), \
+            "a layer's slice of an expert tensor is materialised"
+
+
 # ---------------------------------------------------------------------------
 # Part 2: the bring-up contract on the CPU
 # ---------------------------------------------------------------------------

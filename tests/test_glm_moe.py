@@ -12,13 +12,16 @@ benchmark's seeded weights and a FLOAT32 program:
     margin guard where it returns tokens only;
 (c) absorbed = materialised attention;
 (d) the expert layer = the reference under forced imbalance (every token
-    to the same experts; experts with no token): nothing is dropped;
+    to the same experts; experts with no token): nothing is dropped; the
+    few-row form visits exactly the experts its LIVE rows chose, and a
+    burst's ``experts_read`` is that count, delivered with its tokens;
 (e) the selection bias changes WHICH experts are chosen, never their
     weights;
 (f) the options the family does not serve are refused by name.
 """
 
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +35,8 @@ from benchmarks.reference import glm_moe as ref
 from skypilot_tpu.infer import engine as eng
 from skypilot_tpu.infer import kvcache, latent, sampling
 from skypilot_tpu.models import glm_moe as glm
-from skypilot_tpu.models import registry
+from skypilot_tpu.models import llama, registry
+from skypilot_tpu.utils import timeline
 
 SEED = 2_900_000_011          # more than 31 bits
 # Float32 program against a float32 reference: what is left is the order
@@ -313,11 +317,19 @@ def _expert_layer(params, dims, bias):
     return layer
 
 
-@pytest.mark.parametrize("form", ["dense", "grouped"])
-@pytest.mark.parametrize("bias", [
+BIASES = pytest.mark.parametrize("bias", [
     [9, 9, 0, 0, 0, 0, 0, 0],         # every token to experts 0 and 1
     [0, 0, 0, -9, -9, -9, 9, 0],      # one expert for all, three for none
     [0] * 8], ids=["all-to-two", "one-hot-three-empty", "free"])
+
+
+def _shared(cfg, h, layer):
+    return glm._swiglu(h, layer["ws_gate"], layer["ws_up"],
+                       layer["ws_down"], jnp.float32)
+
+
+@pytest.mark.parametrize("form", ["visited", "few-rows", "grouped"])
+@BIASES
 def test_expert_layer_under_forced_imbalance(cfg, dims, params, bias, form):
     layer = _expert_layer(params, dims, bias)
     h = jax.random.normal(jax.random.key(8), (96, cfg.d_model))
@@ -327,13 +339,207 @@ def test_expert_layer_under_forced_imbalance(cfg, dims, params, bias, form):
         assert counts[0] == counts[1] == 96 and counts[2:].sum() == 0
     if bias[6] == 9:
         assert counts[6] == 96 and counts[3:6].sum() == 0
-    run = glm.experts_dense if form == "dense" else glm.experts_grouped
-    got = run(cfg, h, idx, w, layer) + glm._swiglu(
-        h, layer["ws_gate"], layer["ws_up"], layer["ws_down"], jnp.float32)
+    if form == "visited":
+        combine = glm.combine_weights(cfg, idx, w)
+        ids, n = glm.touched_experts(cfg, idx)
+        assert int(n) == np.count_nonzero(counts)    # a turn an expert
+        got = glm.experts_visited(cfg, h, combine, ids, n, layer)
+    elif form == "few-rows":
+        got, n = glm.experts_few_rows(cfg, h, idx, w, layer)
+        assert int(n) == np.count_nonzero(counts)
+    else:
+        got = glm.experts_grouped(cfg, h, idx, w, layer)
     want = ref.expert_ffn(h, layer, dims, ref.Precision())
     assert float(jnp.abs(want).max()) > 0.5
     # Every token-choice is in the result: nothing dropped.
-    assert float(jnp.abs(got - want).max()) < 1e-4
+    assert float(jnp.abs(got + _shared(cfg, h, layer) - want).max()) < 1e-4
+
+
+@pytest.mark.parametrize("live", [
+    [5], [3, 17], list(range(0, 33, 4)), list(range(33)), []],
+    ids=["one-live", "two-live", "nine-live", "all-live", "none-live"])
+@BIASES
+def test_visit_reads_what_the_live_rows_chose(cfg, dims, params, bias, live):
+    """A decode step's 33 rows of which ``live`` count: the touched list
+    is numpy's sorted distinct choices of the live rows, a dead row's
+    choices are not in it and its routed output is zero, live rows
+    equal the reference and what they get alone."""
+    layer = _expert_layer(params, dims, bias)
+    h = jax.random.normal(jax.random.key(10), (33, cfg.d_model))
+    mask = np.zeros((33,), bool)
+    mask[live] = True
+    idx, w = glm.route(cfg, h, layer)
+    got, n = jax.jit(lambda *a: glm.experts_few_rows(cfg, *a))(
+        h, idx, w, layer, jnp.asarray(mask))
+    ids, n_listed = glm.touched_experts(cfg, idx, jnp.asarray(mask))
+    mine = np.unique(np.asarray(idx)[mask])
+    assert int(n) == int(n_listed) == len(mine)
+    assert np.asarray(ids)[:len(mine)].tolist() == mine.tolist()
+    if bias[0] == 9:
+        assert int(n) == (2 if live else 0)
+    if len(live) == 1:
+        assert int(n) == cfg.experts_per_tok
+    if bias == [0] * 8 and len(live) == 33:
+        assert int(n) == 8                   # every expert, one by one
+    if bias == [0] * 8 and 0 < len(live) <= 2:
+        # the case is not vacuous: dead rows chose experts no live row did
+        assert set(np.asarray(idx).ravel()) - set(mine)
+    got = np.asarray(got)
+    assert not got[~mask].any()
+    if not live:
+        return
+    want = ref.expert_ffn(h, layer, dims, ref.Precision())
+    assert np.abs((got + np.asarray(_shared(cfg, h, layer))
+                   - np.asarray(want))[mask]).max() < 1e-4
+    alone, n_alone = glm.experts_few_rows(cfg, h[mask], idx[mask], w[mask],
+                                          layer)
+    assert int(n_alone) == int(n)
+    assert np.abs(got[mask] - np.asarray(alone)).max() < 1e-5
+
+
+def test_row_mask_is_a_few_row_argument(cfg, dims, params):
+    layer = _expert_layer(params, dims, [0] * 8)
+    h = jnp.zeros((1, 96, cfg.d_model))
+    with pytest.raises(ValueError, match="few-row"):
+        glm.moe_ffn(cfg, h, layer, jnp.ones((1, 96), bool))
+    y, _ = glm.moe_ffn(cfg, h, layer)              # chunks and waves
+    assert y.shape == h.shape
+
+
+def _ref_choices(dims, seq):
+    """The reference's routing of one sequence: chosen experts
+    [expert layers, positions, K], from its own layer functions."""
+    key, prec = _key(), ref.Precision()
+    x = G.embedding(key, dims).astype(jnp.float32)[
+        jnp.asarray(seq, jnp.int32)[None]]
+    out = []
+    for i in range(dims.n_layers):
+        moe = i >= dims.first_k_dense
+        w = ref.layer_weights(key, dims, np.uint32(i), moe, prec)
+        if moe:
+            h = ref.rms_norm(x + ref.mla(x, w, dims, prec), w["ln2"],
+                             dims.norm_eps)[0]
+            out.append(np.asarray(ref.router(h, w, dims)[0]))
+        x = ref.decoder_layer(x, w, dims, moe, prec)
+    return np.stack(out)
+
+
+def _ref_experts_read(dims, seqs, starts, k):
+    """Experts a burst of ``k`` steps must read: per step and expert
+    layer, the distinct experts chosen at position ``start + step`` of
+    each live sequence."""
+    choices = [_ref_choices(dims, s) for s in seqs]
+    return [sum(len(np.unique(np.concatenate(
+        [c[layer, at + step] for c, at in zip(choices, starts)])))
+        for layer in range(choices[0].shape[0])) for step in range(k)]
+
+
+def test_burst_of_two_live_slots_equals_reference_and_counts_their_experts(
+        cfg, dims, params, reference):
+    """Two live slots and a dead one through ``k = 4`` staged steps with
+    the row mask: the live rows' logits are the reference's at every
+    position, the step's count of experts read is the reference
+    routing's, the dead row's choices are in neither, and the burst
+    program hands the counts back in the last (the spare) slot's column."""
+    prompts = _prompts([20, 27], seed=12)
+    n_blocks, bl, k = 12, 16, 4
+    cache = latent.init_paged_cache(cfg, 3, n_blocks, bl)
+    table = np.full((3, 5), n_blocks, np.int32)
+    table[0, :4] = [0, 1, 2, 3]
+    table[1, :4] = [7, 6, 5, 4]
+    table = jnp.asarray(table)
+    tokens = np.zeros((2, 32), np.int32)
+    for i, p in enumerate(prompts):
+        tokens[i, :len(p)] = p
+    lens = jnp.asarray([len(p) for p in prompts])
+    rows, logits = jax.jit(lambda p, t, n: latent.prefill_batch(
+        p, t, n, cfg))(params, jnp.asarray(tokens), lens)
+    seqs = [list(p) for p in prompts]
+    for i in range(2):
+        first = int(np.asarray(logits[i]).argmax())
+        cache = latent.insert(
+            cache, {n: r[:, i] for n, r in rows.items()},
+            jnp.asarray(i), lens[i], jnp.asarray(first), table=table)
+        seqs[i].append(first)
+    # The dead slot holds a stale token: it routes like any row.
+    cache["last_token"] = cache["last_token"].at[2].set(77)
+    active = jnp.asarray([True, True, False])
+
+    def steps(p, c, live):
+        def nxt(logits, s, last):
+            tok = jnp.where(active, sampling.argmax_tokens(logits), last)
+            return tok, logits
+        return latent._staged_steps(p, c, cfg, table, 64, k,
+                                    c["last_token"], nxt, live=live)[2:]
+
+    got, reads = jax.jit(lambda p, c: steps(p, c, active))(params, cache)
+    _, reads_all = jax.jit(lambda p, c: steps(p, c, None))(params, cache)
+    got = np.asarray(got)                              # [k, 3, vocab]
+    for s in range(k):
+        for i in range(2):
+            want = _ref_logits(reference, seqs[i])[-1]
+            assert np.abs(got[s, i] - want).max() < LOGIT_TOL
+            seqs[i].append(int(got[s, i].argmax()))
+    want_reads = _ref_experts_read(dims, seqs, [len(p) for p in prompts], k)
+    assert np.asarray(reads).tolist() == want_reads
+    # (at most 2 rows x top-2 a layer)
+    assert all(2 * 2 <= r <= 2 * 2 * cfg.experts_per_tok
+               for r in want_reads)
+    assert int(reads_all.sum()) > int(reads.sum())     # the dead row's
+    _, _, toks = jax.jit(lambda p, c, r: latent.decode_burst_staged(
+        p, c, r, active, k, cfg, sampling.SamplingParams(), table=table,
+        span=64))(params, cache, jax.random.key(0))
+    toks = np.asarray(toks)
+    assert toks.shape == (k, 3)
+    assert toks[:, 2].tolist() == want_reads
+    assert toks[:, :2].tolist() == [[seqs[i][len(prompts[i]) + 1 + s]
+                                     for i in range(2)] for s in range(k)]
+
+
+def _fetch_records(path):
+    timeline.save_now()
+    with open(path) as f:
+        return [e["args"] for e in json.load(f)["traceEvents"]
+                if e["name"] == "engine.decode.fetch"]
+
+
+def test_engine_burst_reports_experts_read_with_its_tokens(
+        cfg, dims, params, tmp_path, monkeypatch):
+    """One engine burst at 2 live slots of the pool: ``experts_read`` on
+    the fetch record and the ``/metrics`` counter equal the count
+    recomputed on the host from the reference's routing; an engine of
+    the Llama family reports no such field."""
+    path = tmp_path / "timeline.json"
+    monkeypatch.setenv(timeline.ENV_VAR, str(path))
+    e = _engine(params, cfg)
+    prompts = _prompts([20, 27], seed=13)
+    rids = [e.add_request(p, max_new_tokens=12) for p in prompts]
+    e.admit()
+    assert len(e.slot_req) == 2
+    counter = latent.EXPERTS_READ._require_default()
+    before = counter.value
+    out = e.decode_burst(4)
+    (rec,) = _fetch_records(path)
+    assert rec["k"] == 4 and rec["tokens"] == 8
+    by_rid = {r.rid: r for r in e.slot_req.values()}
+    seqs = [list(p) + by_rid[rid].tokens for p, rid in zip(prompts, rids)]
+    assert all(len(out[rid]) == 4 for rid in rids)
+    want = sum(_ref_experts_read(dims, seqs, [len(p) for p in prompts], 4))
+    assert rec["experts_read"] == want == counter.value - before
+    # about 2 rows x top-2 of 8 experts a layer, never all 8 x 2 x 4 steps
+    assert want < 4 * cfg.n_moe_layers * cfg.n_routed_experts // 2
+
+    lcfg = llama.CONFIGS["llama3-tiny"]
+    le = eng.InferenceEngine(
+        llama.init_params(jax.random.key(0), lcfg), lcfg, n_slots=4,
+        max_len=128, prompt_buckets=(16, 32, 64, 128), kv_block=16)
+    le.add_request(list(range(1, 9)), max_new_tokens=6)
+    le.admit()
+    assert le.decode_burst(4)
+    records = _fetch_records(path)
+    assert len(records) == 2 and "experts_read" not in records[-1]
+    assert records[-1]["tokens"] == 4
+    assert counter.value - before == want
 
 
 def test_selection_bias_moves_choices_not_weights(cfg, dims, params):
