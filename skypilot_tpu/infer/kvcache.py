@@ -12,8 +12,14 @@ TPU-first design notes
   never poison the cache because causal attention keeps positions
   < true_len independent of them, and decode masks rows >= length.
 * Decode processes *all slots together*: [slots, 1] tokens through the
-  stacked-layer ``lax.scan``, one scatter per layer to append K/V. This
-  is the JetStream-style generate step — MXU-batched across requests.
+  stacked-layer ``lax.scan`` with the cache read-only inside it, ONE
+  scatter per cache tensor after the loop to append every layer's K/V
+  rows (:func:`_staged_steps`). This is the JetStream-style generate
+  step — MXU-batched across requests.
+* The decoder layer is written ONCE (:func:`_layer_qkv`, the program's
+  own attention, :func:`_layer_out_ffn`), with one head (:func:`_head`)
+  and one row writer (:func:`_write_rows`); a program differs from the
+  next only in which rows attention reads and which it leaves behind.
 * Sharding composes with serving TP: cache kv-head dim maps to ``tp``,
   slot dim to (``dp``, ``fsdp``) via the standard rule table.
 * Two storage layouts share ONE implementation of every program:
@@ -77,36 +83,30 @@ SPARE_COLUMN = None
 
 
 def programs_for(cfg):
-    """The module holding the serve programs of ``cfg``'s family
-    (``prefill_batch``, ``insert``, ``prefill_chunk``, ``decode_step``,
-    ``decode_burst_staged``, ``init_paged_cache``): this module for
-    the GQA decoders, ``infer/latent.py`` for the latent-cache family.
-    The engine's jitted entry points call through it and are otherwise
-    one code path."""
+    """The module holding the serve programs of ``cfg``'s family: this
+    module for the GQA decoders, ``infer/latent.py`` for the
+    latent-cache family. The engine's jitted entry points call through
+    it and are otherwise one code path. What the engine uses of a
+    family module, with this module's signatures:
+
+    * ``init_paged_cache`` — the block pool and the per-slot
+      ``length`` / ``last_token``;
+    * ``prefill_batch`` + ``insert`` (``_admit_wave``), ``prefill_chunk``
+      (``_prefill_chunk``), ``decode_step`` (``_decode``),
+      ``decode_burst_staged`` (``_decode_burst``) and
+      ``verify_draft_staged`` (``_verify``; a family without the program
+      keeps the name and raises);
+    * ``SPARE_COLUMN`` — what the spare slot's column of a burst's
+      ``toks`` carries (``None``: nothing);
+    * ``token_bytes(cfg)`` — only of a family whose row is not this
+      module's per-head K/V (the engine computes those bytes itself).
+
+    Blocks move (allocation, copy-on-write, handoff, addressing) through
+    this module's :func:`row_tensors` helpers whatever the family."""
     if hasattr(cfg, "kv_lora_rank"):
         from skypilot_tpu.infer import latent
         return latent
     return sys.modules[__name__]
-
-
-def _ffn(cfg: llama.LlamaConfig, h: jax.Array, layer: Dict) -> jax.Array:
-    """Post-norm FFN: dense SwiGLU, or the sparse expert FFN when the
-    config is an MoE (aux loss is irrelevant at inference and dropped).
-    h: [B, S, D].
-
-    MoE + right-padded prefill is safe: capacity assignment is
-    position-ordered, so padding rows (after true_len) can never evict
-    a real token from an expert's buffer; decode steps see S=1 where
-    top-k choices always fit.
-    """
-    if hasattr(cfg, "n_experts"):
-        from skypilot_tpu.models import moe
-        out, _ = moe.moe_ffn(cfg, h, layer)
-        return out
-    g = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(cfg.dtype))
-    u = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(cfg.dtype))
-    return jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u,
-                      layer["w_down"].astype(cfg.dtype))
 
 
 def init_cache(cfg: llama.LlamaConfig, n_slots: int,
@@ -675,25 +675,137 @@ def _merge_attn_parts(acc, m, l, ss):
 
 
 # ---------------------------------------------------------------------------
+# The decoder layer, the head and the row writer every program shares
+# ---------------------------------------------------------------------------
+# A program below is: embed -> scan over layers of (front half, ITS OWN
+# attention, back half) -> head -> one write of the new rows.
+# Quantization, projection, adapter and FFN changes land here once.
+
+@jax.named_scope("qkv_proj")
+def _layer_qkv(cfg, layer, qlayer, x, cos, sin, llayer=None, aid=None):
+    """Layer front half: norm + q/k/v projections + rope. x: [B, S, D].
+    ``llayer``/``aid``: one layer's adapter-pool slice + per-row pool
+    ids — the per-row (A, B) gather adds its delta before rope, exactly
+    as a merged weight would."""
+    h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
+    q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
+    k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
+    v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
+    if llayer is not None:
+        q = q + _lora_in_delta(h, llayer["wq"], aid)
+        k = k + _lora_in_delta(h, llayer["wk"], aid)
+        v = v + _lora_in_delta(h, llayer["wv"], aid)
+    q = llama.apply_rope(q, cos, sin)
+    k = llama.apply_rope(k, cos, sin)
+    return q, k, v
+
+
+def _ffn(cfg: llama.LlamaConfig, h: jax.Array, layer: Dict,
+         qlayer=None) -> jax.Array:
+    """Post-norm FFN, h: [B, S, D] — the ONE place that asks which
+    model this is: the sparse expert FFN when the config is an MoE (aux
+    loss is irrelevant at inference and dropped; its experts stay
+    float), else dense SwiGLU, w8a8 for the matrices ``qlayer`` holds.
+
+    MoE + right-padded prefill is safe: capacity assignment is
+    position-ordered, so padding rows (after true_len) can never evict
+    a real token from an expert's buffer; decode steps see S=1 where
+    top-k choices always fit.
+    """
+    if hasattr(cfg, "n_experts"):
+        from skypilot_tpu.models import moe
+        out, _ = moe.moe_ffn(cfg, h, layer)
+        return out
+    g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1, cfg.dtype)
+    u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1, cfg.dtype)
+    return proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer, qlayer,
+                "w_down", 1, cfg.dtype)
+
+
+@jax.named_scope("out_ffn")
+def _layer_out_ffn(cfg, layer, qlayer, x, o, llayer=None, aid=None):
+    """Layer back half: output projection (+ adapter delta) + residual
+    + norm + FFN. x: [B, S, D]; o: the attention output for the same
+    rows, ``[B, S, H, hd]`` — or still grouped by kv head and in the
+    accumulator's fp32, as the paged programs leave it: it takes the
+    projection's shape and dtype here, under this scope."""
+    o = o.reshape(*x.shape[:2], cfg.n_heads, cfg.head_dim).astype(cfg.dtype)
+    y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
+    if llayer is not None:
+        y = y + _lora_out_delta(o, llayer["wo"], aid)
+    x = x + y
+    h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
+    return x + _ffn(cfg, h, layer, qlayer)
+
+
+@jax.named_scope("lm_head")
+def _head(cfg, params, qweights, x, pick=None):
+    """Final norm + LM head (fp or w8a8) -> fp32 logits for the rows it
+    is asked for: ``x`` [..., D] is normed whole (as each program always
+    has; norming only the picked rows is a program change), ``pick``
+    (optional) takes the rows that get logits, and the product runs at
+    exactly their shape — [W, D] (a wave), [D] (a chunk), or a decode
+    step's [B, 1, D], whose step axis is dropped after the product."""
+    x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if pick is not None:
+        x = pick(x)
+    if qweights is not None:
+        logits = qeinsum("...d,dv->...v", x, qweights["head"], 1,
+                         jnp.float32)
+    else:
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("...d,dv->...v", x, head.astype(cfg.dtype))
+    if logits.ndim == 3:
+        logits = logits[:, 0]
+    return logits.astype(jnp.float32)
+
+
+def _write_rows(cache: Cache, table, slots, idx, rows) -> Cache:
+    """Every layer's new rows land at logical ``(slots, idx)`` (arrays
+    that broadcast to one shape ``I``) — through the block table when
+    paged — by ONE scatter per cache tensor, after the layer loop (the
+    stacks are megabyte-scale next to the gigabyte-scale cache, and the
+    donated cache aliases through). ``rows``: ``(k, v)`` as
+    ``[L, *I, G, hd]`` in the cache's dtype, then the two scales
+    ``[L, *I, G]`` when the cache holds them. Scatter, not
+    ``dynamic_update_slice``: a window may poke past max_len, and
+    scatter DROPS out-of-bounds indices instead of clamping the whole
+    window backwards over valid rows (paged: the overflow, and a dead
+    or spare slot's rows, map to the sentinel block and drop the same
+    way). The caller names the ``kv_write`` scope and stamps
+    length / last_token."""
+    blk, off = _phys(cache, table, slots, idx)
+    out = dict(cache)
+    out["k"] = cache["k"].at[:, blk, off].set(rows[0])
+    out["v"] = cache["v"].at[:, blk, off].set(rows[1])
+    if "k_scale" in cache:
+        # Non-adjacent advanced indices lead with the broadcast dims:
+        # the update is [*I, L, G].
+        out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
+            jnp.moveaxis(rows[2], 0, -2))
+        out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
+            jnp.moveaxis(rows[3], 0, -2))
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Prefill
 # ---------------------------------------------------------------------------
 
 def prefill(params: llama.Params, tokens: jax.Array, true_len: jax.Array,
-            cfg: llama.LlamaConfig,
-            constrain=None, qweights=None) -> Tuple[Cache, jax.Array]:
+            cfg: llama.LlamaConfig, qweights=None) -> Tuple[Cache, jax.Array]:
     """Causal forward over ONE right-padded prompt ([S_bucket] int32);
     see :func:`prefill_batch` for the batched core. Returns
     ({"k","v"}: [L, S_bucket, G, hd], logits [vocab] fp32)."""
     prefix, logits = prefill_batch(params, tokens[None], true_len[None],
-                                   cfg, constrain=constrain,
-                                   qweights=qweights)
+                                   cfg, qweights=qweights)
     return {"k": prefix["k"][:, 0], "v": prefix["v"][:, 0]}, logits[0]
 
 
 def prefill_batch(params: llama.Params, tokens: jax.Array,
                   true_lens: jax.Array, cfg: llama.LlamaConfig,
-                  constrain=None, qweights=None, lora=None,
-                  aid=None, mesh=None,
+                  qweights=None, lora=None, aid=None, mesh=None,
                   heads_axis=None) -> Tuple[Cache, jax.Array]:
     """Causal forward over a WAVE of right-padded prompts.
 
@@ -712,62 +824,28 @@ def prefill_batch(params: llama.Params, tokens: jax.Array,
     long enough for the flash kernel then runs it per head shard
     (``ring_attention.local_attention``).
     """
-    if constrain is None:
-        constrain = lambda x, axes: x
     wq8 = qweights is not None
     S = tokens.shape[1]
     x = params["embed"].astype(cfg.dtype)[tokens]
     positions = jnp.arange(S)
     cos, sin = llama.rope_frequencies(cfg, positions)
 
-    def body(carry, layer_q):
-        x = carry
+    def body(x, layer_q):
         layer, qlayer, llayer = _layer_parts(layer_q, wq8,
                                              lora is not None)
-        with jax.named_scope("qkv_proj"):
-            h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
-            q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
-            k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
-            v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
-            if llayer is not None:
-                q = q + _lora_in_delta(h, llayer["wq"], aid)
-                k = k + _lora_in_delta(h, llayer["wk"], aid)
-                v = v + _lora_in_delta(h, llayer["wv"], aid)
-            q = llama.apply_rope(q, cos, sin)
-            k = llama.apply_rope(k, cos, sin)
+        q, k, v = _layer_qkv(cfg, layer, qlayer, x, cos, sin, llayer, aid)
         with jax.named_scope("attn_core"):
             o = ra.local_attention(q, k, v, mesh, causal=True,
                                    batch_axes=None, heads_axis=heads_axis)
-        with jax.named_scope("out_ffn"):
-            y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
-            if llayer is not None:
-                y = y + _lora_out_delta(o, llayer["wo"], aid)
-            x = x + y
-            h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
-            if wq8 and not hasattr(cfg, "n_experts"):
-                g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1,
-                         cfg.dtype)
-                u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1,
-                         cfg.dtype)
-                x = x + proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer,
-                             qlayer, "w_down", 1, cfg.dtype)
-            else:
-                x = x + _ffn(cfg, h, layer)
+        x = _layer_out_ffn(cfg, layer, qlayer, x, o, llayer, aid)
         return x, (k, v)
 
     xs = _scan_xs(params, qweights, lora)
     x, (ks, vs) = lax.scan(body, x, xs)        # ks: [L, W, S, G, hd]
-    with jax.named_scope("lm_head"):
-        x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        last = jnp.take_along_axis(
-            x, (true_lens - 1)[:, None, None], axis=1)[:, 0]       # [W, D]
-        if wq8:
-            logits = qeinsum("wd,dv->wv", last, qweights["head"], 1,
-                             jnp.float32)
-        else:
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = (last @ head.astype(cfg.dtype)).astype(jnp.float32)
+    logits = _head(
+        cfg, params, qweights, x,
+        lambda x: jnp.take_along_axis(
+            x, (true_lens - 1)[:, None, None], axis=1)[:, 0])      # [W, D]
     return {"k": ks, "v": vs}, logits
 
 
@@ -784,7 +862,6 @@ def insert(cache: Cache, prefix: Cache, slot: jax.Array,
     generation bit-identical); the spare slot's all-sentinel row drops
     dummy-wave writes entirely.
     """
-    out = dict(cache)
     pk, pv = prefix["k"], prefix["v"]
     quant = "k_scale" in cache
     if quant:
@@ -793,6 +870,7 @@ def insert(cache: Cache, prefix: Cache, slot: jax.Array,
         sdt = cache["k_scale"].dtype
         ks, vs = ks.astype(sdt), vs.astype(sdt)
     if table is None:
+        out = dict(cache)
         if quant:
             out["k_scale"] = lax.dynamic_update_slice(
                 cache["k_scale"], ks.transpose(0, 2, 1)[:, None],
@@ -805,17 +883,8 @@ def insert(cache: Cache, prefix: Cache, slot: jax.Array,
         out["v"] = lax.dynamic_update_slice(
             cache["v"], pv[:, None], (0, slot, 0, 0, 0))
     else:
-        S = pk.shape[1]
-        blk, off = _phys(cache, table, slot, jnp.arange(S))
-        out["k"] = cache["k"].at[:, blk, off].set(pk)
-        out["v"] = cache["v"].at[:, blk, off].set(pv)
-        if quant:
-            # Non-adjacent advanced indices put the broadcast dim
-            # first: update shape is [S, L, G].
-            out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
-                ks.transpose(1, 0, 2))
-            out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
-                vs.transpose(1, 0, 2))
+        out = _write_rows(cache, table, slot, jnp.arange(pk.shape[1]),
+                          (pk, pv, ks, vs) if quant else (pk, pv))
     out["length"] = cache["length"].at[slot].set(true_len)
     out["last_token"] = cache["last_token"].at[slot].set(first_token)
     return out
@@ -1033,17 +1102,8 @@ def prefill_chunk(params: llama.Params, cache: Cache,
         x, i = carry
         layer, qlayer, llayer = _layer_parts(layer_q, wq8,
                                              lora is not None)
-        with jax.named_scope("qkv_proj"):
-            h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
-            q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
-            k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
-            v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
-            if llayer is not None:
-                q = q + _lora_in_delta(h, llayer["wq"], aid_b)
-                k = k + _lora_in_delta(h, llayer["wk"], aid_b)
-                v = v + _lora_in_delta(h, llayer["wv"], aid_b)
-            q = llama.apply_rope(q, cos, sin)
-            k = llama.apply_rope(k, cos, sin)
+        q, k, v = _layer_qkv(cfg, layer, qlayer, x, cos, sin, llayer,
+                             aid_b)
         with jax.named_scope("attn_core"):
             kr, vr = k[0], v[0]                       # [C, G, hd]
             if quant:
@@ -1053,7 +1113,7 @@ def prefill_chunk(params: llama.Params, cache: Cache,
             else:
                 ys = (kr.astype(kdt), vr.astype(kdt))
             # bf16 dots, fp32 accumulation — int8 converts to bf16 exactly
-            # (see decode_step's note).
+            # (see _staged_attn_layer's note).
             qh = q[0].reshape(C, G, rep, hd).astype(jnp.bfloat16)
             ss = jnp.einsum("cgrk,jgk->cgrj", qh, kr.astype(jnp.bfloat16),
                             preferred_element_type=jnp.float32) * scale
@@ -1097,68 +1157,27 @@ def prefill_chunk(params: llama.Params, cache: Cache,
                                    ws.astype(jnp.bfloat16),
                                    vr.astype(jnp.bfloat16),
                                    preferred_element_type=jnp.float32)
-            o = o.reshape(1, C, cfg.n_heads, hd).astype(cfg.dtype)
-        with jax.named_scope("out_ffn"):
-            y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
-            if llayer is not None:
-                y = y + _lora_out_delta(o, llayer["wo"], aid_b)
-            x = x + y
-            h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
-            if wq8 and not hasattr(cfg, "n_experts"):
-                g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1,
-                         cfg.dtype)
-                u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1,
-                         cfg.dtype)
-                x = x + proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer,
-                             qlayer, "w_down", 1, cfg.dtype)
-            else:
-                x = x + _ffn(cfg, h, layer)
+        x = _layer_out_ffn(cfg, layer, qlayer, x, o, llayer, aid_b)
         return (x, i + 1), ys
 
     xs = _scan_xs(params, qweights, lora)
     (x, _), ys = lax.scan(body, (x, jnp.int32(0)), xs)
 
     if final:
-        with jax.named_scope("lm_head"):
-            x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-            last = lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
-                                            keepdims=False)      # [D]
-            if wq8:
-                logits = qeinsum("d,dv->v", last, qweights["head"], 1,
-                                 jnp.float32)
-            else:
-                head = (params["embed"].T if cfg.tie_embeddings
-                        else params["lm_head"])
-                logits = (last @ head.astype(cfg.dtype)).astype(jnp.float32)
+        logits = _head(
+            cfg, params, qweights, x,
+            lambda x: lax.dynamic_index_in_dim(x[0], n_valid - 1, 0,
+                                               keepdims=False))      # [D]
         with jax.named_scope("sample"):
             rng, sub = jax.random.split(rng)
             tok = sampling_mod.sample(logits, sub, sp)
     else:
         tok = jnp.zeros((), jnp.int32)
 
-    # Chunk rows land at logical [slot, start:start+C]. Scatter (not
-    # dynamic_update_slice): a final partial chunk's window may poke
-    # past max_len, and scatter DROPS out-of-bounds indices instead of
-    # clamping the whole window backwards over valid rows (paged: the
-    # overflow maps to the sentinel block, dropped the same way).
+    # Chunk rows land at logical [slot, start:start+C]; a final partial
+    # chunk's window may poke past max_len (:func:`_write_rows` drops it).
     with jax.named_scope("kv_write"):
-        idx = start + jnp.arange(C)
-        blk, off = _phys(cache, table, slot, idx)
-        out = dict(cache)
-        if quant:
-            kq_l, vq_l, ks_l, vs_l = ys       # [L,C,G,hd] / [L,C,G]
-            out["k"] = cache["k"].at[:, blk, off].set(kq_l)
-            out["v"] = cache["v"].at[:, blk, off].set(vq_l)
-            # Non-adjacent advanced indices put the broadcast dim first:
-            # update shape is [C, L, G].
-            out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
-                ks_l.transpose(1, 0, 2))
-            out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
-                vs_l.transpose(1, 0, 2))
-        else:
-            k_l, v_l = ys
-            out["k"] = cache["k"].at[:, blk, off].set(k_l)
-            out["v"] = cache["v"].at[:, blk, off].set(v_l)
+        out = _write_rows(cache, table, slot, start + jnp.arange(C), ys)
         out["length"] = cache["length"].at[slot].set(new_len)
         if final:
             out["last_token"] = cache["last_token"].at[slot].set(tok)
@@ -1168,199 +1187,6 @@ def prefill_chunk(params: llama.Params, cache: Cache,
 # ---------------------------------------------------------------------------
 # Decode
 # ---------------------------------------------------------------------------
-
-@jax.named_scope("qkv_proj")
-def _decode_qkv(cfg, layer, qlayer, x, cos, sin, llayer=None,
-                aid=None):
-    """Shared decode-layer front half: norm + q/k/v projections + rope
-    (used by decode_step AND decode_burst_staged so quantization or
-    projection changes land in ONE place). ``llayer``/``aid``: one
-    layer's adapter-pool slice + per-slot pool ids — the per-slot
-    (A, B) gather adds its delta before rope, exactly as a merged
-    weight would."""
-    h = llama.rms_norm(x, layer["ln1"], cfg.norm_eps)
-    q = proj("bsd,dhk->bshk", h, layer, qlayer, "wq", 1, cfg.dtype)
-    k = proj("bsd,dhk->bshk", h, layer, qlayer, "wk", 1, cfg.dtype)
-    v = proj("bsd,dhk->bshk", h, layer, qlayer, "wv", 1, cfg.dtype)
-    if llayer is not None:
-        q = q + _lora_in_delta(h, llayer["wq"], aid)
-        k = k + _lora_in_delta(h, llayer["wk"], aid)
-        v = v + _lora_in_delta(h, llayer["wv"], aid)
-    q = llama.apply_rope(q, cos, sin)
-    k = llama.apply_rope(k, cos, sin)
-    return q, k, v
-
-
-@jax.named_scope("out_ffn")
-def _decode_out_ffn(cfg, layer, qlayer, wq8, x, o, llayer=None,
-                    aid=None):
-    """Shared decode-layer back half: output projection + residual +
-    FFN (w8a8 dense when quantized weights are present, the model's
-    own _ffn — incl. MoE experts — otherwise)."""
-    B = x.shape[0]
-    o = o.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(cfg.dtype)
-    y = proj("bshk,hkd->bsd", o, layer, qlayer, "wo", 2, cfg.dtype)
-    if llayer is not None:
-        y = y + _lora_out_delta(o, llayer["wo"], aid)
-    x = x + y
-    h = llama.rms_norm(x, layer["ln2"], cfg.norm_eps)
-    if wq8 and not hasattr(cfg, "n_experts"):
-        g = proj("bsd,df->bsf", h, layer, qlayer, "w_gate", 1,
-                 cfg.dtype)
-        u = proj("bsd,df->bsf", h, layer, qlayer, "w_up", 1, cfg.dtype)
-        m = proj("bsf,fd->bsd", jax.nn.silu(g) * u, layer, qlayer,
-                 "w_down", 1, cfg.dtype)
-        return x + m
-    return x + _ffn(cfg, h, layer)
-
-
-@jax.named_scope("lm_head")
-def _decode_head(cfg, params, qweights, x):
-    """Shared final-norm + LM head (fp or w8a8)."""
-    x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if qweights is not None:
-        return qeinsum("bsd,dv->bsv", x, qweights["head"], 1,
-                       jnp.float32)[:, 0]
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    return jnp.einsum("bsd,dv->bsv", x,
-                      head.astype(cfg.dtype))[:, 0].astype(jnp.float32)
-
-
-def decode_step(params: llama.Params, cache: Cache,
-                cfg: llama.LlamaConfig,
-                constrain=None, qweights=None,
-                table=None, span=None, lora=None,
-                aid=None) -> Tuple[Cache, jax.Array]:
-    """One token for every slot. Returns (cache', logits [slots, vocab]).
-
-    ``qweights`` (from ``quantize_block_weights``/``quantize_head``):
-    run the seven block matmuls + the LM head as w8a8 int8 — half the
-    weight HBM reads and the 2x int8 MXU path, the decode bottleneck.
-    ``table`` ([slots, blocks_per_slot + 1] int32): paged layout —
-    reads gather each slot's blocks in logical order, the pending-row
-    scatter maps through the table (sentinel -> dropped).
-    ``span`` (static): attention reads only the first ``span`` logical
-    rows — valid whenever every active slot's length <= span (the
-    engine's span-bucket selection guarantees it); the pending-row
-    scatter still routes through the FULL table, so writes are
-    untouched. Bit-identical to the full view: the rows dropped were
-    all masked to exact-zero softmax weight.
-    """
-    if constrain is None:
-        constrain = lambda x, axes: x
-    B = cache["length"].shape[0]
-    M = span if span is not None else _logical_rows(cache, table)
-    G, hd = cfg.n_kv_heads, cfg.head_dim
-    rep = cfg.n_heads // G
-
-    tokens = cache["last_token"][:, None]                     # [B, 1]
-    # ``length`` counts rows already in the cache (prompt + committed
-    # tokens); the pending token's K/V row is written at index length.
-    pos = cache["length"]                                     # [B]
-    x = params["embed"].astype(cfg.dtype)[tokens]             # [B, 1, D]
-    cos, sin = llama.rope_frequencies(cfg, pos[:, None])      # [B,1,hd/2]
-
-    # Stored rows are STRICTLY below ``length``; the pending token
-    # joins attention as an explicit SELF-TERM (one extra logit per
-    # head) and its K/V rows are scattered into the cache ONCE — for
-    # all layers together — after the layer scan. Keeping the cache a
-    # scan INVARIANT (read-only inside the loop) instead of a carry is
-    # what the decode-step's HBM budget lives on: the carried version
-    # round-tripped each layer's 82 MB K/V slice through
-    # dynamic-slice/row-update/dynamic-update (~330 MB of copy traffic
-    # per layer, ~12 ms of a 31 ms 8B step), and even the scatter-into-
-    # carry variant paid 4 serialized scatters x 32 layers of fixed op
-    # overhead. Self-term math is identical: the pending row's score
-    # uses the SAME quantized values a read-back would see, and the
-    # softmax simply sees that logit at the end of the row instead of
-    # at index ``length``.
-    valid = (jnp.arange(M)[None, :] < cache["length"][:, None])   # [B, M]
-    neg = jnp.asarray(-1e30, jnp.float32)
-    scale = hd ** -0.5
-    batch_ix = jnp.arange(B)
-
-    quant = "k_scale" in cache
-    wq8 = qweights is not None
-    sdt = cache["k_scale"].dtype if quant else None
-
-    def body(carry, layer_q):
-        x, i = carry
-        layer, qlayer, llayer = _layer_parts(layer_q, wq8,
-                                             lora is not None)
-        q, k, v = _decode_qkv(cfg, layer, qlayer, x, cos, sin,
-                              llayer, aid)
-        with jax.named_scope("attn_core"):
-            if quant:
-                kq, ks = quantize_rows(k[:, 0])     # ks/vs: [B, G]
-                vq, vs = quantize_rows(v[:, 0])
-                ks, vs = ks.astype(sdt), vs.astype(sdt)
-                k_new = kq.astype(jnp.bfloat16)     # exact: int8 fits bf16
-                v_new = vq.astype(jnp.float32) * vs.astype(jnp.float32)[..., None]
-                ys = (kq, vq, ks, vs)
-            else:
-                kq, vq = k[:, 0], v[:, 0]
-                ks = vs = None
-                k_new = kq.astype(jnp.bfloat16)
-                v_new = vq.astype(jnp.float32)
-                ys = (kq, vq)
-            ck, cv, cks, cvs = _gather_kv_layer(cache, i, table, span)
-            # The attention dots run in bf16 with fp32 ACCUMULATION. The
-            # int8 cache converts to bf16 EXACTLY (integers <= 127 carry no
-            # rounding in an 8-bit mantissa) and each bf16xbf16 product is
-            # exact in the fp32 accumulator, so the scores match a full
-            # fp32 dot while the materialized cache-sized intermediate is
-            # half the size. Per-row scales stay linear in the contraction:
-            # K's scale applies to the SCORES and V's folds into the
-            # softmax weights — nothing dequantized at cache shape ever
-            # hits fp32.
-            qh = q[:, 0].reshape(B, G, rep, hd).astype(jnp.bfloat16)
-            s = jnp.einsum("bgrk,bmgk->bgrm", qh, ck.astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32) * scale
-            s_self = jnp.einsum("bgrk,bgk->bgr", qh, k_new,
-                                preferred_element_type=jnp.float32) * scale
-            if quant:
-                s = s * cks[:, :, None, :]
-                s_self = s_self * ks.astype(jnp.float32)[:, :, None]
-            s = jnp.where(valid[:, None, None, :], s, neg)
-            w = jax.nn.softmax(jnp.concatenate([s, s_self[..., None]], -1),
-                               axis=-1)
-            wm, w_self = w[..., :M], w[..., M]
-            if quant:
-                wm = wm * cvs[:, :, None, :]
-            o = jnp.einsum("bgrm,bmgk->bgrk", wm.astype(jnp.bfloat16),
-                           cv.astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32)
-            o = o + w_self[..., None] * v_new[:, :, None, :]
-        x = _decode_out_ffn(cfg, layer, qlayer, wq8, x, o, llayer, aid)
-        return (x, i + 1), ys
-
-    xs = _scan_xs(params, qweights, lora)
-    (x, _), ys = lax.scan(body, (x, jnp.int32(0)), xs)
-    logits = _decode_head(cfg, params, qweights, x)
-    # One batched scatter per cache array: every layer's pending row
-    # lands at logical [l, b, pos[b]] (the ys stacks are megabyte-scale
-    # next to the gigabyte-scale cache, and the donated cache aliases
-    # through).
-    with jax.named_scope("kv_write"):
-        blk, off = _phys(cache, table, batch_ix, pos)
-        out = dict(cache)
-        if quant:
-            kq_l, vq_l, ks_l, vs_l = ys           # [L,B,G,hd] / [L,B,G]
-            out["k"] = cache["k"].at[:, blk, off].set(kq_l)
-            out["v"] = cache["v"].at[:, blk, off].set(vq_l)
-            # Non-adjacent advanced indices put the broadcast dim first:
-            # update shape is [B, L, G].
-            out["k_scale"] = cache["k_scale"].at[:, blk, :, off].set(
-                ks_l.transpose(1, 0, 2))
-            out["v_scale"] = cache["v_scale"].at[:, blk, :, off].set(
-                vs_l.transpose(1, 0, 2))
-        else:
-            k_l, v_l = ys
-            out["k"] = cache["k"].at[:, blk, off].set(k_l)
-            out["v"] = cache["v"].at[:, blk, off].set(v_l)
-    return out, logits
-
 
 @jax.named_scope("kv_write")
 def commit_tokens(cache: Cache, tokens: jax.Array,
@@ -1376,16 +1202,17 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
                        i, s, sk, sv, sks, svs, valid_cache,
                        stage_valid, batch_ix, span=None, pos0=None,
                        kv_kernel=False, llayer=None, aid=None):
-    """One decoder layer of a staged-burst step: the current step's
-    K/V rows land in the staging buffers, attention runs as big-cache
-    dot (rows masked by ``valid_cache``) ++ staged-columns dot
-    (columns masked by ``stage_valid``), and the big cache stays a
-    pure invariant. Shared VERBATIM by :func:`decode_burst_staged` and
-    :func:`verify_draft_staged` — the speculative parity guarantee is
-    precisely that both programs run THIS math, so an edit here can
-    never drift one without the other. ``span`` (static) bounds the
-    big-cache read to the first ``span`` logical rows; the caller's
-    ``valid_cache`` mask must already be span-shaped.
+    """One decoder layer of a staged step: the current step's K/V rows
+    land in the staging buffers, attention runs as big-cache dot (rows
+    masked by ``valid_cache``) ++ staged-columns dot (columns masked by
+    ``stage_valid``) under ONE softmax, and the big cache stays a pure
+    invariant. The only decode attention in the module: the step, burst
+    and verify programs all run THIS math (:func:`_staged_steps`), so
+    the speculative parity guarantee and step == burst-of-one hold by
+    construction — an edit here can never drift one without the others.
+    ``span`` (static) bounds the big-cache read to the first ``span``
+    logical rows; the caller's ``valid_cache`` mask must already be
+    span-shaped.
 
     ``kv_kernel`` (static): run the big-cache block through the Pallas
     paged-attention kernel instead of the gather — the kernel walks
@@ -1400,7 +1227,6 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
     Returns (x', sk, sv, sks, svs).
     """
     quant = "k_scale" in cache
-    wq8 = qlayer is not None
     kdt = cache["k"].dtype
     sdt = cache["k_scale"].dtype if quant else None
     B = x.shape[0]
@@ -1410,8 +1236,7 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
     scale = hd ** -0.5
     neg = jnp.asarray(-1e30, jnp.float32)
 
-    q, kk, v = _decode_qkv(cfg, layer, qlayer, x, cos, sin, llayer,
-                           aid)
+    q, kk, v = _layer_qkv(cfg, layer, qlayer, x, cos, sin, llayer, aid)
     with jax.named_scope("attn_core"):
         if quant:
             kq, ksc = quantize_rows(kk[:, 0])
@@ -1426,8 +1251,17 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
             sv = sv.at[i, batch_ix, s].set(v[:, 0].astype(kdt))
         lk = lax.dynamic_index_in_dim(sk, i, 0, False)
         lv = lax.dynamic_index_in_dim(sv, i, 0, False)
-        # bf16 dots, fp32 accumulation — int8 converts to bf16 exactly
-        # (see decode_step's note).
+        # The attention dots run in bf16 with fp32 ACCUMULATION. The
+        # int8 cache converts to bf16 EXACTLY (integers <= 127 carry no
+        # rounding in an 8-bit mantissa) and each bf16xbf16 product is
+        # exact in the fp32 accumulator, so the scores match a full
+        # fp32 dot while the materialized cache-sized intermediate is
+        # half the size. Per-row scales stay linear in the contraction:
+        # K's scale applies to the SCORES and V's folds into the
+        # softmax weights — nothing dequantized at cache shape ever
+        # hits fp32. The step's own row is read back from the staging
+        # buffer, so its score uses the SAME quantized values a later
+        # step's cache read will see.
         qh = q[:, 0].reshape(B, G, rep, hd).astype(jnp.bfloat16)
         ss = jnp.einsum("bgrk,bjgk->bgrj", qh,
                         lk.astype(jnp.bfloat16),
@@ -1468,31 +1302,122 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
                                ws.astype(jnp.bfloat16),
                                lv.astype(jnp.bfloat16),
                                preferred_element_type=jnp.float32)
-    x = _decode_out_ffn(cfg, layer, qlayer, wq8, x, o, llayer, aid)
+    x = _layer_out_ffn(cfg, layer, qlayer, x, o, llayer, aid)
     return x, sk, sv, sks, svs
 
 
-@jax.named_scope("kv_write")
-def _flush_staged_rows(cache: Cache, table, pos0, batch_ix,
-                       sk, sv, sks, svs) -> Cache:
-    """One batched scatter per cache array: every staged window row
-    lands at logical [b, pos0[b] + j] (through the block table when
-    paged — sentinel/overflow rows drop). Shared by the burst and
-    verify programs; the caller updates length/last_token."""
-    W = sk.shape[2]
-    idx = pos0[:, None] + jnp.arange(W)[None, :]           # [B, W]
-    blk, off = _phys(cache, table, batch_ix[:, None], idx)
-    out = dict(cache)
-    out["k"] = cache["k"].at[:, blk, off].set(sk)
-    out["v"] = cache["v"].at[:, blk, off].set(sv)
-    if "k_scale" in cache:
-        # Non-adjacent advanced indices lead with the broadcast [B, W]
-        # dims: updates are [B, W, L, G].
-        out["k_scale"] = cache["k_scale"].at[
-            :, blk, :, off].set(sks.transpose(1, 2, 0, 3))
-        out["v_scale"] = cache["v_scale"].at[
-            :, blk, :, off].set(svs.transpose(1, 2, 0, 3))
-    return out
+def _staged_steps(params: llama.Params, cache: Cache,
+                  cfg: llama.LlamaConfig, W: int, xs, state, token, emit,
+                  qweights=None, table=None, span=None, kv_kernel=False,
+                  lora=None, aid=None):
+    """``W`` decode steps for every slot with the big cache a read-only
+    scan INVARIANT — the one scaffold under :func:`decode_step` (W = 1),
+    :func:`decode_burst_staged` and :func:`verify_draft_staged`
+    (``latent._staged_steps`` is the latent family's).
+
+    Each step's K/V rows land in a small STAGING buffer
+    ([L, slots, W, G, hd] — megabytes) and attention runs as big-cache
+    dot (rows < the start lengths, a CONSTANT mask) ++ staged-columns
+    dot (cols <= step); ONE batched scatter per tensor flushes all W
+    rows to logical [b, length[b] + j] after the step loop
+    (:func:`_write_rows`). That is what decode's HBM budget lives on:
+    a cache CARRIED through the layer scan round-tripped each layer's
+    82 MB K/V slice through dynamic-slice / row-update /
+    dynamic-update (~330 MB of copy traffic per layer, ~12 ms of a
+    31 ms 8B step); scattering into the carried cache every step still
+    paid 4 serialized scatters x 32 layers of fixed op overhead, XLA
+    could not keep them fully in place (~2.3 ms of a 24.9 ms 8B step),
+    and carried-cache reads fuse worse than invariant reads (measured:
+    the staged burst decodes in ~18-20 ms/step, ~25% faster end to end).
+
+    What a driver brings: ``xs`` (leading dim W; step s sees its slice
+    ``x``), a carried ``state``, ``token(state, x) -> [B]`` the token
+    step s consumes and ``emit(logits [B, vocab], state, x) ->
+    (state', emitted)``. ``qweights`` runs the seven block matmuls +
+    the head w8a8; ``table`` routes reads and the flush through the
+    block table; ``span`` / ``kv_kernel`` as :func:`_staged_attn_layer`
+    (the flush scatters through the FULL table, so writes are
+    untouched by a span); ``lora``/``aid``: the adapter pool + per-slot
+    ids. Returns (cache with the W rows flushed — length / last_token
+    untouched, the driver's to stamp —, final state, emitted [W, ...]).
+    """
+    B = cache["length"].shape[0]
+    M = span if span is not None else _logical_rows(cache, table)
+    G, hd = cfg.n_kv_heads, cfg.head_dim
+    L = cfg.n_layers
+    quant = "k_scale" in cache
+    wq8 = qweights is not None
+    sdt = cache["k_scale"].dtype if quant else None
+    kdt = cache["k"].dtype
+
+    # ``length`` counts rows already in the cache (prompt + committed
+    # tokens); step s's row is written at index length + s.
+    pos0 = cache["length"]
+    valid_cache = jnp.arange(M)[None, :] < pos0[:, None]   # [B, M]
+    batch_ix = jnp.arange(B)
+
+    stage_k = jnp.zeros((L, B, W, G, hd), kdt)
+    stage_v = jnp.zeros((L, B, W, G, hd), kdt)
+    zero = jnp.zeros((), jnp.float32)
+    stage_ks = jnp.zeros((L, B, W, G), sdt) if quant else zero
+    stage_vs = jnp.zeros((L, B, W, G), sdt) if quant else zero
+
+    def step(carry, x_s):
+        with jax.named_scope("decode_step"):
+            x_in, s = x_s
+            state, sk, sv, sks, svs = carry
+            x = params["embed"].astype(cfg.dtype)[
+                token(state, x_in)[:, None]]
+            pos = pos0 + s
+            cos, sin = llama.rope_frequencies(cfg, pos[:, None])
+            stage_valid = jnp.arange(W)[None, :] <= s     # [1, W]
+
+            def body(carry2, layer_q):
+                x, i, sk, sv, sks, svs = carry2
+                layer, qlayer, llayer = _layer_parts(layer_q, wq8,
+                                                     lora is not None)
+                x, sk, sv, sks, svs = _staged_attn_layer(
+                    cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
+                    sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
+                    span, pos0, kv_kernel, llayer, aid)
+                return (x, i + 1, sk, sv, sks, svs), None
+
+            xs_l = _scan_xs(params, qweights, lora)
+            (x, _, sk, sv, sks, svs), _ = lax.scan(
+                body, (x, jnp.int32(0), sk, sv, sks, svs), xs_l)
+            logits = _head(cfg, params, qweights, x)
+            state, emitted = emit(logits, state, x_in)
+        return (state, sk, sv, sks, svs), emitted
+
+    init = (state, stage_k, stage_v, stage_ks, stage_vs)
+    (state, sk, sv, sks, svs), emitted = lax.scan(
+        step, init, (xs, jnp.arange(W)))
+    with jax.named_scope("kv_write"):
+        idx = pos0[:, None] + jnp.arange(W)[None, :]           # [B, W]
+        out = _write_rows(cache, table, batch_ix[:, None], idx,
+                          (sk, sv, sks, svs))
+    return out, state, emitted
+
+
+def decode_step(params: llama.Params, cache: Cache,
+                cfg: llama.LlamaConfig, qweights=None, table=None,
+                span=None, lora=None, aid=None) -> Tuple[Cache, jax.Array]:
+    """One token for every slot: a burst of one (:func:`_staged_steps`
+    at W = 1) that emits its logits instead of sampling, so they equal
+    :func:`decode_burst_staged`'s first step bit for bit. Returns
+    (cache' with the pending row written at ``length``, logits
+    [slots, vocab]); the caller samples and commits
+    (:func:`commit_tokens`). ``span`` (static) is valid whenever every
+    active slot's length <= span (the engine's span-bucket selection
+    guarantees it): the rows dropped were all masked to exact-zero
+    softmax weight.
+    """
+    out, _, logits = _staged_steps(
+        params, cache, cfg, 1, None, (),
+        lambda state, x: cache["last_token"],
+        lambda logits, state, x: (state, logits),
+        qweights=qweights, table=table, span=span, lora=lora, aid=aid)
+    return out, logits[0]
 
 
 def decode_burst_staged(params: llama.Params, cache: Cache,
@@ -1502,24 +1427,9 @@ def decode_burst_staged(params: llama.Params, cache: Cache,
                         kv_kernel=False, lora=None, aid=None
                         ) -> Tuple[Cache, jax.Array, jax.Array]:
     """k decode steps with a per-BURST cache flush (the engine's burst
-    program; trace under jit with cache+rng donated).
-
-    Within the burst, each step's K/V rows land in a small STAGING
-    buffer ([L, slots, k, G, hd] — megabytes) and attention runs as
-    big-cache dot (rows < the burst-start lengths, a CONSTANT mask) ++
-    staged-columns dot (cols <= step). The big cache is therefore a
-    pure scan INVARIANT: one batched scatter flushes all k rows after
-    the step loop. The previous formulation scattered into the carried
-    cache every step — XLA couldn't keep those fully in place, costing
-    ~2.3 ms of a 24.9 ms 8B step, and carried-cache reads fuse worse
-    than invariant reads (measured: this version decodes the same
-    burst in ~18-20 ms/step, ~25% faster end to end).
-
-    Logits equal the per-step formulation's up to summation order
-    (the same score set, softmaxed with staged columns concatenated
-    after the cache block instead of interleaved at their cache
-    positions), so greedy tokens can differ on near-ties exactly as
-    any kernel reorganization allows.
+    program; trace under jit with cache+rng donated): the staged
+    scaffold (:func:`_staged_steps`) with each step's SAMPLED token fed
+    to the next.
 
     Dead slots (inactive, or retired mid-burst) write rows past their
     logical end; flush indices beyond the buffer are DROPPED by JAX
@@ -1532,8 +1442,7 @@ def decode_burst_staged(params: llama.Params, cache: Cache,
     ``span`` logical rows. Correct whenever every ACTIVE slot's
     burst-start length <= span (the engine's bucket selection); an
     inactive slot whose length exceeds the span computes garbage that
-    is never committed, exactly like any other dead-slot row. The
-    flush scatters through the FULL table, so writes are unchanged.
+    is never committed, exactly like any other dead-slot row.
 
     ``kv_kernel`` (static): route the big-cache read through the
     Pallas paged-attention kernel (paged only — see
@@ -1541,62 +1450,19 @@ def decode_burst_staged(params: llama.Params, cache: Cache,
     the flag off, which stays the oracle.
     Returns (cache', rng', toks [k, slots]).
     """
-    B = cache["length"].shape[0]
-    M = span if span is not None else _logical_rows(cache, table)
-    G, hd = cfg.n_kv_heads, cfg.head_dim
-    L = cfg.n_layers
-    quant = "k_scale" in cache
-    wq8 = qweights is not None
-    sdt = cache["k_scale"].dtype if quant else None
-    kdt = cache["k"].dtype
-
-    pos0 = cache["length"]                           # burst-start rows
-    valid_cache = jnp.arange(M)[None, :] < pos0[:, None]   # [B, M]
-    batch_ix = jnp.arange(B)
-
     rng, sub = jax.random.split(rng)
     keys = jax.random.split(sub, k)
 
-    stage_k = jnp.zeros((L, B, k, G, hd), kdt)
-    stage_v = jnp.zeros((L, B, k, G, hd), kdt)
-    zero = jnp.zeros((), jnp.float32)
-    stage_ks = jnp.zeros((L, B, k, G), sdt) if quant else zero
-    stage_vs = jnp.zeros((L, B, k, G), sdt) if quant else zero
+    def emit(logits, last, key):
+        with jax.named_scope("sample"):
+            tok = sampling_mod.sample(logits, key, sp)
+        return jnp.where(active, tok, last), tok
 
-    def step(carry, key_s):
-        with jax.named_scope("decode_step"):
-            key, s = key_s
-            last, sk, sv, sks, svs = carry
-            x = params["embed"].astype(cfg.dtype)[last[:, None]]
-            pos = pos0 + s
-            cos, sin = llama.rope_frequencies(cfg, pos[:, None])
-            stage_valid = jnp.arange(k)[None, :] <= s     # [1, k]
-
-            def body(carry2, layer_q):
-                x, i, sk, sv, sks, svs = carry2
-                layer, qlayer, llayer = _layer_parts(layer_q, wq8,
-                                                     lora is not None)
-                x, sk, sv, sks, svs = _staged_attn_layer(
-                    cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
-                    sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
-                    span, pos0, kv_kernel, llayer, aid)
-                return (x, i + 1, sk, sv, sks, svs), None
-
-            xs = _scan_xs(params, qweights, lora)
-            (x, _, sk, sv, sks, svs), _ = lax.scan(
-                body, (x, jnp.int32(0), sk, sv, sks, svs), xs)
-            logits = _decode_head(cfg, params, qweights, x)
-            with jax.named_scope("sample"):
-                tok = sampling_mod.sample(logits, key, sp)
-            last = jnp.where(active, tok, last)
-        return (last, sk, sv, sks, svs), tok
-
-    init = (cache["last_token"], stage_k, stage_v, stage_ks, stage_vs)
-    (last, sk, sv, sks, svs), toks = lax.scan(
-        step, init, (keys, jnp.arange(k)))
-
-    out = _flush_staged_rows(cache, table, pos0, batch_ix,
-                             sk, sv, sks, svs)
+    out, last, toks = _staged_steps(
+        params, cache, cfg, k, keys, cache["last_token"],
+        lambda last, key: last, emit,
+        qweights=qweights, table=table, span=span, kv_kernel=kv_kernel,
+        lora=lora, aid=aid)
     out["length"] = cache["length"] + k * active.astype(jnp.int32)
     out["last_token"] = last
     return out, rng, toks
@@ -1660,64 +1526,23 @@ def verify_draft_staged(params: llama.Params, cache: Cache,
     first ``n_commit[b]`` of row b are the committed tokens —
     n_commit [B] int32, 0 for inactive slots).
     """
-    B = cache["length"].shape[0]
-    W = k + 1
-    M = span if span is not None else _logical_rows(cache, table)
-    G, hd = cfg.n_kv_heads, cfg.head_dim
-    L = cfg.n_layers
-    quant = "k_scale" in cache
-    wq8 = qweights is not None
-    sdt = cache["k_scale"].dtype if quant else None
-    kdt = cache["k"].dtype
-
-    pos0 = cache["length"]                           # burst-start rows
-    valid_cache = jnp.arange(M)[None, :] < pos0[:, None]   # [B, M]
-    batch_ix = jnp.arange(B)
-
     # Window tokens: the pending token then the draft — the exact
     # sequence sequential decode would consume while every draft
     # position matches.
     window = jnp.concatenate(
         [cache["last_token"][:, None], draft.astype(jnp.int32)],
-        axis=1)                                      # [B, W]
+        axis=1)                                      # [B, k + 1]
 
-    stage_k = jnp.zeros((L, B, W, G, hd), kdt)
-    stage_v = jnp.zeros((L, B, W, G, hd), kdt)
-    zero = jnp.zeros((), jnp.float32)
-    stage_ks = jnp.zeros((L, B, W, G), sdt) if quant else zero
-    stage_vs = jnp.zeros((L, B, W, G), sdt) if quant else zero
+    def emit(logits, state, tok):
+        with jax.named_scope("sample"):
+            return state, sampling_mod.argmax_tokens(logits)
 
-    def step(carry, tok_s):
-        with jax.named_scope("decode_step"):
-            tok, s = tok_s
-            sk, sv, sks, svs = carry
-            x = params["embed"].astype(cfg.dtype)[tok[:, None]]
-            pos = pos0 + s
-            cos, sin = llama.rope_frequencies(cfg, pos[:, None])
-            stage_valid = jnp.arange(W)[None, :] <= s     # [1, W]
-
-            def body(carry2, layer_q):
-                x, i, sk, sv, sks, svs = carry2
-                layer, qlayer, llayer = _layer_parts(layer_q, wq8,
-                                                     lora is not None)
-                x, sk, sv, sks, svs = _staged_attn_layer(
-                    cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
-                    sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
-                    span, pos0, kv_kernel, llayer, aid)
-                return (x, i + 1, sk, sv, sks, svs), None
-
-            xs = _scan_xs(params, qweights, lora)
-            (x, _, sk, sv, sks, svs), _ = lax.scan(
-                body, (x, jnp.int32(0), sk, sv, sks, svs), xs)
-            logits = _decode_head(cfg, params, qweights, x)
-            with jax.named_scope("sample"):
-                out_tok = sampling_mod.argmax_tokens(logits)
-        return (sk, sv, sks, svs), out_tok
-
-    init = (stage_k, stage_v, stage_ks, stage_vs)
-    (sk, sv, sks, svs), toks = lax.scan(
-        step, init, (window.T, jnp.arange(W)))
-    toks = toks.T                                     # [B, W]
+    out, _, toks = _staged_steps(
+        params, cache, cfg, k + 1, window.T, (),
+        lambda state, tok: tok, emit,
+        qweights=qweights, table=table, span=span, kv_kernel=kv_kernel,
+        lora=lora, aid=aid)
+    toks = toks.T                                     # [B, k + 1]
 
     # Accepted prefix: out[s] must reproduce draft position s, and
     # padding positions (>= n_draft) never match — a pad token that
@@ -1729,9 +1554,8 @@ def verify_draft_staged(params: llama.Params, cache: Cache,
                       axis=1)                          # [B]
     n_commit = jnp.where(active, n_match + 1, 0).astype(jnp.int32)
 
-    out = _flush_staged_rows(cache, table, pos0, batch_ix,
-                             sk, sv, sks, svs)
     out["length"] = cache["length"] + n_commit
-    out["last_token"] = jnp.where(active, toks[batch_ix, n_match],
-                                  cache["last_token"])
+    out["last_token"] = jnp.where(
+        active, toks[jnp.arange(toks.shape[0]), n_match],
+        cache["last_token"])
     return out, toks, n_commit

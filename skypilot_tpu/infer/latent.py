@@ -174,7 +174,7 @@ def _no_extras(qweights, lora):
 # ---------------------------------------------------------------------------
 
 def prefill_batch(params, tokens, true_lens, cfg: glm.GlmMoeConfig,
-                  constrain=None, qweights=None, lora=None, aid=None,
+                  qweights=None, lora=None, aid=None,
                   mesh=None, heads_axis=None) -> Tuple[Cache, jax.Array]:
     """Causal forward over a WAVE of right-padded prompts [W, S].
     Returns (``{"c_kv": [L, W, S, R], "k_pe": [L, W, S, rope]}``, logits
@@ -322,7 +322,7 @@ def _staged_steps(params, cache: Cache, cfg: glm.GlmMoeConfig, table, span,
 
 
 def decode_step(params, cache: Cache, cfg: glm.GlmMoeConfig,
-                constrain=None, qweights=None, table=None, span=None,
+                qweights=None, table=None, span=None,
                 lora=None, aid=None) -> Tuple[Cache, jax.Array]:
     """One token for every slot: (cache' with the pending row written,
     logits [slots, vocab]). The caller samples and commits

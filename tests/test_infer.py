@@ -281,6 +281,63 @@ def test_weights_int8_composes_with_kv_int8(cfg, params):
     assert all(0 <= t < cfg.vocab_size for t in out)
 
 
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["fp", "int8"])
+def test_wave_and_chunk_rows_land_alike(cfg, kv_int8):
+    """One row writer under every program: a prompt's rows written by
+    ``insert`` through a block table (scattered blocks, a partial last
+    one) and read back through ``_gather_kv_layer`` are, bit for bit,
+    the rows the contiguous ``insert`` stores, and the rows
+    ``prefill_chunk`` leaves for the same tokens through the same table
+    (same addresses, same scale layout; the values agree to the two
+    attentions' summation order)."""
+    import dataclasses
+    cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    params = llama.init_params(jax.random.key(0), cfg)
+    n, S, bl = 13, 16, 8
+    toks = np.zeros((S,), np.int32)
+    toks[:n] = np.random.default_rng(0).integers(1, cfg.vocab_size, n)
+    toks, i32 = jnp.asarray(toks), lambda v: jnp.asarray(v, jnp.int32)
+    tbl = np.full((2, 5), 6, np.int32)           # 6 blocks; 6 = sentinel
+    tbl[1, :4] = [4, 1, 5, 2]
+    table, slot = jnp.asarray(tbl), i32(1)
+    prefix, logits = kvcache.prefill(params, toks, i32(n), cfg)
+    first = i32(jnp.argmax(logits))
+    paged0 = kvcache.init_paged_cache(cfg, 2, 6, bl, kv_int8=kv_int8)
+    waved = kvcache.insert(paged0, prefix, slot, i32(n), first, table=table)
+    contig = kvcache.insert(kvcache.init_cache(cfg, 2, 4 * bl, kv_int8),
+                            prefix, slot, i32(n), first)
+    chunked, _, tok = kvcache.prefill_chunk(
+        params, paged0, toks, i32(0), i32(n), slot, i32(n),
+        jax.random.key(0), cfg, sampling.SamplingParams(), final=True,
+        table=table)
+    assert int(tok) == int(first)
+    assert int(chunked["length"][1]) == int(waved["length"][1]) == n
+
+    def rows(cache, tbl, layer):
+        """Slot 1's first n rows as read back: k, v [n, G, hd] (+ the
+        two scales [n, G])."""
+        k, v, ks, vs = kvcache._gather_kv_layer(cache, layer, tbl)
+        out = [np.asarray(k[1, :n]), np.asarray(v[1, :n])]
+        if ks is not None:
+            out += [np.asarray(ks[1, :, :n].T), np.asarray(vs[1, :, :n].T)]
+        return out
+
+    def values(r):
+        if not kv_int8:
+            return r
+        return [np.asarray(kvcache.dequantize_rows(r[i], r[i + 2]))
+                for i in (0, 1)]
+
+    for layer in range(cfg.n_layers):
+        through_table = rows(waved, table, layer)
+        for got, want in zip(through_table, rows(contig, None, layer)):
+            assert np.array_equal(got, want)
+        # (the chunk's attention dots run in bf16, the wave's in fp32)
+        for got, want in zip(values(rows(chunked, table, layer)),
+                             values(through_table)):
+            np.testing.assert_allclose(got, want, atol=0.08)
+
+
 @pytest.mark.parametrize("family", ["llama", "moe"])
 def test_staged_burst_cache_matches_oracle(family, cfg, params,
                                            moe_setup):

@@ -259,7 +259,7 @@ def test_layer_logits_close_and_untied_argmax_equal(params, cfg,
                 sk, sv, sks, svs, valid, stage_valid, batch_ix,
                 None, pos0, li == li and kernel)
             i = i + 1
-        return np.asarray(kvcache._decode_head(cfg, params, None, x))
+        return np.asarray(kvcache._head(cfg, params, None, x))
 
     lg = one_step_logits(False)
     lk = one_step_logits(True)

@@ -278,6 +278,14 @@ def test_spec_oracle_full_acceptance(params, cfg):
 
 # -- rollback ---------------------------------------------------------------
 
+def _paged_table():
+    # Slot 0 owns blocks 0..7 logically in order; slot 1 + the sentinel
+    # column stay unmapped (the engine's claim shape).
+    tbl = np.full((2, 9), 10, np.int32)
+    tbl[0, :8] = np.arange(8)
+    return jnp.asarray(tbl)
+
+
 def _seeded_cache(params, cfg, kv_int8, prompt, table=None):
     cache = (kvcache.init_cache(cfg, 2, 64, kv_int8=kv_int8)
              if table is None else
@@ -303,13 +311,7 @@ def test_rollback_leaves_kv_bit_equal(params, cfg, kv_int8, layout):
     moves."""
     prompt = [3, 1, 4, 1, 5, 9, 2, 6]
     K = 4
-    table = None
-    if layout == "paged":
-        # Slot 0 owns blocks 0..7 logically in order; slot 1 + the
-        # sentinel column stay unmapped (the engine's claim shape).
-        tbl = np.full((2, 9), 10, np.int32)
-        tbl[0, :8] = np.arange(8)
-        table = jnp.asarray(tbl)
+    table = _paged_table() if layout == "paged" else None
     cache = _seeded_cache(params, cfg, kv_int8, prompt, table=table)
     active = jnp.asarray(np.array([True, False]))
 
@@ -358,6 +360,61 @@ def test_rollback_leaves_kv_bit_equal(params, cfg, kv_int8, layout):
                 rows_b = gb.transpose(0, 2, 1, 3).reshape(
                     b.shape[0], b.shape[2], 64)[:, :, :n]
         assert np.array_equal(rows_a, rows_b), name
+
+
+def _same_cache(a, b):
+    for name in a:
+        assert np.array_equal(np.asarray(a[name]), np.asarray(b[name])), name
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["fp32", "int8"])
+@pytest.mark.parametrize("span", [None, 16], ids=["full", "span16"])
+def test_step_burst_and_verify_are_one_scaffold(params, cfg, kv_int8,
+                                                layout, span):
+    """The three decode programs are drivers of ONE staged scaffold, so
+    their equalities are exact, not up to summation order:
+
+    (a) ``decode_step``'s logits ARE the first step's logits of a
+        burst: a greedy burst of one emits their argmax and leaves the
+        same cache, bit for bit, and the first of four staged steps
+        computes the same logits (the three unused staged columns are
+        masked to exact-zero weight);
+    (b) ``verify_draft_staged`` fed the burst's own tokens as its draft
+        accepts all of them and commits k + 1 tokens equal to the
+        burst's, leaving the same cache, bit for bit.
+    """
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+    K = 3
+    table = _paged_table() if layout == "paged" else None
+    cache = _seeded_cache(params, cfg, kv_int8, prompt, table=table)
+    # Both slots live (slot 1 is empty: it attends to its own staged
+    # rows only), so every row either program writes is a fed-back one.
+    active = jnp.asarray(np.array([True, True]))
+    greedy = sampling.SamplingParams()
+    kw = dict(table=table, span=span)
+
+    # (a) a step is a burst of one.
+    stepped, logits = kvcache.decode_step(params, cache, cfg, **kw)
+    burst1, _, toks1 = kvcache.decode_burst_staged(
+        params, cache, jax.random.key(0), active, 1, cfg, greedy, **kw)
+    assert int(toks1[0, 0]) == int(np.argmax(np.asarray(logits[0])))
+    _same_cache(kvcache.commit_tokens(stepped, toks1[0], active), burst1)
+    _, _, first_of_four = kvcache._staged_steps(
+        params, cache, cfg, K + 1, None, cache["last_token"],
+        lambda last, x: last,
+        lambda lg, last, x: (sampling.argmax_tokens(lg), lg), **kw)
+    assert np.array_equal(np.asarray(first_of_four[0]), np.asarray(logits))
+
+    # (b) verify over the burst's own tokens is the burst.
+    burst, _, toks = kvcache.decode_burst_staged(
+        params, cache, jax.random.key(0), active, K + 1, cfg, greedy, **kw)
+    ver, toks_v, n_commit = kvcache.verify_draft_staged(
+        params, cache, toks[:K].T, jnp.full((2,), K, jnp.int32), active, K,
+        cfg, **kw)
+    assert n_commit.tolist() == [K + 1, K + 1]
+    assert np.array_equal(np.asarray(toks_v), np.asarray(toks.T))
+    _same_cache(ver, burst)
 
 
 def test_rejected_drafts_roll_back_engine_level(params, cfg):
