@@ -1518,6 +1518,18 @@ class InferenceEngine:
 
     # -- flight recorder + compile watch -----------------------------------
 
+    def _tiles(self, burst: str, n_live: int) -> int:
+        """Turns a layer of this decode program takes to read and attend
+        its live rows, ``kvcache.TILE`` slots a turn: host arithmetic
+        over what the dispatch already knows (the device counts the
+        same from its ``active`` mask). ``decode1`` has no mask and
+        visits every row; the paged kernel visits none by tiles."""
+        if burst == "decode1":
+            n_live = self.n_slots + 1
+        elif self.kv_kernel:
+            return 0
+        return -(-n_live // kvcache.TILE)
+
     def _record_flight(self, burst: str, begin_s: float, end_s: float,
                        program: Dict[str, Any], slots, reqs,
                        toks: int, stall: bool = False,
@@ -1558,6 +1570,8 @@ class InferenceEngine:
         if attn is not None:
             program["attn"] = attn
         extra: Dict[str, Any] = {}
+        if burst in ("decode", "verify", "decode1"):
+            extra["tiles"] = self._tiles(burst, len(slots))
         if stall:
             extra["stall"] = True
         if drafted:
@@ -3568,6 +3582,7 @@ class InferenceEngine:
         with timeline.phase(
                 "engine.decode.dispatch", seq=self._burst_seq,
                 k=K + 1, slots=len(slots), rows=self.n_slots + 1,
+                tiles=self._tiles("verify", len(slots)),
                 span=attn_span, promoted=promoted, why=why,
                 waiting=len(self.waiting)):
             self.cache, toks_dev, commit_dev = self._verify_fn(
@@ -3741,10 +3756,12 @@ class InferenceEngine:
         self.decode_programs.add(("burst", k, sarg))
         DECODE_ATTN_ROWS.observe(attn_span)
         # The program's k steps run at ``rows`` batch rows of which
-        # ``slots`` are live, ``promoted`` of them above their own rung.
+        # ``slots`` are live, ``promoted`` of them above their own rung;
+        # a layer reads and attends the live ones in ``tiles`` turns.
         with timeline.phase(
                 "engine.decode.dispatch", seq=self._burst_seq, k=k,
                 slots=len(slots), rows=self.n_slots + 1,
+                tiles=self._tiles("decode", len(slots)),
                 span=attn_span, promoted=promoted, why=why,
                 waiting=len(self.waiting)):
             self.cache, self.rng, toks = self._decode_burst_fn(
@@ -3856,6 +3873,7 @@ class InferenceEngine:
         with timeline.phase(
                 "engine.decode.dispatch", seq=self._burst_seq, k=1,
                 slots=len(slots), rows=self.n_slots + 1,
+                tiles=self._tiles("decode1", len(slots)),
                 span=attn_span, promoted=promoted, why="step",
                 waiting=len(self.waiting)):
             self.cache, self.rng, toks = self._decode_fn(

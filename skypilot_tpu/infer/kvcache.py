@@ -15,7 +15,10 @@ TPU-first design notes
   stacked-layer ``lax.scan`` with the cache read-only inside it, ONE
   scatter per cache tensor after the loop to append every layer's K/V
   rows (:func:`_staged_steps`). This is the JetStream-style generate
-  step — MXU-batched across requests.
+  step — MXU-batched across requests. What costs time row for row —
+  reading resident rows and attending them — is done for the LIVE
+  slots only, a fixed tile of slots a turn (:func:`_visit_tiles`): the
+  shapes stay static, the trip count is the data.
 * The decoder layer is written ONCE (:func:`_layer_qkv`, the program's
   own attention, :func:`_layer_out_ffn`), with one head (:func:`_head`)
   and one row writer (:func:`_write_rows`); a program differs from the
@@ -544,95 +547,127 @@ def _phys(cache: Cache, table, slots, idx):
     return table[slots, idx // bl], idx % bl
 
 
+# Slots one turn of a staged step's attention reads and attends: a
+# decode program visits its LIVE slots, this many a turn, and the
+# number of turns is data (:func:`_live_tiles`, :func:`_visit_tiles`).
+# Reading resident rows and attending them cost time row for row, and a
+# serving round keeps a few of its slots live. One value for both cache
+# families (``infer/latent.py`` visits by the same two helpers), timed
+# on the chip at 4 / 8 / 16 (``PERF.md`` section 6): a turn's fixed cost
+# is about one row's (GQA rows at span 640) to three rows' (latent rows
+# at span 4352), so 4 is ahead of 8 wherever 1-4 or 9-12 slots are live
+# and at most a turn's cost behind elsewhere.
+TILE = 4
+
+
+def _live_tiles(live, pos0, table):
+    """Once a program: what :func:`_visit_tiles` walks — how many tiles
+    of :data:`TILE` slots hold the live rows, the slots in visiting
+    order, and per slot in that order its resident length and its table
+    row (``None`` when contiguous), so a turn SLICES what a gather by
+    slot id would fetch again every layer. ``live`` [B] bool — the rows
+    whose tokens anyone keeps; ``None``: every row. The order is
+    live-first (stable), padded to whole tiles with the LAST slot — an
+    engine's hidden spare, whose table row is all sentinel: its gathered
+    rows are garbage its length never admits, and the pad rows only
+    rewrite that slot's own attention output. ``n_tiles`` is an int32
+    scalar, or a Python int without ``live``."""
+    n_slots = pos0.shape[0]
+    pad = -n_slots % TILE
+    if live is None:
+        order = jnp.arange(n_slots, dtype=jnp.int32)
+        n_tiles = (n_slots + pad) // TILE
+    else:
+        order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+        n_tiles = (jnp.sum(live, dtype=jnp.int32) + TILE - 1) // TILE
+    order = jnp.concatenate(
+        [order, jnp.full((pad,), n_slots - 1, jnp.int32)])
+    return (n_tiles, order, pos0[order],
+            None if table is None else table[order])
+
+
+def _visit_tiles(tiles, n_slots: int, attend, row_shape):
+    """A layer's attention output ``[B, *row_shape]`` float32 by visiting
+    the live tiles: a turn takes :data:`TILE` slots — ``attend(ids,
+    pos0, table_rows)``, each of the three for those slots, gives their
+    rows ``[TILE, *row_shape]`` — and they land by slot id. Rows of no
+    visited tile keep zeros (dead: they flow through the rest of the
+    layer and their tokens are discarded). The trip count is the data —
+    there is no second form for full or for empty programs."""
+    n_tiles, *per_slot = tiles
+
+    def turn(t, out):
+        ids, pos, rows = (
+            a if a is None else lax.dynamic_slice_in_dim(a, t * TILE, TILE)
+            for a in per_slot)
+        return out.at[ids].set(attend(ids, pos, rows))
+
+    return lax.fori_loop(
+        0, n_tiles, turn, jnp.zeros((n_slots,) + row_shape, jnp.float32))
+
+
+def _resident_mask(pos0, n_rows: int):
+    """[B, n_rows] bool: the rows resident when the program began
+    (``< pos0`` [B]) — a constant of the program."""
+    return jnp.arange(n_rows)[None, :] < pos0[:, None]
+
+
 @jax.named_scope("kv_gather")
-def _gather_kv_layer(cache: Cache, i, table, span=None):
-    """Layer ``i``'s K/V (+ scales when int8) arranged per slot:
-    k/v [B, M, G, hd], scales [B, G, M]. Contiguous reads the
-    slot-major layout directly; paged gathers each slot's blocks in
-    logical order — identical row ordering, so the attention sums
-    match the contiguous layout bit-for-bit.
+def _gather_kv_layer(cache: Cache, i, table_rows, ids, span=None):
+    """Layer ``i``'s K/V (+ scales when int8) of the slots ``ids`` [T]:
+    k/v [T, M, G, hd], scales [T, G, M]. Paged (``table_rows`` [T, nb +
+    1]: those slots' rows of the block table): each slot's blocks in
+    logical order; contiguous (``None``): the same read with slots as
+    blocks (one of max_len rows each) — identical row ordering, so the
+    attention sums match between the layouts bit for bit.
 
-    ``span`` (static int): gather only the first ``span`` logical
-    rows — the span-bucketed read. The rows kept are a PREFIX of the
-    full view in the same order, and every row the caller's validity
-    mask admits lies below the span by construction (the engine picks
-    the bucket covering the longest active slot), so the masked score
-    set — and the attention output — is bit-identical to the full
-    gather while the materialized K/V transient (the decode-bandwidth
-    cost) shrinks from max_len to span rows per slot. Paged: the
-    gather covers ceil(span / block_len) whole blocks of the table
-    prefix, then slices to the span — sub-block spans still pay one
-    block of gather but only span rows of attention."""
-    ck = lax.dynamic_index_in_dim(cache["k"], i, 0, keepdims=False)
-    cv = lax.dynamic_index_in_dim(cache["v"], i, 0, keepdims=False)
-    cks = cvs = None
-    if "k_scale" in cache:
-        cks = lax.dynamic_index_in_dim(cache["k_scale"], i, 0,
-                                       keepdims=False)
-        cvs = lax.dynamic_index_in_dim(cache["v_scale"], i, 0,
-                                       keepdims=False)
-    if table is not None:
-        bl = ck.shape[1]
-        nb = table.shape[1] - 1              # sentinel column: no rows
+    The blocks are gathered STRAIGHT out of the pool seen as ``[L *
+    blocks, block_len, ...]`` at ``i * blocks + id``. Slicing layer
+    ``i`` out first made the compiler copy the layer's whole pool (K
+    and V, 43 MB each at the 7B serving shapes) before the gather,
+    every layer of every step. (A sentinel id gathers the next layer's
+    first block, or clamps: garbage the caller's mask never admits.)
+
+    ``span`` (static int): only the first ``span`` logical rows — the
+    span-bucketed read. The rows kept are a PREFIX of the full view in
+    the same order, and every row the caller's validity mask admits
+    lies below the span by construction (the engine picks the bucket
+    covering the longest active slot), so the masked score set — and
+    the attention output — is bit-identical to the full read while the
+    materialized K/V transient shrinks from max_len to span rows per
+    slot. Paged: ceil(span / block_len) whole blocks of the table
+    prefix, then cut to the span (a sub-block span reads its rows of
+    one block)."""
+    n_units, rows = cache["k"].shape[1:3]
+    if table_rows is None:
+        units = ids[:, None]                         # [T, 1]
+    else:
+        nb = table_rows.shape[1] - 1         # sentinel column: no rows
         if span is not None:
-            nb = -(-span // bl)              # block-table prefix
-        tbl = table[:, :nb]
-        B = tbl.shape[0]
-        G = ck.shape[2]
-        ck = ck[tbl].reshape(B, nb * bl, *ck.shape[2:])
-        cv = cv[tbl].reshape(B, nb * bl, *cv.shape[2:])
-        if cks is not None:
-            cks = cks[tbl].transpose(0, 2, 1, 3).reshape(B, G, nb * bl)
-            cvs = cvs[tbl].transpose(0, 2, 1, 3).reshape(B, G, nb * bl)
+            nb = -(-span // rows)            # block-table prefix
+        units = table_rows[:, :nb]                   # [T, nb]
     if span is not None:
-        ck = ck[:, :span]
-        cv = cv[:, :span]
-        if cks is not None:
-            cks = cks[..., :span]
-            cvs = cvs[..., :span]
-    return ck, cv, cks, cvs
+        rows = min(rows, span)
+    T, nb = units.shape
+    at = i * n_units + units
+    M = nb * rows if span is None else span
 
+    def flat(name):
+        pool = cache[name]
+        return pool.reshape((-1,) + pool.shape[2:])
 
-@jax.named_scope("kv_gather")
-def _gather_slot_kv_layer(cache: Cache, i, slot, table, span=None):
-    """One slot's rows for layer ``i``: k/v [M, G, hd], scales [G, M]
-    (the prefill_chunk read path). ``span``: first ``span`` logical
-    rows only — same prefix semantics as :func:`_gather_kv_layer`."""
-    ck = lax.dynamic_index_in_dim(cache["k"], i, 0, keepdims=False)
-    cv = lax.dynamic_index_in_dim(cache["v"], i, 0, keepdims=False)
-    cks = cvs = None
+    def read_rows(name):                 # pool [L, units, rows, G, hd]
+        got = flat(name)[at, :rows]
+        return got.reshape(T, nb * rows, *got.shape[3:])[:, :M]
+
+    def read_scales(name):               # pool [L, units, G, rows]
+        got = flat(name)[at, :, :rows].transpose(0, 2, 1, 3)
+        return got.reshape(T, got.shape[1], nb * rows)[..., :M]
+
     if "k_scale" in cache:
-        cks = lax.dynamic_index_in_dim(cache["k_scale"], i, 0,
-                                       keepdims=False)
-        cvs = lax.dynamic_index_in_dim(cache["v_scale"], i, 0,
-                                       keepdims=False)
-    if table is None:
-        ck = lax.dynamic_index_in_dim(ck, slot, 0, keepdims=False)
-        cv = lax.dynamic_index_in_dim(cv, slot, 0, keepdims=False)
-        if cks is not None:
-            cks = lax.dynamic_index_in_dim(cks, slot, 0, keepdims=False)
-            cvs = lax.dynamic_index_in_dim(cvs, slot, 0, keepdims=False)
-        if span is not None:
-            ck, cv = ck[:span], cv[:span]
-            if cks is not None:
-                cks, cvs = cks[:, :span], cvs[:, :span]
-        return ck, cv, cks, cvs
-    bl = ck.shape[1]
-    nb = table.shape[1] - 1                  # sentinel column: no rows
-    if span is not None:
-        nb = -(-span // bl)
-    tblk = table[slot, :nb]                  # [nb]
-    G = ck.shape[2]
-    ck = ck[tblk].reshape(nb * bl, *ck.shape[2:])
-    cv = cv[tblk].reshape(nb * bl, *cv.shape[2:])
-    if cks is not None:
-        cks = cks[tblk].transpose(1, 0, 2).reshape(G, nb * bl)
-        cvs = cvs[tblk].transpose(1, 0, 2).reshape(G, nb * bl)
-    if span is not None:
-        ck, cv = ck[:span], cv[:span]
-        if cks is not None:
-            cks, cvs = cks[:, :span], cvs[:, :span]
-    return ck, cv, cks, cvs
+        return (read_rows("k"), read_rows("v"),
+                read_scales("k_scale"), read_scales("v_scale"))
+    return read_rows("k"), read_rows("v"), None, None
 
 
 @jax.named_scope("kv_gather")
@@ -1097,6 +1132,8 @@ def prefill_chunk(params: llama.Params, cache: Cache,
     # scores; padding ROWS compute garbage that lands past the prompt's
     # true length, where decode's validity mask never reads.
     intra_mask = (j[None, :] <= j[:, None]) & (j[None, :] < n_valid)
+    slot_table = (None if table is None
+                  else lax.dynamic_slice_in_dim(table, slot, 1, 0))
 
     def body(carry, layer_q):
         x, i = carry
@@ -1125,8 +1162,8 @@ def prefill_chunk(params: llama.Params, cache: Cache,
                 # (rows below this chunk are the resident prefix).
                 q_k = qh.transpose(1, 0, 2, 3).reshape(1, G, C * rep, hd)
                 acc, m, l = _paged_attn_stats(
-                    cache, i, lax.dynamic_slice_in_dim(table, slot, 1, 0),
-                    q_k, jnp.reshape(start, (1,)), span)
+                    cache, i, slot_table, q_k, jnp.reshape(start, (1,)),
+                    span)
                 acc = acc.reshape(G, C, rep, hd).transpose(1, 0, 2, 3)
                 m = m.reshape(G, C, rep).transpose(1, 0, 2)
                 l = l.reshape(G, C, rep).transpose(1, 0, 2)
@@ -1137,8 +1174,11 @@ def prefill_chunk(params: llama.Params, cache: Cache,
                     preferred_element_type=jnp.float32)
                 o = o / l_tot[..., None]
             else:
-                ck, cv, cks, cvs = _gather_slot_kv_layer(cache, i, slot,
-                                                         table, span)
+                # One slot's rows: the decode read at T = 1.
+                ck, cv, cks, cvs = (
+                    t if t is None else t[0] for t in _gather_kv_layer(
+                        cache, i, slot_table, jnp.reshape(slot, (1,)),
+                        span))
                 sm = jnp.einsum("cgrk,mgk->cgrm", qh,
                                 ck.astype(jnp.bfloat16),
                                 preferred_element_type=jnp.float32) * scale
@@ -1199,28 +1239,32 @@ def commit_tokens(cache: Cache, tokens: jax.Array,
 
 
 def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
-                       i, s, sk, sv, sks, svs, valid_cache,
-                       stage_valid, batch_ix, span=None, pos0=None,
-                       kv_kernel=False, llayer=None, aid=None):
+                       i, s, sk, sv, sks, svs, pos0, stage_valid,
+                       batch_ix, tiles, span=None, kv_kernel=False,
+                       llayer=None, aid=None):
     """One decoder layer of a staged step: the current step's K/V rows
-    land in the staging buffers, attention runs as big-cache dot (rows
-    masked by ``valid_cache``) ++ staged-columns dot (columns masked by
+    land in the staging buffers, attention runs as big-cache dot (the
+    resident rows ``< pos0``) ++ staged-columns dot (columns masked by
     ``stage_valid``) under ONE softmax, and the big cache stays a pure
     invariant. The only decode attention in the module: the step, burst
     and verify programs all run THIS math (:func:`_staged_steps`), so
     the speculative parity guarantee and step == burst-of-one hold by
     construction — an edit here can never drift one without the others.
+
+    The read of resident rows and the attention visit the LIVE slots,
+    :data:`TILE` of them a turn (``tiles``: :func:`_live_tiles`): a
+    turn gathers its slots' blocks, attends them exactly as the whole
+    batch was attended — the same dots at ``B = TILE`` — and writes the
+    output rows back by slot id; dead rows' attention output is zero.
     ``span`` (static) bounds the big-cache read to the first ``span``
-    logical rows; the caller's ``valid_cache`` mask must already be
-    span-shaped.
+    logical rows.
 
     ``kv_kernel`` (static): run the big-cache block through the Pallas
     paged-attention kernel instead of the gather — the kernel walks
-    the block table per (slot, kv-head) and the logical-view transient
-    never materializes. Requires a ``table`` (the kernel is
-    block-table-native; contiguous callers keep the gather) and
-    ``pos0`` (the burst-start lengths the kernel masks by — the same
-    rule ``valid_cache`` encodes). The staged-columns block is
+    the block table per (slot, kv-head), every slot, and the
+    logical-view transient never materializes. Requires a ``table``
+    (the kernel is block-table-native; contiguous callers keep the
+    gather); it masks by ``pos0`` too. The staged-columns block is
     UNCHANGED either way; the two blocks merge via the online-softmax
     combine (:func:`_merge_attn_parts`) — same score set, online
     summation order, greedy parity vs the gather oracle.
@@ -1251,6 +1295,10 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
             sv = sv.at[i, batch_ix, s].set(v[:, 0].astype(kdt))
         lk = lax.dynamic_index_in_dim(sk, i, 0, False)
         lv = lax.dynamic_index_in_dim(sv, i, 0, False)
+        lks = lvs = None
+        if quant:
+            lks = lax.dynamic_index_in_dim(sks, i, 0, False)
+            lvs = lax.dynamic_index_in_dim(svs, i, 0, False)
         # The attention dots run in bf16 with fp32 ACCUMULATION. The
         # int8 cache converts to bf16 EXACTLY (integers <= 127 carry no
         # rounding in an 8-bit mantissa) and each bf16xbf16 product is
@@ -1263,16 +1311,43 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
         # buffer, so its score uses the SAME quantized values a later
         # step's cache read will see.
         qh = q[:, 0].reshape(B, G, rep, hd).astype(jnp.bfloat16)
-        ss = jnp.einsum("bgrk,bjgk->bgrj", qh,
-                        lk.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32) * scale
-        lvs = None
-        if quant:
-            lks = lax.dynamic_index_in_dim(sks, i, 0, False)
-            lvs = lax.dynamic_index_in_dim(svs, i, 0, False)
-            ss = ss * lks.transpose(0, 2, 1)[:, :, None, :]
-        ss = jnp.where(stage_valid[:, None, None, :], ss, neg)
+
+        def staged_scores(qh, lk, lks):
+            ss = jnp.einsum("bgrk,bjgk->bgrj", qh, lk.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32) * scale
+            if quant:
+                ss = ss * lks.transpose(0, 2, 1)[:, :, None, :]
+            return jnp.where(stage_valid[:, None, None, :], ss, neg)
+
+        def attend(ids, pos, table_rows):
+            """The gather path for the slots ``ids`` [T]: o [T, G, rep,
+            hd]."""
+            qt, lvt = qh[ids], lv[ids]
+            ss = staged_scores(qt, lk[ids], lks[ids] if quant else None)
+            ck, cv, cks, cvs = _gather_kv_layer(cache, i, table_rows, ids,
+                                                span)
+            sm = jnp.einsum("bgrk,bmgk->bgrm", qt,
+                            ck.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32) * scale
+            if quant:
+                sm = sm * cks[:, :, None, :]
+            sm = jnp.where(_resident_mask(pos, M)[:, None, None, :],
+                           sm, neg)
+            w = jax.nn.softmax(jnp.concatenate([sm, ss], axis=-1), axis=-1)
+            wm, ws = w[..., :M], w[..., M:]
+            if quant:
+                wm = wm * cvs[:, :, None, :]
+                ws = ws * lvs[ids].transpose(0, 2, 1)[:, :, None, :]
+            o = jnp.einsum("bgrm,bmgk->bgrk", wm.astype(jnp.bfloat16),
+                           cv.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
+            return o + jnp.einsum("bgrj,bjgk->bgrk",
+                                  ws.astype(jnp.bfloat16),
+                                  lvt.astype(jnp.bfloat16),
+                                  preferred_element_type=jnp.float32)
+
         if kv_kernel and table is not None:
+            ss = staged_scores(qh, lk, lks)
             acc, m, l = _paged_attn_stats(cache, i, table, qh, pos0, span)
             alpha, w_s, l_tot = _merge_attn_parts(acc, m, l, ss)
             if quant:
@@ -1283,25 +1358,7 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
                 preferred_element_type=jnp.float32)
             o = o / l_tot[..., None]
         else:
-            ck, cv, cks, cvs = _gather_kv_layer(cache, i, table, span)
-            sm = jnp.einsum("bgrk,bmgk->bgrm", qh,
-                            ck.astype(jnp.bfloat16),
-                            preferred_element_type=jnp.float32) * scale
-            if quant:
-                sm = sm * cks[:, :, None, :]
-            sm = jnp.where(valid_cache[:, None, None, :], sm, neg)
-            w = jax.nn.softmax(jnp.concatenate([sm, ss], axis=-1), axis=-1)
-            wm, ws = w[..., :M], w[..., M:]
-            if quant:
-                wm = wm * cvs[:, :, None, :]
-                ws = ws * lvs.transpose(0, 2, 1)[:, :, None, :]
-            o = jnp.einsum("bgrm,bmgk->bgrk", wm.astype(jnp.bfloat16),
-                           cv.astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32)
-            o = o + jnp.einsum("bgrj,bjgk->bgrk",
-                               ws.astype(jnp.bfloat16),
-                               lv.astype(jnp.bfloat16),
-                               preferred_element_type=jnp.float32)
+            o = _visit_tiles(tiles, B, attend, (G, rep, hd))
     x = _layer_out_ffn(cfg, layer, qlayer, x, o, llayer, aid)
     return x, sk, sv, sks, svs
 
@@ -1309,7 +1366,7 @@ def _staged_attn_layer(cfg, cache, table, layer, qlayer, x, cos, sin,
 def _staged_steps(params: llama.Params, cache: Cache,
                   cfg: llama.LlamaConfig, W: int, xs, state, token, emit,
                   qweights=None, table=None, span=None, kv_kernel=False,
-                  lora=None, aid=None):
+                  lora=None, aid=None, live=None):
     """``W`` decode steps for every slot with the big cache a read-only
     scan INVARIANT — the one scaffold under :func:`decode_step` (W = 1),
     :func:`decode_burst_staged` and :func:`verify_draft_staged`
@@ -1338,11 +1395,13 @@ def _staged_steps(params: llama.Params, cache: Cache,
     block table; ``span`` / ``kv_kernel`` as :func:`_staged_attn_layer`
     (the flush scatters through the FULL table, so writes are
     untouched by a span); ``lora``/``aid``: the adapter pool + per-slot
-    ids. Returns (cache with the W rows flushed — length / last_token
-    untouched, the driver's to stamp —, final state, emitted [W, ...]).
+    ids; ``live`` [B] bool: the rows whose tokens the driver keeps —
+    resident rows are read and attended for THEM, a tile of slots a turn
+    (:func:`_live_tiles`; absent: every row). Returns (cache with the
+    W rows flushed — length / last_token untouched, the driver's to
+    stamp —, final state, emitted [W, ...]).
     """
     B = cache["length"].shape[0]
-    M = span if span is not None else _logical_rows(cache, table)
     G, hd = cfg.n_kv_heads, cfg.head_dim
     L = cfg.n_layers
     quant = "k_scale" in cache
@@ -1353,8 +1412,8 @@ def _staged_steps(params: llama.Params, cache: Cache,
     # ``length`` counts rows already in the cache (prompt + committed
     # tokens); step s's row is written at index length + s.
     pos0 = cache["length"]
-    valid_cache = jnp.arange(M)[None, :] < pos0[:, None]   # [B, M]
     batch_ix = jnp.arange(B)
+    tiles = _live_tiles(live, pos0, table)
 
     stage_k = jnp.zeros((L, B, W, G, hd), kdt)
     stage_v = jnp.zeros((L, B, W, G, hd), kdt)
@@ -1378,8 +1437,8 @@ def _staged_steps(params: llama.Params, cache: Cache,
                                                      lora is not None)
                 x, sk, sv, sks, svs = _staged_attn_layer(
                     cfg, cache, table, layer, qlayer, x, cos, sin, i, s,
-                    sk, sv, sks, svs, valid_cache, stage_valid, batch_ix,
-                    span, pos0, kv_kernel, llayer, aid)
+                    sk, sv, sks, svs, pos0, stage_valid, batch_ix, tiles,
+                    span, kv_kernel, llayer, aid)
                 return (x, i + 1, sk, sv, sks, svs), None
 
             xs_l = _scan_xs(params, qweights, lora)
@@ -1462,7 +1521,7 @@ def decode_burst_staged(params: llama.Params, cache: Cache,
         params, cache, cfg, k, keys, cache["last_token"],
         lambda last, key: last, emit,
         qweights=qweights, table=table, span=span, kv_kernel=kv_kernel,
-        lora=lora, aid=aid)
+        lora=lora, aid=aid, live=active)
     out["length"] = cache["length"] + k * active.astype(jnp.int32)
     out["last_token"] = last
     return out, rng, toks
@@ -1541,7 +1600,7 @@ def verify_draft_staged(params: llama.Params, cache: Cache,
         params, cache, cfg, k + 1, window.T, (),
         lambda state, tok: tok, emit,
         qweights=qweights, table=table, span=span, kv_kernel=kv_kernel,
-        lora=lora, aid=aid)
+        lora=lora, aid=aid, live=active)
     toks = toks.T                                     # [B, k + 1]
 
     # Accepted prefix: out[s] must reproduce draft position s, and

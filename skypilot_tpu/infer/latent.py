@@ -268,7 +268,9 @@ def _staged_steps(params, cache: Cache, cfg: glm.GlmMoeConfig, table, span,
     tensor flushes all ``k`` rows afterwards. ``next_token(logits, s,
     last) -> (token fed to step s + 1, what the step emits)``. ``live``
     [B] bool: the rows whose tokens anyone keeps — the expert layers
-    read only the experts THEY chose (absent: every row counts). Returns
+    read only the experts THEY chose, and resident rows are gathered
+    and attended for THEM alone, ``kvcache.TILE`` slots a turn
+    (``kvcache._live_tiles``; absent: every row counts). Returns
     (cache with the rows flushed — bookkeeping untouched —, last token
     [B], emitted [k, ...], routed experts read [k]: a step's sum over
     its expert layers)."""
@@ -278,8 +280,8 @@ def _staged_steps(params, cache: Cache, cfg: glm.GlmMoeConfig, table, span,
     L = cfg.n_layers
     dt = cache["c_kv"].dtype
     pos0 = cache["length"]
-    resident = (jnp.arange(M)[None, :] < pos0[:, None])[:, None, :]
     batch_ix = jnp.arange(B)
+    tiles = kvcache._live_tiles(live, pos0, table)
     live = None if live is None else live[:, None]
 
     def step(carry, s):
@@ -296,13 +298,19 @@ def _staged_steps(params, cache: Cache, cfg: glm.GlmMoeConfig, table, span,
                 with jax.named_scope("attn_core"):
                     sc = sc.at[i, batch_ix, s].set(c_kv[:, 0].astype(dt))
                     sp_ = sp_.at[i, batch_ix, s].set(k_pe[:, 0].astype(dt))
-                    rc, rp = _gather_rows(cache, i, table, span)
-                    o = glm.latent_attention(
-                        cfg, layer["wkv_b"], q_nope, q_pe,
-                        [(rc, rp, resident),
-                         (lax.dynamic_index_in_dim(sc, i, 0, False),
-                          lax.dynamic_index_in_dim(sp_, i, 0, False),
-                          staged)], True)
+                    lc = lax.dynamic_index_in_dim(sc, i, 0, False)
+                    lp = lax.dynamic_index_in_dim(sp_, i, 0, False)
+
+                    def attend(ids, pos, table_rows):
+                        rc, rp = _gather_rows(cache, i, table_rows, span)
+                        resident = kvcache._resident_mask(pos, M)
+                        return glm.latent_attention(
+                            cfg, layer["wkv_b"], q_nope[ids], q_pe[ids],
+                            [(rc, rp, resident[:, None, :]),
+                             (lc[ids], lp[ids], staged)], True)
+
+                    o = kvcache._visit_tiles(
+                        tiles, B, attend, (1, cfg.n_heads, cfg.v_head_dim))
                 x, read = glm.out_ffn(cfg, layer, x, o, moe, live)
                 return (x, sc, sp_), read
 

@@ -16,6 +16,7 @@ never gets a passing last line.
 
 import dataclasses
 import json
+import math
 import os
 import re
 import shutil
@@ -175,6 +176,51 @@ def test_decode_burst_compiles_for_v5e(one_chip, engine_8b_2layers,
     assert n == (1 if kernel else 0)
 
 
+def _largest_under(text, *scopes):
+    """Bytes of the largest array an op under one of ``scopes`` makes
+    (a tuple's first element), read from the compiled text."""
+    sizes = {"s8": 1, "pred": 1, "bf16": 2, "f32": 4, "s32": 4, "u32": 4}
+    found = re.findall(
+        r"= \(?(\w+)\[([\d,]+)\].*op_name=\"[^\"]*/(?:%s)/"
+        % "|".join(scopes), text)
+    return max(sizes[dt] * math.prod(int(d) for d in dims.split(","))
+               for dt, dims in found)
+
+
+@pytest.mark.parametrize("span", [640, None], ids=["span640", "full"])
+def test_decode_burst_reads_live_tiles_out_of_the_flat_pool(
+        one_chip, engine_8b_2layers, span):
+    """A staged step gathers its live slots' blocks, ``kvcache.TILE``
+    slots a turn, straight out of the pool seen as ``[L * blocks, ...]``:
+    the compiled burst holds no copy of a layer's whole K or V pool
+    (``s8[165,256,8,128]``, 43 MB each, sliced out before the gather every
+    layer of every step until PR 31), no gather of all 33 rows — the
+    gather's leading dim is the tile —, the largest array the read and
+    the attention make is the tile's view of K or V (a few MB; 43 MB
+    with the slices and the 33-row view — the program's WHOLE
+    temporaries say nothing here: with two layers the compiler spends
+    the memory it got back on keeping an FFN weight stack near), and
+    the turns are one more loop inside the layer scan."""
+    e = engine_8b_2layers
+    params, qw, cache, rng, table, S = _engine_args(e, one_chip)
+    compiled = e._decode_burst_fn.__wrapped__.lower(
+        params, cache, rng, S((e.n_slots + 1,), jnp.bool_), table, k=4,
+        qweights=qw, span=span, kernel=False).compile()
+    text = compiled.as_text()
+    rows = e.n_slots + 1
+    layer_pool = ",".join(str(n) for n in e.cache["k"].shape[1:])
+    assert layer_pool == "165,256,8,128"
+    assert not re.search(
+        rf"= s8\[{layer_pool}\]\S* (copy|dynamic-slice|fusion)\(", text), \
+        "a layer of the K/V pool is sliced out or copied"
+    assert not re.search(rf"= s8\[{rows},\d+,256,8,128\]\S* gather\(", text)
+    assert len(re.findall(
+        rf"= s8\[{kvcache.TILE},\d+,256,8,128\]\S* gather\(", text)) == 2
+    view = kvcache.TILE * -(-(span or 1280) // 256) * 256 * 8 * 128
+    assert _largest_under(text, "kv_gather", "attn_core") == view
+    assert text.count(" while(") == 3       # steps, layers, live tiles
+
+
 def test_prefill_chunk_compiles_for_v5e(one_chip, engine_8b_2layers):
     e = engine_8b_2layers
     params, qw, cache, rng, table, S = _engine_args(e, one_chip)
@@ -207,14 +253,15 @@ def test_top_bucket_prefill_takes_flash_at_1280(one_chip,
 def latent_engine_2layers():
     """The latent-cache family at every published GLM-4.7-Flash width,
     depth cut to one dense + one expert layer (weights are shapes only;
-    the pool is real: 8 slots of 8704 rows, ~0.2 GB of host memory)."""
+    the pool is real: 16 slots — several tiles — of 8704 rows, ~0.3 GB
+    of host memory)."""
     from skypilot_tpu.models import glm_moe
     cfg = dataclasses.replace(glm_moe.CONFIGS["glm-4.7-flash"], n_layers=2)
     params = jax.eval_shape(lambda: jax.tree.map(
         lambda a: a.astype(cfg.dtype),
         glm_moe.init_params(jax.random.key(0), cfg)))
     return eng.InferenceEngine(
-        params, cfg, n_slots=7, max_len=8704,
+        params, cfg, n_slots=15, max_len=8704,
         prompt_buckets=(128, 512, 8704), max_wave=4, pad_waves=True,
         prefix_pool=8, spec_k=0)
 
@@ -283,6 +330,27 @@ def test_latent_decode_reads_the_expert_stack_in_place(
         assert not re.search(
             rf"= bf16\[64,{rows},{cols}\]\S* (copy|dynamic-slice)\(", text), \
             "a layer's slice of an expert tensor is materialised"
+
+
+def test_latent_decode_gathers_a_tile_of_slots(one_chip,
+                                               latent_engine_2layers):
+    """The latent burst reads its live slots' latent rows a tile a turn:
+    no gather of every row's blocks (``bf16[16,17,256,512]`` here,
+    ``[33,17,256,512]`` in the cell), the tile's instead, in both layer
+    groups — each with its own loop of turns."""
+    e = latent_engine_2layers
+    params, _, cache, rng, table, S = _engine_args(e, one_chip)
+    text = e._decode_burst_fn.__wrapped__.lower(
+        params, cache, rng, S((e.n_slots + 1,), jnp.bool_), table,
+        k=4, qweights=None, span=4352, kernel=False).compile().as_text()
+    rows = e.n_slots + 1
+    assert rows > kvcache.TILE
+    assert not re.search(rf"= bf16\[{rows},\d+,256,512\]\S* gather\(", text)
+    assert len(re.findall(
+        rf"= bf16\[{kvcache.TILE},17,256,512\]\S* gather\(", text)) == 2
+    # steps; dense layers, expert layers, each with its tile turns; the
+    # expert visit.
+    assert text.count(" while(") >= 5
 
 
 # ---------------------------------------------------------------------------

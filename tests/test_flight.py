@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from skypilot_tpu.infer import engine as eng
+from skypilot_tpu.infer import kvcache
 from skypilot_tpu.models import llama
 from skypilot_tpu.observability import flight as fl
 from skypilot_tpu.observability import metrics as metrics_lib
@@ -223,6 +224,17 @@ def test_every_burst_has_a_record_with_matching_identity(flown_engine):
     eng_dv = {(k, s) for kind, k, s in e.decode_programs
               if kind in ("burst", "verify")}
     assert rec_dv == eng_dv
+    # Every decode-side record says how many turns of live tiles a
+    # layer took: host arithmetic over its slots.
+    for r in window:
+        if r["burst"] in ("decode", "verify"):
+            assert r["tiles"] == e._tiles(r["burst"], len(r["slots"])) \
+                == -(-len(r["slots"]) // kvcache.TILE)
+        else:
+            assert "tiles" not in r or r["burst"] == "decode1"
+    assert e._tiles("decode", 0) == 0
+    assert e._tiles("verify", 2 * kvcache.TILE + 1) == 3
+    assert e._tiles("decode1", 1) == -(-(e.n_slots + 1) // kvcache.TILE)
     # Layout stamped on every record; host timing sane.
     assert all(r["program"]["layout"] == "paged" for r in window)
     assert all(r["dur_s"] >= 0 and r["ts_s"] > 0 for r in window)

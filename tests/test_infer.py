@@ -316,7 +316,8 @@ def test_wave_and_chunk_rows_land_alike(cfg, kv_int8):
     def rows(cache, tbl, layer):
         """Slot 1's first n rows as read back: k, v [n, G, hd] (+ the
         two scales [n, G])."""
-        k, v, ks, vs = kvcache._gather_kv_layer(cache, layer, tbl)
+        k, v, ks, vs = kvcache._gather_kv_layer(cache, layer, tbl,
+                                                jnp.arange(2))
         out = [np.asarray(k[1, :n]), np.asarray(v[1, :n])]
         if ks is not None:
             out += [np.asarray(ks[1, :, :n].T), np.asarray(vs[1, :, :n].T)]

@@ -14,6 +14,8 @@ from skypilot_tpu.utils import timeline
 
 def test_timeline_disabled_is_noop(tmp_path, monkeypatch):
     monkeypatch.delenv(timeline.ENV_VAR, raising=False)
+    # (An earlier test file on this worker may have traced on purpose.)
+    timeline._events.clear()
 
     @timeline.event
     def f():

@@ -239,8 +239,8 @@ def test_layer_logits_close_and_untied_argmax_equal(params, cfg,
     def one_step_logits(kernel):
         c = {k: jnp.copy(v) for k, v in cache.items()}
         pos0 = c["length"]
-        valid = jnp.arange(64)[None, :] < pos0[:, None]
         batch_ix = jnp.arange(B)
+        tiles = kvcache._live_tiles(None, pos0, table)
         sk = jnp.zeros((L, B, 1, G, hd), kdt)
         sv = jnp.zeros((L, B, 1, G, hd), kdt)
         zero = jnp.zeros((), jnp.float32)
@@ -256,8 +256,8 @@ def test_layer_logits_close_and_untied_argmax_equal(params, cfg,
             layer = jax.tree.map(lambda w: w[li], params["blocks"])
             x, sk, sv, sks, svs = kvcache._staged_attn_layer(
                 cfg, c, table, layer, None, x, cos, sin, i, 0,
-                sk, sv, sks, svs, valid, stage_valid, batch_ix,
-                None, pos0, li == li and kernel)
+                sk, sv, sks, svs, pos0, stage_valid, batch_ix, tiles,
+                None, li == li and kernel)
             i = i + 1
         return np.asarray(kvcache._head(cfg, params, None, x))
 

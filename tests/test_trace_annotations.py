@@ -182,6 +182,17 @@ def test_promoted_counts_the_slots_above_their_own_rung(served):
                if a[4]["slots"] == 1)
 
 
+def test_tiles_counts_the_turns_of_the_live_rows(served):
+    """``tiles`` is host arithmetic over ``slots``: the turns of
+    ``kvcache.TILE`` slots a layer takes to read and attend them."""
+    from skypilot_tpu.infer import kvcache
+    anns, _, _ = served
+    dispatches = _named(anns, "engine.decode.dispatch")
+    assert dispatches
+    for a in dispatches:
+        assert a[4]["tiles"] == -(-a[4]["slots"] // kvcache.TILE) == 1
+
+
 def test_loop_annotations_come_from_one_thread(served):
     anns, _, _ = served
     loop = {a[3] for a in anns if a[0].startswith(("server.", "engine."))}
