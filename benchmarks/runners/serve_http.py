@@ -144,25 +144,29 @@ def _drive(ctx, plan, child: Child, port: int, trace_dir: str,
     seconds = ctx["seconds"]
     vocab = _vocab_size(ctx)
     lead = -min([r["due_s"] for r in plan["requests"]] + [0.0])
-    t0 = time.monotonic() + 0.3 + lead
-    t1 = t0 + seconds
-    t0_wall = time.time() + (t0 - time.monotonic())
-    timers = []
-    trace_at: Dict[str, Optional[float]] = {"start": None, "stop": None}
-    if ctx["trace"]:
-        span = min(TRACE_SECONDS, seconds / 2)
-        t_on = t0 + (seconds - span) / 2
+    with loadgen.collector_off():     # before the due times are fixed
+        t0 = time.monotonic() + 0.3 + lead
+        t1 = t0 + seconds
+        t0_wall = time.time() + (t0 - time.monotonic())
+        timers = []
+        trace_at: Dict[str, Optional[float]] = {"start": None, "stop": None}
+        if ctx["trace"]:
+            span = min(TRACE_SECONDS, seconds / 2)
+            t_on = t0 + (seconds - span) / 2
 
-        def trace_stop():
-            trace_at["stop"] = time.monotonic()
-            child.command("trace_stop")
+            def trace_stop():
+                trace_at["stop"] = time.monotonic()
+                child.command("trace_stop")
 
-        timers = [(t_on, lambda: child.command(f"trace_start {trace_dir}")),
-                  (t_on + span, trace_stop)]
+            timers = [
+                (t_on, lambda: child.command(f"trace_start {trace_dir}")),
+                (t_on + span, trace_stop)]
 
-    records = loadgen.run(
-        _HOST, port, [(t0 + r["due_s"], r) for r in plan["requests"]],
-        timers=timers)
+        loop_ms: Dict[str, float] = {}
+        records = loadgen.run(
+            _HOST, port, [(t0 + r["due_s"], r) for r in plan["requests"]],
+            timers=timers, loop_info=loop_ms,
+            poll=bool(ctx["cell"].get("generator_polls")))
 
     if ctx["trace"]:
         started = child.expect("TRACE", 30.0)
@@ -184,7 +188,10 @@ def _drive(ctx, plan, child: Child, port: int, trace_dir: str,
             values[f"ttft_p{q}_ms"] = stats.percentile(ttft, q) * 1e3
         if gaps:
             values[f"tpot_p{q}_ms"] = stats.percentile(gaps, q)
+    facts["client"] = dict(values)       # reader ``client_value``
     late = [r.sent - r.due for r in records if r.sent is not None]
+    dues = sorted(r.due for r in records)
+    ticks = [t0 + seconds * (i + 0.5) / 200 for i in range(200)]
     both = [r for r in good if r.first is not None
             and r.first - r.due <= TTFT_LIMIT_S
             and (stats.mean_gap_ms(r.stamps) or 0.0) <= GAP_LIMIT_MS]
@@ -195,11 +202,22 @@ def _drive(ctx, plan, child: Child, port: int, trace_dir: str,
         "generator_lateness_ms": {
             "median": stats.percentile(late, 50) * 1e3 if late else None,
             "max": max(late) * 1e3 if late else None},
+        # The loop's own worst moments (``loadgen.run``): a late generator
+        # shows in the first three, a slow accept or read in the last.
+        "generator_loop_ms": loop_ms,
+        # Of the window's arrivals, the share due a second or more after
+        # the one before (the server's ``open_window_s``: it has gone
+        # over to long bursts by then).
+        "arrivals_after_1s_quiet_share": sum(
+            1 for a, b in zip(dues, dues[1:]) if b >= t0 and b - a >= 1.0)
+        / max(len(judged), 1),
         "share_inside_limits": len(both) / max(len(judged), 1),
         "out_tokens_in_window": sum(1 for r in judged for t in r.stamps
                                     if t0 <= t < t1),
         "in_flight": {"window_opens": _in_flight(records, t0),
-                      "window_closes": _in_flight(records, t1)},
+                      "window_closes": _in_flight(records, t1),
+                      "window_mean": sum(_in_flight(records, t)
+                                         for t in ticks) / len(ticks)},
         "ttft_ms": stats.summary(t * 1e3 for t in ttft),
         "ttft_from_send_mean_ms": (sum(sent_ttft) * 1e3 / len(sent_ttft)
                                    if sent_ttft else None),

@@ -1,19 +1,28 @@
 """Find a serve configuration's knee, once, when a cell is defined:
 
-    python3 -m benchmarks.tools.knee_sweep --workload <cell> --rates 1.6,2,2.4,2.8,3.2 --step-seconds 60
+    python3 -m benchmarks.tools.knee_sweep --workload <cell> --rates 2,2.4,2.8,3.2,3.6,4,4.5,5,6,7,8,9,10 --step-seconds 60
 
 One server (one set-up), then each rate as an open-loop step of the
 cell's own mix, started on an empty server and drained before the next.
-A step has to last several request lifetimes (a request of the chat mix
-lives ~11 s at ~100 ms a token); its first third is the ramp and is not
-judged. A rate is SUSTAINED when, over the last two thirds of its step,
-the tokens received keep up with the tokens asked for (>= 0.9 of the
-output tokens of the requests due then) AND the requests in flight,
-averaged over the last third, are no more than 1.2 times their average
-over the middle third. Above the knee the backlog, and with it the
-time to a first token, grows all through the step. The knee is the
-highest sustained rate; the cell's file then fixes about 0.8 of it, and
-the table goes into ``PERF.md``. Never part of a run of the benchmark."""
+A step has to last several request lifetimes (PR 37's sweeps: a request
+of the chat mix lives ~2 s at 2 req/s and ~5 s at 8, at 17-36 ms a
+token; one of the long-prompt mix 1-4 s); its first third is the ramp
+and is not judged. A rate is SUSTAINED when, over the last two thirds of
+its step, the tokens received keep up with the tokens asked for (>= 0.9
+of the output tokens of the requests due then) AND the requests in
+flight, averaged over the last third, are no more than 1.2 times their
+average over the middle third (than 1.2 requests, where that was under
+one). Above the knee the backlog, and with it the time to a first
+token, grows all through the step. Two things the rule does, both seen in PR 37's tables
+(``PERF.md`` section 6): it calls a rate sustained whose queue stands
+still at a length (7.5 and 8.0 req/s of the chat mix keep 50-57 requests
+for 32 slots and a first token waits 3 s), and with one or two requests
+in flight it trips on the arrivals' own noise (1.25 -> 2.0 in flight is
+"growth" of 1.6). Read the first-token times by third beside it. The
+knee is the highest sustained rate below the first that is not (low
+rates that trip on noise apart); the cell's file then fixes 0.8 of it
+(``knee`` and ``traffic_overrides.rate_rps`` there), and the table goes
+into ``PERF.md``. Never part of a run of the benchmark."""
 
 from __future__ import annotations
 
@@ -73,6 +82,9 @@ def judge(records, t0: float, step_s: float) -> dict:
                                      ttft_ms(b, c, 95)],
             "tpot_p50_ms": stats.percentile(gaps, 50) if gaps else None,
             "tpot_p90_ms": stats.percentile(gaps, 90) if gaps else None,
+            "generator_late_ms_max": max(
+                ((r.sent - r.due) * 1e3 for r in records
+                 if r.sent is not None), default=None),
             "drain_s": max((r.end or t0) for r in records) - c}
 
 
@@ -116,10 +128,11 @@ def main() -> int:
             plan = gen.generate(dict(mix, rate_rps=rate), args.seed + i,
                                 args.step_seconds, dims.vocab_size,
                                 int(config["program"]["max_len"]))
-            t0 = time.monotonic() + 0.3
-            records = loadgen.run(
-                "127.0.0.1", port,
-                [(t0 + r["due_s"], r) for r in plan["requests"]])
+            with loadgen.collector_off():
+                t0 = time.monotonic() + 0.3
+                records = loadgen.run(
+                    "127.0.0.1", port,
+                    [(t0 + r["due_s"], r) for r in plan["requests"]])
             row = dict({"rate_rps": rate},
                        **judge(records, t0, args.step_seconds))
             rows.append(row)

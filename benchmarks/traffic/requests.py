@@ -21,11 +21,13 @@ A length distribution is {"dist": "lognormal", "median", "sigma", "min",
 
 Every ``--seed`` replays the SAME schedule — the same lengths at the same
 due times, fixed by ``shape_seed`` — with other token ids (and, in the
-child, other weights). A window holds some tens of requests that each
-live for a quarter of it, and an arrival waits for whatever burst the
-server has in flight, so another order of the same arrivals is another
-experiment whose numbers differ by more than any bound could admit: the
-seed changes what is computed, not when. What the driver's spread then
+child, other weights). A window holds tens to hundreds of requests (56
+of the long-prompt mix, 192 of the chat mix at their cells' rates, PR
+37) that each live for a tenth of it or less, a tail is the slowest
+dozen of them, and an arrival waits for whatever burst the server has in
+flight and for a slot when all are taken, so another order of the same
+arrivals is another experiment whose numbers differ by more than any
+bound could admit: the seed changes what is computed, not when. What the driver's spread then
 shows is the run-to-run noise of one schedule, not the variance across
 arrivals; ``PERF.md`` says so. numpy only; never JAX.
 """

@@ -42,6 +42,26 @@ def test_iqr_share_is_the_contracts_spread():
         (q3 - q1) / statistics.median(vals))
 
 
+def test_two_sides_of_one_commit_by_hand():
+    """``tools/aa_runs.summarise``: each side's median and spread, the
+    spread without the run farthest from the median (one far-off run
+    does no harm there, as in the driver's rule), and how far apart the
+    medians lie."""
+    from benchmarks.tools import aa_runs
+    sides = {0: [100.0, 101.0, 102.0, 103.0, 104.0, 150.0],
+             1: [110.0, 111.0, 112.0, 113.0, 114.0, 115.0]}
+    rows = [{"side": s, "values": {"m": v, "absent": None}}
+            for s, vs in sides.items() for v in vs]
+    got, absent = aa_runs.summarise(rows, ["m", "absent"])
+    assert absent["sides"] == {}
+    a, b = got["sides"][0], got["sides"][1]
+    assert a["median"] == 102.5 and b["median"] == 112.5
+    assert a["spread"] == pytest.approx(stats.iqr_share(sides[0]))
+    assert a["spread_trimmed"] == pytest.approx(
+        stats.iqr_share(sides[0][:5])) and a["spread_trimmed"] < a["spread"]
+    assert got["medians_apart"] == pytest.approx(10 / 102.5)
+
+
 def test_mean_gap_spans_bursts():
     # four tokens in two bursts: the mean gap is (t_last - t_first) / 3
     assert stats.mean_gap_ms([1.0, 1.0, 1.06, 1.06]) == pytest.approx(20.0)

@@ -70,11 +70,17 @@ def test_the_manifest_with_the_new_cell_is_valid(spec):
     assert layers == SERVE_METRICS | NEW_METRICS
     chat = {m["name"] for m in manifest.cell_metrics(
         spec, "mistral-7b-w8a8.chat-steady", "per_layer")}
-    assert chat == SERVE_METRICS | {"decode_attn_core_share"}
-    # new entries stand last in their lists
+    # since PR 37 the chat cell reports no first token end to end: its
+    # first-token readings carry its own names (PERF.md section 2)
+    first_token = {"prefill_device_ms_per_ktok", "ttft_queue_share",
+                   "prefill_useful_token_share"}
+    assert chat == (SERVE_METRICS - first_token) | {
+        "decode_attn_core_share", "ttft_p95_ms.chat"} | {
+        n + ".chat" for n in first_token}
+    # new entries stand last in their lists (PR 37's four after them)
     assert spec["configs"][-1]["name"] == CONFIG
     assert spec["workloads"][-1]["name"] == CELL
-    assert {m["name"] for m in spec["per_layer"][-4:]} == NEW_METRICS
+    assert {m["name"] for m in spec["per_layer"][-8:-4]} == NEW_METRICS
 
 
 def test_configuration_keeps_every_published_key(config, spec):

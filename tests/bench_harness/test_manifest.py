@@ -561,3 +561,69 @@ def test_a_name_cannot_leave_the_directory():
         manifest.load_module("readers", "no_such_reader")
     with pytest.raises(manifest.ManifestError):
         manifest.load_family({"family": "no_such_family"})
+
+
+# ---------------------------------------------------------------------------
+# An open-loop cell's rate is a stated share of a stated knee, and its tails
+# stand on enough requests
+# ---------------------------------------------------------------------------
+
+def _open_loop_cells():
+    spec = manifest.load_manifest()
+    cells = [manifest.load_workload(w["name"]) for w in spec["workloads"]]
+    return [c["name"] for c in cells
+            if "rate_rps" in (c.get("traffic_overrides") or {})]
+
+
+def _num(x: float) -> str:
+    return f"{x:g}" if x != int(x) else f"{x:.1f}"
+
+
+@pytest.mark.parametrize("name", _open_loop_cells())
+def test_a_cells_rate_is_the_stated_share_of_its_knee(spec, name):
+    """The file's ``knee`` says which sweep found which rate; the cell's
+    rate is that share of it rounded DOWN to 0.1 req/s; the ``why``
+    names knee, share and rate, and is the one ``BENCHMARK.json``
+    prints."""
+    cell = manifest.load_workload(name)
+    knee, rate = cell["knee"], cell["traffic_overrides"]["rate_rps"]
+    assert rate == int(knee["share"] * knee["rate_rps"] * 10 + 1e-9) / 10
+    assert 0.5 <= knee["share"] <= 0.9 and knee["swept"]
+    (entry,) = [w for w in spec["workloads"] if w["name"] == name]
+    assert entry["why"] == cell["why"] and len(cell["why"]) <= 200
+    for number in (rate, knee["rate_rps"], knee["share"]):
+        assert _num(number) in cell["why"], (number, cell["why"])
+    assert "knee" in cell["why"]
+
+
+@pytest.mark.parametrize("name", _open_loop_cells())
+def test_a_cells_tails_stand_on_enough_requests(spec, name):
+    """``stats.supported_tail``: the highest percentile with ten samples
+    beyond it. A cell whose window judges fewer says so, and why, in its
+    file's ``tail_note``."""
+    import re
+
+    from benchmarks import stats
+    cell = manifest.load_workload(name)
+    judged = round(cell["traffic_overrides"]["rate_rps"]
+                   * spec["run_seconds"])
+    tail = stats.supported_tail(judged) or 0
+    named = [int(m.group(1)) for m in (
+        re.search(r"_p(\d+)_", e["name"]) for e in
+        manifest.cell_metrics(spec, name, "end_to_end")) if m]
+    assert named
+    if max(named) > tail:
+        note = cell.get("tail_note", "")
+        assert str(judged) in note and len(note) > 60, (judged, tail)
+    else:
+        assert "tail_note" not in cell
+
+
+@pytest.mark.parametrize("name", _open_loop_cells())
+def test_a_cell_whose_generator_polls_says_why(name):
+    """The generator waits between events; a cell whose file makes it
+    spin (``generator_polls``, one core for a run's length) gives the
+    measured comparison that it rests on."""
+    why = manifest.load_workload(name).get("generator_polls")
+    if why is not None:
+        assert "PERF.md" in why and "runs" in why and len(why) > 100

@@ -331,6 +331,12 @@ def test_the_reduction_is_made_once_per_run_directory(tmp_path):
     ("flash_dkv_device_ms", "device_trace", "train_tokens_per_s"),
     ("flash_dq_device_ms", "device_trace", "train_tokens_per_s"),
     ("base_matmul_share", "device_trace", "train_tokens_per_s"),
+    # the chat cell's first-token readings, split off in PR 37: the cell
+    # reports no TTFT end to end, so they move what it does report
+    ("ttft_queue_share.chat", "program_span", "tpot_p90_ms"),
+    ("prefill_useful_token_share.chat", "program_counter", "tpot_p90_ms"),
+    ("prefill_device_ms_per_ktok.chat", "device_trace", "tpot_p90_ms"),
+    ("ttft_p95_ms.chat", "host_clock", "tpot_p90_ms"),
 ])
 def test_manifest_entry_of_each_new_metric(name, source, moves):
     spec = manifest.load_manifest()
@@ -372,3 +378,58 @@ def test_scopes_and_kernel_names_are_the_programs_own():
     for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq",
                    "paged_decode"):
         assert f'name="{kernel}"' in text
+
+
+# ---------------------------------------------------------------------------
+# A quantity split between cells that report different end-to-end metrics
+# ---------------------------------------------------------------------------
+
+def _split_metrics():
+    spec = manifest.load_manifest()
+    names = {m["name"] for m in spec["per_layer"]}
+    return sorted(n for n in names
+                  if "." in n and n.rsplit(".", 1)[0] in names)
+
+
+@pytest.mark.parametrize("name", _split_metrics())
+def test_a_split_metric_reads_what_its_original_reads(name):
+    """``<metric>.<cell>`` is the same reading for cells that report
+    another end-to-end metric: layer, unit, reader and arguments are its
+    original's, only ``moves`` and the cells differ; no cell lists both."""
+    spec = manifest.load_manifest()
+    base = name.rsplit(".", 1)[0]
+    a, b = manifest.load_metric(base), manifest.load_metric(name)
+    for key in ("layer", "unit", "reader", "args"):
+        assert a.get(key) == b.get(key), key
+    assert a["moves"] != b["moves"]
+    ea, eb = ([m for m in spec["per_layer"] if m["name"] == n][0]
+              for n in (base, name))
+    for key in ("layer", "unit", "better", "source"):
+        assert ea[key] == eb[key], key
+    assert eb["moves"] == b["moves"] and ea["moves"] == a["moves"]
+    assert not set(ea["workloads"]) & set(eb["workloads"])
+
+
+def test_the_chat_cell_keeps_its_prefill_readings():
+    assert _split_metrics() == [
+        "prefill_device_ms_per_ktok.chat",
+        "prefill_useful_token_share.chat", "ttft_queue_share.chat"]
+    spec = manifest.load_manifest()
+    chat = {m["name"] for m in manifest.cell_metrics(
+        spec, "mistral-7b-w8a8.chat-steady", "per_layer")}
+    assert set(_split_metrics()) | {"ttft_p95_ms.chat"} <= chat
+    assert not {n.rsplit(".", 1)[0] for n in _split_metrics()} & chat
+
+
+def test_a_client_value_is_the_runs_own_and_only_from_the_chip(tmp_path):
+    """``ttft_p95_ms.chat``: the first-token tail the generator measured
+    over the traced run's whole window; nothing off the chip, and nothing
+    where the runner gave no such value."""
+    facts, ctx = _facts_ctx(tmp_path, FIXTURE)
+    assert _read("ttft_p95_ms.chat", facts, ctx) is None
+    facts["client"] = {"ttft_p95_ms": 431.5, "tpot_p90_ms": 31.8}
+    assert _read("ttft_p95_ms.chat", facts, ctx) == 431.5
+    facts["trace"]["platform"] = "cpu"
+    assert _read("ttft_p95_ms.chat", facts, ctx) is None
+    assert _read("ttft_p95_ms.chat", {"client": {"ttft_p95_ms": 1.0}},
+                 ctx) is None

@@ -4,6 +4,7 @@ stamps against a scripted server."""
 
 import http.server
 import json
+import socket
 import threading
 import time
 
@@ -183,6 +184,26 @@ def test_timers_run_from_the_loop_at_their_time(scripted_server):
     assert all(r.ok for r in recs) and recs[1].sent > fired[0]
 
 
+def test_the_collector_is_off_inside_the_block_and_back_after(
+        scripted_server):
+    """A measuring caller enters ``collector_off`` before it fixes the
+    due times (a collection takes its time in a large process); ``run``
+    itself leaves the collector alone."""
+    import gc
+    assert gc.isenabled()
+    seen = []
+    with loadgen.collector_off():
+        t0 = time.monotonic() + 0.1
+        loadgen.run("127.0.0.1", scripted_server, [(t0, _req(1, 2))],
+                    timers=[(t0, lambda: seen.append(gc.isenabled()))])
+        assert not gc.isenabled() and gc.get_freeze_count() > 0
+    assert gc.isenabled() and gc.get_freeze_count() == 0
+    t0 = time.monotonic() + 0.1
+    loadgen.run("127.0.0.1", scripted_server, [(t0, _req(1, 2))],
+                timers=[(t0, lambda: seen.append(gc.isenabled()))])
+    assert seen == [False, True] and gc.isenabled()
+
+
 # -- the knee sweep's rule on made-up records ------------------------------
 
 def _records(rate, step_s, lifetime, out_tokens, slots=None):
@@ -234,3 +255,113 @@ def test_prometheus_text_sums_by_name():
     got = loadgen.parse_prometheus(text)
     assert got["skytpu_programs_compiled_total"] == 7
     assert got["skytpu_ttft_seconds_sum"] == 1.5
+
+
+# -- a listener that accepts late -------------------------------------------
+
+class _LateListener:
+    """A server whose accept queue holds ONE connection and which starts
+    accepting ``late_s`` after it was made: the second connection's SYN
+    is dropped and retried by the kernel a second later. Each accepted
+    request gets ``n`` tokens, one every ``gap_s``."""
+
+    def __init__(self, late_s, n=4, gap_s=0.05):
+        self.sock = socket.socket()
+        self.sock.bind(("127.0.0.1", 0))
+        self.sock.listen(0)
+        self.port = self.sock.getsockname()[1]
+        self.late_s, self.n, self.gap_s = late_s, n, gap_s
+        self.accepted = []
+        self.closing = False
+        self.threads = [threading.Thread(target=self._accept, daemon=True)]
+        self.threads[0].start()
+
+    def _accept(self):
+        time.sleep(self.late_s)
+        self.sock.settimeout(0.05)
+        while not self.closing:
+            try:
+                conn, _ = self.sock.accept()
+            except socket.timeout:
+                continue
+            except OSError:
+                return
+            conn.settimeout(None)
+            self.accepted.append(time.monotonic())
+            t = threading.Thread(target=self._serve, args=(conn,),
+                                 daemon=True)
+            self.threads.append(t)
+            t.start()
+
+    def _serve(self, conn):
+        with conn:
+            data = b""
+            while b"\r\n\r\n" not in data:
+                data += conn.recv(65536)
+            conn.sendall(b"HTTP/1.1 200 OK\r\n"
+                         b"Transfer-Encoding: chunked\r\n\r\n")
+            for i in range(self.n):
+                line = json.dumps({"tokens": [i]}).encode() + b"\n"
+                conn.sendall(b"%x\r\n%s\r\n" % (len(line), line))
+                time.sleep(self.gap_s)
+            line = b'{"done": true}\n'
+            conn.sendall(b"%x\r\n%s\r\n0\r\n\r\n" % (len(line), line))
+
+    def close(self):
+        self.closing = True
+        for t in self.threads:
+            t.join(timeout=5)
+        self.sock.close()
+
+
+@pytest.mark.parametrize("poll", [False, True])
+def test_a_late_accept_delays_one_request_and_no_stamp(poll):
+    """Four requests a twentieth of a second apart at a listener that
+    starts accepting 0.4 s late and queues one connection: the kernel
+    retries the others' connects a second later. The loop must not wait
+    in any of them — the first request's tokens, streamed from 0.4 s on,
+    are stamped as they come, and every request is taken up at its due
+    time. (A ``send`` that connects and writes in the loop sits in the
+    second request's connect until ~1 s and stamps the first one's four
+    tokens together.) The same whether the loop waits between events
+    or polls."""
+    srv = _LateListener(late_s=0.4)
+    try:
+        t0 = time.monotonic() + 0.1
+        info = {}
+        recs = loadgen.run("127.0.0.1", srv.port,
+                           [(t0 + 0.05 * i, _req(1, 4)) for i in range(4)],
+                           loop_info=info, poll=poll)
+    finally:
+        srv.close()
+    assert all(r.ok for r in recs), [r.error for r in recs]
+    first = min(recs, key=lambda r: r.first)
+    # read as they came: three gaps of 50 ms, not one late read
+    assert first.stamps[-1] - first.stamps[0] > 0.1
+    assert first.first - srv.accepted[0] < 0.8
+    # every request was taken up when it was due (the kernel's retry is a
+    # second; the margins are for a loaded test machine); what waited
+    # for the listener is its own hand-over, and is recorded as that
+    assert all(r.started - r.due < 0.8 for r in recs)
+    assert info["taken_up_late_max"] < 800 and info["turn_max"] < 800
+    assert info["send_call_max"] < 800
+    late = [r for r in recs if r.sent - r.started > 0.3]
+    assert late and info["handover_max"] > 300
+
+
+def test_a_connect_that_never_completes_fails_that_request(monkeypatch):
+    """The listener's queue is full for longer than the connect limit:
+    the requests behind the first fail as refused ones do (attempted,
+    failed, no latency), and the loop goes on to finish the first."""
+    monkeypatch.setattr(loadgen, "CONNECT_TIMEOUT_S", 0.3)
+    srv = _LateListener(late_s=1.0)
+    try:
+        t0 = time.monotonic() + 0.1
+        recs = loadgen.run("127.0.0.1", srv.port,
+                           [(t0 + 0.02 * i, _req(1, 4)) for i in range(3)])
+    finally:
+        srv.close()
+    assert [r.ok for r in recs] == [True, False, False]
+    for r in recs[1:]:
+        assert "timed out" in r.error and r.status == 0 and r.sent is None
+        assert 0.3 <= r.end - r.started < 0.9
