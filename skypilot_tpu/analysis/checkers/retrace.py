@@ -39,7 +39,8 @@ _ROOT_DIRS = ("skypilot_tpu/infer/", "skypilot_tpu/train/")
 # call graph follows it into all of them.
 _FAMILY_SELECTOR = "programs_for"
 _FAMILY_MODULES = ("skypilot_tpu.infer.kvcache",
-                   "skypilot_tpu.infer.latent")
+                   "skypilot_tpu.infer.latent",
+                   "skypilot_tpu.infer.hybrid")
 
 # jnp constructors whose first argument is a shape.
 _SHAPE_CTORS = {"zeros", "ones", "full", "empty", "eye"}
@@ -138,7 +139,9 @@ class RetraceSafetyChecker(Checker):
     # is reached through ``kvcache.programs_for(cfg)``; calls on that
     # handle are followed into every family module, which keeps
     # kvcache's own programs reachable from the engine's roots too.
-    version = 6
+    # v7: a third family (infer/hybrid.py: K/V rows beside a per-slot
+    # recurrent state, models/olmo_hybrid.py) joins the family modules.
+    version = 7
 
     def check_project(self, ctxs: Sequence[FileContext],
                       root: str) -> List[Finding]:

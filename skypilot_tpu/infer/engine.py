@@ -109,7 +109,8 @@ KV_TOKEN_BYTES = metrics.gauge(
     "skytpu_kv_cache_bytes_per_token",
     "Cache bytes one token holds over all layers, from the cache's own "
     "tensors: 2 x layers x kv_heads x head_dim (+ scales) for per-head "
-    "K/V, layers x (kv_lora_rank + rope dim) for a latent (MLA) cache")
+    "K/V, layers x (kv_lora_rank + rope dim) for a latent (MLA) cache, "
+    "the full-attention layers only for a hybrid cache")
 KV_BLOCKS_USED = metrics.gauge(
     "skytpu_kv_blocks_used",
     "Paged KV cache: blocks currently referenced by decode slots "
@@ -407,24 +408,26 @@ class UnsupportedOptionError(ValueError):
         }
 
 
-def refuse_latent_options(**given) -> None:
-    """The latent-cache family (``infer/latent.py``) serves float
-    weights from a paged latent cache on one device; everything else
-    is refused by name (docs/serving.md §Latent cache lists them)."""
-    why = {
-        "kv_block=0": "the latent cache is paged only",
-        "kv_int8": "latent rows have no int8 form",
-        "weights_int8": "the expert and MLA matrices have no int8 form",
-        "tp": "no latent cache or expert layer under a mesh",
-        "adapters": "no LoRA targets in the MLA projections",
-        "spec_k": "no verify program over the latent cache",
-        "draft_model": "no verify program over the latent cache",
-        "kv_kernel": "the paged-attention kernel reads per-head K/V",
-    }
+def refuse_options(progs, **given) -> None:
+    """Refuse, by name and before anything is allocated, each option of
+    ``given`` that is on and that the family of serve programs ``progs``
+    (``kvcache.programs_for``) cannot serve: the family's module lists
+    them with their reasons (``UNSUPPORTED``)."""
     for option, on in given.items():
-        if on:
-            raise UnsupportedOptionError(option, "latent-cache (MLA)",
-                                         why[option])
+        if on and option in progs.UNSUPPORTED:
+            raise UnsupportedOptionError(option, progs.FAMILY,
+                                         progs.UNSUPPORTED[option])
+
+
+def refuse_hybrid_options(**given) -> None:
+    """:func:`refuse_options` for the hybrid family (``infer/hybrid.py``),
+    which serves float weights from paged K/V beside a per-slot
+    recurrent state on one device, with no shared blocks: a non-zero
+    prefix pool, the handoff and everything the latent family refuses
+    too are refused by name (docs/serving.md §Recurrent state lists
+    them)."""
+    from skypilot_tpu.infer import hybrid
+    refuse_options(hybrid, **given)
 
 
 class KvPoolWedgedError(RuntimeError):
@@ -747,26 +750,30 @@ class InferenceEngine:
         self.qos = qos
         self.cfg = cfg
         # The serve programs of the config's family: kvcache's own
-        # (GQA rows) or infer/latent.py's (MLA latent rows). Every
-        # jitted entry point below calls through this, and everything
-        # that moves blocks without reading rows is shared.
+        # (GQA rows), infer/latent.py's (MLA latent rows) or
+        # infer/hybrid.py's (K/V rows beside a per-slot recurrent
+        # state). Every jitted entry point below calls through this,
+        # everything that moves blocks without reading rows is shared,
+        # and what differs by family is a question the module answers
+        # (kvcache.programs_for lists them) — first of all which of
+        # these options it cannot serve.
         self._progs = progs = kvcache.programs_for(cfg)
-        self.latent = progs is not kvcache
-        if self.latent:
-            refuse_latent_options(**{
-                "kv_block=0": kv_block == 0 or (
-                    kv_block is None and os.environ.get(
-                        "SKYTPU_KV_BLOCK", "256") in ("", "0")),
-                "kv_int8": kv_int8,
-                "weights_int8": weights_int8 or qweights is not None,
-                "tp": mesh is not None,
-                "adapters": adapters is not None,
-                "spec_k": (spec_k if spec_k is not None else int(
-                    os.environ.get("SKYTPU_SPEC_K", "0") or 0)) > 0,
-                "draft_model": draft_engine is not None,
-                "kv_kernel": bool(kv_kernel) or (
-                    kv_kernel is None
-                    and os.environ.get("SKYTPU_KV_KERNEL", "") == "1")})
+        refuse_options(progs, **{
+            "kv_block=0": kv_block == 0 or (
+                kv_block is None and os.environ.get(
+                    "SKYTPU_KV_BLOCK", "256") in ("", "0")),
+            "kv_int8": kv_int8,
+            "weights_int8": weights_int8 or qweights is not None,
+            "tp": mesh is not None,
+            "adapters": adapters is not None,
+            "spec_k": (spec_k if spec_k is not None else int(
+                os.environ.get("SKYTPU_SPEC_K", "0") or 0)) > 0,
+            "draft_model": draft_engine is not None,
+            "kv_kernel": bool(kv_kernel) or (
+                kv_kernel is None
+                and os.environ.get("SKYTPU_KV_KERNEL", "") == "1"),
+            "prefix_pool": (prefix_pool if prefix_pool is not None else int(
+                os.environ.get("SKYTPU_PREFIX_POOL", "0") or 0)) > 0})
         self.n_slots = n_slots
         self.max_len = max_len
         self.buckets = tuple(b for b in prompt_buckets if b <= max_len)
@@ -1118,17 +1125,7 @@ class InferenceEngine:
         # model behind the MFU / bandwidth-utilization columns. KV
         # bytes-per-token is computed from the ACTUAL cache dtypes
         # (int8 KV counts its fp32 scales).
-        L = cfg.n_layers
-        if self.latent:
-            hd = cfg.qk_head_dim
-            self._kv_token_bytes = progs.token_bytes(cfg)
-            param_count = cfg.active_params()
-        else:
-            itemsize = self.cache["k"].dtype.itemsize
-            G, hd = cfg.n_kv_heads, cfg.head_dim
-            self._kv_token_bytes = 2 * L * G * hd * itemsize \
-                + (2 * L * G * 4 if "k_scale" in self.cache else 0)
-            param_count = cfg.num_params()
+        self._kv_token_bytes = progs.token_bytes(cfg, self.cache)
         KV_TOKEN_BYTES.set(self._kv_token_bytes)
         self._kv_block_bytes = (self._kv_token_bytes * self.kv_block
                                 if self.paged else 0)
@@ -1137,10 +1134,10 @@ class InferenceEngine:
         self.hbm_ledger = attribution_lib.HbmLedger()
         self._weight_bytes = weight_bytes
         self.roofline = attribution_lib.Roofline(
-            param_count=param_count, weight_bytes=weight_bytes,
+            weight_bytes=weight_bytes,
             kv_token_bytes=self._kv_token_bytes, d_model=cfg.d_model,
-            n_layers=L, n_heads=cfg.n_heads, head_dim=hd,
-            max_len=max_len, chunk_tokens=self.prefill_chunk)
+            max_len=max_len, chunk_tokens=self.prefill_chunk,
+            **progs.roofline_dims(cfg))
         # The draft model's rollouts attribute at ITS scale, not the
         # verifier's — a second roofline on the draft config.
         self._draft_roofline = None
@@ -1243,10 +1240,14 @@ class InferenceEngine:
         def _decode(params, cache, rng, active, table=None,
                     lora=None, aid=None, qweights=None, *, span=None):
             rng, sub = jax.random.split(rng)
+            # A per-slot state moves on for the rows that keep their
+            # token only; rows in a pool need no such care (a dead
+            # slot's pending row lands past its length).
+            own = {"live": active} if progs.SLOT_STATE else {}
             cache, logits = progs.decode_step(params, cache, cfg,
                                                 qweights=qweights,
                                                 table=table, span=span,
-                                                lora=lora, aid=aid)
+                                                lora=lora, aid=aid, **own)
             with jax.named_scope("sample"):
                 toks = sampling.sample(logits, sub, sp)
             cache = kvcache.commit_tokens(cache, toks, active)
@@ -1450,17 +1451,12 @@ class InferenceEngine:
         family with no host-authoritative array to read."""
         led = self.hbm_ledger
         led.set_bytes("weights", self._weight_bytes)
-        # A latent cache and a layer's routed experts are rows of
-        # their own: what the first holds a token and how much of the
-        # weights the second is are what sizes such a deployment.
-        # ("expert_weights" is a view INSIDE "weights", as kv_used is
-        # inside its pool.)
-        pool_row = "latent_kv_pool" if self.latent else "kv_pool"
-        led.set_bytes(pool_row, attribution_lib.tensor_bytes(self.cache))
-        if self.latent:
-            led.set_bytes("expert_weights", sum(
-                attribution_lib.tensor_bytes(self.params["moe"][n])
-                for n in ("we_gate", "we_up", "we_down")))
+        # What the cache holds is the family's to name: ``kv_pool``,
+        # or ``latent_kv_pool`` (+ the ``expert_weights`` view inside
+        # ``weights``), or ``kv_pool`` beside ``recurrent_state``.
+        for row, n in self._progs.hbm_rows(self.cache,
+                                           self.params).items():
+            led.set_bytes(row, n)
         led.set_bytes("prefix_pool",
                       attribution_lib.tensor_bytes(self.pool))
         led.set_bytes("draft_pool",
@@ -1529,6 +1525,12 @@ class InferenceEngine:
         elif self.kv_kernel:
             return 0
         return -(-n_live // kvcache.TILE)
+
+    def _state_rows(self, slots) -> Dict[str, int]:
+        """The decode dispatch annotation's ``state_rows``: the slots
+        whose per-slot state the program updates (a family without one
+        says nothing)."""
+        return {"state_rows": len(slots)} if self._progs.SLOT_STATE else {}
 
     def _record_flight(self, burst: str, begin_s: float, end_s: float,
                        program: Dict[str, Any], slots, reqs,
@@ -2742,6 +2744,9 @@ class InferenceEngine:
         fresh = req.first_token_s is None    # not a preemption resume
         counts = {"chunk_tokens": n_valid, "padded_tokens": C,
                   "final": 1 if final else 0}
+        if self._progs.SLOT_STATE:
+            # Whether the chunk continues a state resident in the slot.
+            counts["carried"] = 1 if start > 0 else 0
         if fresh and req.n_chunks == 0:
             req.queue_s = max(t0 - req.submit_s, 0.0)
             counts["queue_ms"] = round(req.queue_s * 1e3, 3)
@@ -2920,6 +2925,7 @@ class InferenceEngine:
         handoff leaves the donor exactly as warm as any cached serve).
         Returns None when no chunk-aligned prefix is resident (the
         caller falls back to single-tier)."""
+        refuse_options(self._progs, export_prefix=True)
         idx = self._prefix_index
         if not self.paged or idx is None:
             return None
@@ -2954,6 +2960,7 @@ class InferenceEngine:
         layout/geometry mismatch or a dry pool; the caller's request
         still runs correctly, just cold). Loop-thread only: allocates
         blocks and swaps the donated cache."""
+        refuse_options(self._progs, import_prefix=True)
         idx = self._prefix_index
         if not self.paged or idx is None:
             return 0
@@ -3763,7 +3770,7 @@ class InferenceEngine:
                 slots=len(slots), rows=self.n_slots + 1,
                 tiles=self._tiles("decode", len(slots)),
                 span=attn_span, promoted=promoted, why=why,
-                waiting=len(self.waiting)):
+                waiting=len(self.waiting), **self._state_rows(slots)):
             self.cache, self.rng, toks = self._decode_burst_fn(
                 self.params, self.cache, self.rng,
                 jnp.asarray(active), self.table_device(), k=k,
@@ -3875,7 +3882,7 @@ class InferenceEngine:
                 slots=len(slots), rows=self.n_slots + 1,
                 tiles=self._tiles("decode1", len(slots)),
                 span=attn_span, promoted=promoted, why="step",
-                waiting=len(self.waiting)):
+                waiting=len(self.waiting), **self._state_rows(slots)):
             self.cache, self.rng, toks = self._decode_fn(
                 self.params, self.cache, self.rng, jnp.asarray(active),
                 self.table_device(), qweights=self.qweights, span=sarg,
@@ -3961,9 +3968,8 @@ def random_serving_weights(cfg: llama.LlamaConfig, *,
     :class:`WeightsDoNotFitError` names both numbers."""
     from skypilot_tpu.parallel import sharding as sh
     model = registry.model_for(cfg)
-    if model is not llama and (weights_int8 or mesh is not None):
-        refuse_latent_options(weights_int8=weights_int8,
-                               tp=mesh is not None)
+    refuse_options(kvcache.programs_for(cfg), weights_int8=weights_int8,
+                   tp=mesh is not None)
     if weights_int8:
         def build():
             params, qweights = kvcache.random_quantized_params(cfg, seed)

@@ -40,7 +40,7 @@ JetStream section). This module is the in-tree TPU-native engine core.
 
 from __future__ import annotations
 
-import sys
+import importlib
 from typing import Dict, Tuple
 
 import jax
@@ -48,7 +48,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from skypilot_tpu.infer import sampling as sampling_mod
-from skypilot_tpu.models import llama
+from skypilot_tpu.models import llama, registry
+from skypilot_tpu.observability import attribution
 from skypilot_tpu.ops import paged_attention as paged_attn_ops
 from skypilot_tpu.parallel import ring_attention as ra
 
@@ -86,14 +87,16 @@ SPARE_COLUMN = None
 
 
 def programs_for(cfg):
-    """The module holding the serve programs of ``cfg``'s family: this
-    module for the GQA decoders, ``infer/latent.py`` for the
-    latent-cache family. The engine's jitted entry points call through
-    it and are otherwise one code path. What the engine uses of a
-    family module, with this module's signatures:
+    """The module holding the serve programs of ``cfg``'s family, as the
+    family's model module names it (``SERVE_PROGRAMS``): this module for
+    the GQA decoders, ``infer/latent.py`` for the latent-cache family,
+    ``infer/hybrid.py`` for the decoders that keep a recurrent state per
+    slot beside their K/V rows. The engine's jitted entry points call
+    through it and are otherwise one code path. What the engine uses of
+    a family module, with this module's signatures:
 
     * ``init_paged_cache`` — the block pool and the per-slot
-      ``length`` / ``last_token``;
+      ``length`` / ``last_token`` (and whatever else a slot holds);
     * ``prefill_batch`` + ``insert`` (``_admit_wave``), ``prefill_chunk``
       (``_prefill_chunk``), ``decode_step`` (``_decode``),
       ``decode_burst_staged`` (``_decode_burst``) and
@@ -101,15 +104,48 @@ def programs_for(cfg):
       keeps the name and raises);
     * ``SPARE_COLUMN`` — what the spare slot's column of a burst's
       ``toks`` carries (``None``: nothing);
-    * ``token_bytes(cfg)`` — only of a family whose row is not this
-      module's per-head K/V (the engine computes those bytes itself).
+    * ``FAMILY`` and ``UNSUPPORTED`` — the family's name in a refusal
+      and the engine options it cannot serve, each with its reason
+      (``engine.refuse_options``);
+    * ``SLOT_STATE`` — the cache tensors that hold one FIXED-size entry
+      per slot instead of rows (``()``: none). A family that has them
+      takes ``live`` in ``decode_step`` too, is told ``carried`` /
+      ``state_rows`` in the dispatch annotations, and cannot share
+      blocks (a block's rows are not all a sharer needs);
+    * ``token_bytes(cfg, cache)``, ``hbm_rows(cache, params)`` and
+      ``roofline_dims(cfg)`` — the cache bytes a token holds, the HBM
+      ledger's rows for what the family keeps on the device, and what
+      the analytical cost model takes from the config.
 
     Blocks move (allocation, copy-on-write, handoff, addressing) through
     this module's :func:`row_tensors` helpers whatever the family."""
-    if hasattr(cfg, "kv_lora_rank"):
-        from skypilot_tpu.infer import latent
-        return latent
-    return sys.modules[__name__]
+    return importlib.import_module(registry.model_for(cfg).SERVE_PROGRAMS)
+
+
+# This family's answers (see :func:`programs_for`).
+FAMILY = "GQA decoder"
+UNSUPPORTED: Dict[str, str] = {}
+SLOT_STATE: Tuple[str, ...] = ()
+
+
+def token_bytes(cfg: llama.LlamaConfig, cache: Cache) -> int:
+    """Cache bytes a token holds, all layers, from the cache's ACTUAL
+    dtypes (int8 rows count their float32-accounted scales)."""
+    per_layer = 2 * cfg.n_kv_heads * cfg.head_dim * cache["k"].dtype.itemsize
+    if "k_scale" in cache:
+        per_layer += 2 * cfg.n_kv_heads * 4
+    return cfg.n_layers * per_layer
+
+
+def hbm_rows(cache: Cache, params) -> Dict[str, int]:
+    """The HBM ledger's rows for what this family keeps resident."""
+    return {"kv_pool": attribution.tensor_bytes(cache)}
+
+
+def roofline_dims(cfg: llama.LlamaConfig) -> Dict[str, int]:
+    """What the engine's analytical cost model takes from the config."""
+    return {"param_count": cfg.num_params(), "n_layers": cfg.n_layers,
+            "n_heads": cfg.n_heads, "head_dim": cfg.head_dim}
 
 
 def init_cache(cfg: llama.LlamaConfig, n_slots: int,

@@ -38,7 +38,7 @@ from jax import lax
 from skypilot_tpu.infer import kvcache
 from skypilot_tpu.infer import sampling as sampling_mod
 from skypilot_tpu.models import glm_moe as glm
-from skypilot_tpu.observability import metrics
+from skypilot_tpu.observability import attribution, metrics
 
 Cache = kvcache.Cache
 
@@ -79,10 +79,42 @@ def init_paged_cache(cfg: glm.GlmMoeConfig, n_slots: int, n_blocks: int,
                           cfg.dtype)}
 
 
-def token_bytes(cfg: glm.GlmMoeConfig) -> int:
+def token_bytes(cfg: glm.GlmMoeConfig, cache=None) -> int:
     """Cache bytes a token holds, all layers."""
     return cfg.n_layers * cfg.latent_row_width \
         * jnp.dtype(cfg.dtype).itemsize
+
+
+# This family's answers to the engine (``kvcache.programs_for``).
+FAMILY = "latent-cache (MLA)"
+UNSUPPORTED = {
+    "kv_block=0": "the latent cache is paged only",
+    "kv_int8": "latent rows have no int8 form",
+    "weights_int8": "the expert and MLA matrices have no int8 form",
+    "tp": "no latent cache or expert layer under a mesh",
+    "adapters": "no LoRA targets in the MLA projections",
+    "spec_k": "no verify program over the latent cache",
+    "draft_model": "no verify program over the latent cache",
+    "kv_kernel": "the paged-attention kernel reads per-head K/V",
+}
+SLOT_STATE = ()
+
+
+def roofline_dims(cfg: glm.GlmMoeConfig) -> dict:
+    """A token multiplies with its chosen experts only."""
+    return {"param_count": cfg.active_params(), "n_layers": cfg.n_layers,
+            "n_heads": cfg.n_heads, "head_dim": cfg.qk_head_dim}
+
+
+def hbm_rows(cache: Cache, params) -> dict:
+    """The HBM ledger's rows: a latent cache and a layer's routed
+    experts are rows of their own — what the first holds a token and
+    how much of the weights the second is are what sizes such a
+    deployment. (``expert_weights`` is a view INSIDE ``weights``, as
+    ``kv_used`` is inside its pool.)"""
+    return {"latent_kv_pool": attribution.tensor_bytes(cache),
+            "expert_weights": attribution.tensor_bytes(
+                [params["moe"][n] for n in glm.EXPERT_TENSORS])}
 
 
 # ---------------------------------------------------------------------------

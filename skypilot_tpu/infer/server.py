@@ -1352,14 +1352,15 @@ def _main() -> None:
     # A family without a verify program serves with speculation off
     # unless the operator asks for it (and is then refused by name,
     # like every option the family does not serve: main()).
-    can_verify = kvcache.programs_for(cfg) is kvcache
-    if not can_verify:
-        # Before either is built from a config object of another family.
-        eng.refuse_latent_options(
-            adapters=bool(args.adapters or os.environ.get(
-                "SKYTPU_ADAPTERS", "").strip()),
-            draft_model=bool(args.draft_model or os.environ.get(
-                "SKYTPU_DRAFT_MODEL", "").strip()))
+    progs = kvcache.programs_for(cfg)
+    can_verify = "spec_k" not in progs.UNSUPPORTED
+    # Before either is built from a config object of another family.
+    eng.refuse_options(
+        progs,
+        adapters=bool(args.adapters or os.environ.get(
+            "SKYTPU_ADAPTERS", "").strip()),
+        draft_model=bool(args.draft_model or os.environ.get(
+            "SKYTPU_DRAFT_MODEL", "").strip()))
     mesh = None
     if args.tp > 1:
         import numpy as np
@@ -1415,11 +1416,14 @@ def _main() -> None:
         kv_kernel=args.kv_kernel,
         # Serving default: prefix reuse ON (repeated system prompts are
         # the common serving workload); the engine-level default stays
-        # 0 so library users opt in.
+        # 0 so library users opt in. A family whose blocks cannot be
+        # shared defaults to 0 and refuses more, by name.
         prefix_pool=(args.prefix_pool
                      if args.prefix_pool is not None
-                     else int(os.environ.get("SKYTPU_PREFIX_POOL",
-                                             "8") or 0)),
+                     else int(os.environ.get(
+                         "SKYTPU_PREFIX_POOL",
+                         "0" if "prefix_pool" in progs.UNSUPPORTED
+                         else "8") or 0)),
         # Serving default: speculation ON at K=4 (greedy serving is the
         # common case and a missed draft costs one empty verify slot);
         # the engine-level default stays 0 so library users opt in.

@@ -53,6 +53,9 @@ from skypilot_tpu.parallel import ring_attention as ra
 
 Params = Dict[str, Any]
 
+# The serve programs of this family (``infer.kvcache.programs_for``).
+SERVE_PROGRAMS = "skypilot_tpu.infer.latent"
+
 # Rows at or below which the expert layer takes its few-row form (see
 # the module docstring); above it, sort + grouped products.
 DENSE_EXPERT_MAX_TOKENS = 64

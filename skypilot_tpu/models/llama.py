@@ -34,6 +34,9 @@ from jax import lax
 
 Params = Dict[str, Any]
 
+# The serve programs of this family (``infer.kvcache.programs_for``).
+SERVE_PROGRAMS = "skypilot_tpu.infer.kvcache"
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:

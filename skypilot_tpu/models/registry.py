@@ -11,10 +11,12 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
-from skypilot_tpu.models import glm_moe, llama
+from skypilot_tpu.models import glm_moe, llama, olmo_hybrid
 
-# Served decoder families, in lookup order.
-FAMILIES = (llama, glm_moe)
+# Served decoder families, in lookup order. Each module brings, besides
+# the three names above, ``SERVE_PROGRAMS``: the module that holds its
+# serve programs (``infer.kvcache.programs_for``).
+FAMILIES = (llama, glm_moe, olmo_hybrid)
 
 
 def serving_configs() -> Dict[str, Any]:
@@ -34,5 +36,10 @@ def get_config(name: str):
 
 
 def model_for(cfg):
-    """The model module of a config object."""
-    return glm_moe if isinstance(cfg, glm_moe.GlmMoeConfig) else llama
+    """The model module of a config object: the family whose
+    ``CONFIGS`` hold objects of its class (``llama`` for the subclasses
+    of its config that other model files define)."""
+    for module in FAMILIES[1:]:
+        if type(cfg) in {type(c) for c in module.CONFIGS.values()}:
+            return module
+    return llama

@@ -79,9 +79,9 @@ DEVTIME_EWMA_MS = metrics.gauge(
 HBM_BYTES = metrics.gauge(
     "skytpu_hbm_bytes",
     "Analytical HBM ledger: bytes each device-resident tensor family "
-    "holds (weights, kv_pool or latent_kv_pool, kv_used, draft_pool, "
-    "adapter_pool, prefix_pinned, workspace; expert_weights is the "
-    "routed experts' part of weights)",
+    "holds (weights, kv_pool or latent_kv_pool, recurrent_state, kv_used, "
+    "draft_pool, adapter_pool, prefix_pinned, workspace; expert_weights "
+    "is the routed experts' part of weights)",
     labelnames=("component",))
 HBM_LIMIT = metrics.gauge(
     "skytpu_hbm_limit_bytes",

@@ -366,6 +366,68 @@ class _Device:
         return self._stats
 
 
+@pytest.fixture(scope="module")
+def hybrid_engine_1period():
+    """The hybrid family at every published Olmo-Hybrid-7B width, depth
+    cut to one period (three linear layers + one full; weights are
+    shapes only; the cache is real: 15 slots — several tiles — of 8704
+    rows over a pool of 40 blocks, ~0.3 GB of host memory)."""
+    from skypilot_tpu.models import olmo_hybrid
+    cfg = dataclasses.replace(olmo_hybrid.CONFIGS["olmo-hybrid-7b"],
+                              n_layers=4)
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(cfg.dtype),
+        olmo_hybrid.init_params(jax.random.key(0), cfg)))
+    return eng.InferenceEngine(
+        params, cfg, n_slots=15, max_len=8704,
+        prompt_buckets=(128, 512, 8704), max_wave=4, pad_waves=True,
+        prefix_pool=0, spec_k=0, kv_blocks=40)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_chunk",
+                                     "admit_wave"])
+def test_hybrid_programs_compile_for_v5e(one_chip, hybrid_engine_1period,
+                                         program):
+    """The gated-delta-rule programs lower for the chip in plain XLA (no
+    Mosaic kernel), and what a slot holds is written IN PLACE: the K/V
+    pool of 32-head rows is not re-laid nor copied (with 30 heads
+    second-minor the compiler copied it whole, twice a tensor, in every
+    program), and the recurrent state is sliced and updated slot by slot
+    (a gather by slot id made the compiler slice the whole state —
+    every layer, every slot — before each read)."""
+    e = hybrid_engine_1period
+    params, _, cache, rng, table, S = _engine_args(e, one_chip)
+    i32 = S((), jnp.int32)
+    if program == "decode_burst":
+        lowered = e._decode_burst_fn.__wrapped__.lower(
+            params, cache, rng, S((e.n_slots + 1,), jnp.bool_), table,
+            k=4, qweights=None, span=None, kernel=False)
+    elif program == "prefill_chunk":
+        lowered = e._prefill_chunk_fn.__wrapped__.lower(
+            params, cache, S((512,), jnp.int32), i32, i32, i32, i32, rng,
+            table, final=True, qweights=None, span=4352, kernel=False)
+    else:
+        lowered = e._admit_wave_fn.__wrapped__.lower(
+            params, cache, S((4, 512), jnp.int32), S((4,), jnp.int32),
+            S((4,), jnp.int32), rng, table, bucket=512, qweights=None)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 0
+    held = sum(e.cache[n].nbytes for n in ("k", "v", "state", "conv"))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    assert e.cache["k"].shape[3] == 32          # 30 heads in whole tiles
+    for name, dtype in (("k", "bf16"), ("state", "f32")):
+        shape = ",".join(str(n) for n in e.cache[name].shape)
+        assert not re.search(rf"{dtype}\[{shape}\]\S* copy\(", text), \
+            f"the {name} tensor is copied"
+    # (the K/V gather's own slices, inside its fusion, are bf16)
+    assert not re.search(r"mini-gather-slice\S* = f32\[", text), \
+        "the state is gathered by slot id"
+    # Transients stay a fraction of what is resident at 16 layers (8.2
+    # GB of weights): the largest here is the head's 0.77 GB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+
+
 def test_peaks_table_is_keyed_by_device_kind():
     v5e = _Device("tpu", "TPU v5 lite")
     row = attribution.peaks_for(v5e)
