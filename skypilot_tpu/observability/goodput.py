@@ -251,16 +251,18 @@ class GoodputRecorder:
         self._phases = {}
 
     @contextlib.contextmanager
-    def phase(self, name: str, tokens: int = 0) -> Iterator[None]:
+    def phase(self, name: str, tokens: int = 0,
+              **counts) -> Iterator[None]:
         """Time one named slice of the open step. One ``with`` yields
         the ledger entry AND the ``train.*`` annotation on the device
         trace (``compute`` is the profiler's step marker, numbered by
         the open step). Disabled or outside a step only the ledger
         entry is skipped — the loop body never branches on recorder
-        state."""
+        state. ``counts`` are further arguments of the annotation."""
         if name not in _PHASE_BUCKET:
             raise ValueError(f"unknown step phase: {name}")
-        counts = {"tokens": tokens} if tokens else {}
+        if tokens:
+            counts["tokens"] = tokens
         with timeline.phase(
                 _PHASE_ANNOTATION.get(name, "train." + name),
                 step_num=self._step if name == "compute" else None,

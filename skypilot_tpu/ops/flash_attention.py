@@ -25,6 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_Q = 512
@@ -373,6 +374,10 @@ def _flash(q, k, v, segments, causal, block_q, block_k, interpret):
 def _flash_fwd(q, k, v, segments, causal, block_q, block_k, interpret):
     o, lse = _fwd(q, k, v, segments, causal=causal, block_q=block_q,
                   block_k=block_k, interpret=interpret)
+    # Named, so that a rematerialised caller whose policy saves both
+    # (train/qlora.py) runs this kernel once; inert under any other.
+    o = checkpoint_name(o, "flash_o")
+    lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, segments, o, lse)
 
 

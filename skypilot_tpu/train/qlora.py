@@ -24,14 +24,17 @@ examples/tpu/v6e/README.md §Train — the tok/s/chip benchmark class.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import optax
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.ops import flash_attention as fa
 from skypilot_tpu.train import trainer
 from skypilot_tpu.train.lora import LoRAConfig
 
@@ -63,6 +66,14 @@ def _lora_out(o, ab, scale):
     return scale * jnp.einsum("bsr,rd->bsd", u, ab["b"].astype(o.dtype))
 
 
+# What a kept layer holds for the backward pass instead of computing it
+# a second time: the outputs of six frozen-base products (all but
+# w_down's) and the flash forward's two residuals
+# (ops/flash_attention._flash_fwd names those).
+KEPT_NAMES = ("base_wq", "base_wk", "base_wv", "base_wo", "base_w_gate",
+              "base_w_up", "flash_o", "flash_lse")
+
+
 def _qdecoder_layer(cfg: llama.LlamaConfig, lc: LoRAConfig, x, qlayer,
                     norms, adapters, cos, sin, constrain, mesh, rules,
                     segment_ids):
@@ -74,7 +85,10 @@ def _qdecoder_layer(cfg: llama.LlamaConfig, lc: LoRAConfig, x, qlayer,
         # on the device timeline (forward, and backward through it).
         with jax.named_scope("base_matmul"):
             w = dequant_weight(qlayer[name], n_contract, dt)
-            return jnp.einsum(eq, h, w)
+            y = jnp.einsum(eq, h, w)
+        # A kept layer (forward_hidden) saves these by name; w_down's
+        # product is no residual of anything, so it has none.
+        return y if name == "w_down" else checkpoint_name(y, "base_" + name)
 
     h = llama.rms_norm(x, norms["ln1"], cfg.norm_eps)
     with jax.named_scope("attn"):
@@ -109,11 +123,14 @@ def _qdecoder_layer(cfg: llama.LlamaConfig, lc: LoRAConfig, x, qlayer,
 def forward_hidden(qweights: Params, fp_params: Params, adapters: Params,
                    tokens: jax.Array, cfg: llama.LlamaConfig,
                    lc: LoRAConfig, constrain=None, mesh=None, rules=None,
-                   positions=None, segment_ids=None) -> jax.Array:
+                   positions=None, segment_ids=None,
+                   n_keep: int = 0) -> jax.Array:
     """Token ids [B, S] -> final-norm hidden states, int8 base.
 
     ``fp_params`` is the slim tree (embed + norms, kvcache.slim_params
     layout); ``qweights["blocks"]`` the stacked int8 block weights.
+    The first ``n_keep`` layers keep ``KEPT_NAMES`` for the backward
+    pass; the rest recompute them (``layers_kept`` says how many fit).
     """
     if constrain is None:
         constrain = lambda x, axes: x
@@ -134,16 +151,35 @@ def forward_hidden(qweights: Params, fp_params: Params, adapters: Params,
     norms = {"ln1": fp_params["blocks"]["ln1"],
              "ln2": fp_params["blocks"]["ln2"]}
 
-    def body(carry, xs):
-        qlayer, norm, ab = xs
-        y = _qdecoder_layer(cfg, lc, carry, qlayer, norm, ab, cos, sin,
-                            constrain, mesh, layer_rules, segment_ids)
-        return y, None
+    blocks = qweights["blocks"]
 
-    if cfg.remat:
-        body = jax.checkpoint(body, policy=llama.remat_policy(cfg))
+    def run(x, lo, hi, policy):
+        """Layers lo..hi under one policy. A part of the stack picks its
+        frozen layers out of the whole by index: a scan over a SLICE of
+        the int8 stack would first copy the slice."""
+        if lo == hi:
+            return x
+        if (lo, hi) == (0, cfg.n_layers):
+            pick, xs = (lambda qlayer: qlayer), (blocks, norms, adapters)
+        else:
+            pick = lambda i: jax.tree.map(lambda a: a[i], blocks)
+            xs = (jnp.arange(lo, hi),) + jax.tree.map(
+                lambda a: a[lo:hi], (norms, adapters))
 
-    x, _ = lax.scan(body, x, (qweights["blocks"], norms, adapters))
+        def body(carry, xs):
+            which, norm, ab = xs
+            y = _qdecoder_layer(cfg, lc, carry, pick(which), norm, ab,
+                                cos, sin, constrain, mesh, layer_rules,
+                                segment_ids)
+            return y, None
+
+        if cfg.remat:
+            body = jax.checkpoint(body, policy=policy)
+        return lax.scan(body, x, xs)[0]
+
+    x = run(x, 0, n_keep,
+            jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+    x = run(x, n_keep, cfg.n_layers, llama.remat_policy(cfg))
     if use_zigzag:
         x = ra.zigzag_unpermute(x, n_sp)
     return llama.rms_norm(x, fp_params["final_norm"], cfg.norm_eps)
@@ -151,7 +187,8 @@ def forward_hidden(qweights: Params, fp_params: Params, adapters: Params,
 
 def loss_fn(qweights: Params, fp_params: Params, adapters: Params,
             batch: Dict[str, jax.Array], cfg: llama.LlamaConfig,
-            lc: LoRAConfig, constrain=None, mesh=None, rules=None):
+            lc: LoRAConfig, constrain=None, mesh=None, rules=None,
+            n_keep: int = 0):
     """Next-token cross-entropy off the int8 base + adapters."""
     if constrain is None:
         constrain = lambda x, axes: x
@@ -159,7 +196,8 @@ def loss_fn(qweights: Params, fp_params: Params, adapters: Params,
     h = forward_hidden(qweights, fp_params, adapters, tokens, cfg, lc,
                        constrain, mesh, rules,
                        positions=batch.get("positions"),
-                       segment_ids=batch.get("segment_ids"))
+                       segment_ids=batch.get("segment_ids"),
+                       n_keep=n_keep)
     # The head's dequantisation is a base matmul's too (the einsum that
     # consumes it sits in xent_metrics' chunk loop, under "xent").
     with jax.named_scope("base_matmul"):
@@ -170,35 +208,160 @@ def loss_fn(qweights: Params, fp_params: Params, adapters: Params,
     return loss, {"loss": loss, "accuracy": acc, "tokens": denom}
 
 
+# ---------------------------------------------------------------------------
+# How many layers keep their products: arithmetic on shapes and the
+# device's stated limit. No flag sets it, and bytes_in_use (which moves
+# from run to run) is never read: another n_keep is another program.
+# ---------------------------------------------------------------------------
+
+# Left free beside the step on the device, on top of a re-laid-stacks
+# term that errs high (the Mistral-7B step at 2 x 2048 on a v5e: 13
+# layers kept, 0.9 GB measured free; one layer more and the compiler
+# starts to trade time for memory: 1010 -> 1091 ms a step).
+MARGIN_BYTES = 512 * 2**20
+
+
+def kept_layer_bytes(cfg: llama.LlamaConfig, batch: int, seq: int) -> int:
+    """Bytes one kept layer holds for the backward pass (KEPT_NAMES):
+    six product outputs and the flash output in the compute dtype, and
+    the flash log-sum-exp as the kernel lays it out — float32,
+    replicated over the 128 lanes."""
+    tokens = batch * seq
+    heads, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    wide = (heads + 2 * kv      # wq, wk, wv
+            + heads             # flash_o
+            + cfg.d_model       # wo
+            + 2 * cfg.d_ff)     # w_gate, w_up
+    lse = batch * cfg.n_heads * seq * fa.LANES * 4
+    return tokens * wide * jnp.dtype(cfg.dtype).itemsize + lse
+
+
+def step_transient_bytes(cfg: llama.LlamaConfig, batch: int,
+                         seq: int) -> int:
+    """What the step with NOTHING kept holds beside its arguments, from
+    its shapes: every layer's input (the scan's residuals), the
+    dequantised head, and the larger of one layer's backward working
+    set (about two and a half kept layers' worth) and three float32
+    copies of a cross-entropy chunk's logits, which are never live
+    together. 2.39 GB where a v5e reserved 2.42 (Mistral-7B, 2 x 2048).
+    """
+    item = jnp.dtype(cfg.dtype).itemsize
+    layer_inputs = cfg.n_layers * batch * seq * cfg.d_model * item
+    head = cfg.d_model * cfg.vocab_size * item
+    logits = 3 * batch * (cfg.xent_chunk or seq) * cfg.vocab_size * 4
+    return layer_inputs + head + max(
+        5 * kept_layer_bytes(cfg, batch, seq) // 2, logits)
+
+
+def layers_kept(cfg: llama.LlamaConfig, batch: int, seq: int,
+                argument_bytes: int, limit_bytes: int) -> int:
+    """How many layers' KEPT_NAMES fit beside the step's arguments and
+    its own transients under the device's memory limit, less
+    MARGIN_BYTES. 0 where the device states no limit, where one layer
+    does not fit, or where ``cfg`` asks for another rematerialisation
+    than the default ("dots" already saves every product; ``remat``
+    off saves everything)."""
+    if not limit_bytes or not cfg.remat or cfg.remat_policy != "none":
+        return 0
+    free = (limit_bytes - MARGIN_BYTES - argument_bytes
+            - step_transient_bytes(cfg, batch, seq))
+    layer = kept_layer_bytes(cfg, batch, seq)
+    if free >= cfg.n_layers * layer:
+        return cfg.n_layers
+    # A part of the stack picks its int8 layers by index, and the
+    # compiler then re-lays the whole wq / wk / wv stacks once a step.
+    relaid = (cfg.n_layers * cfg.d_model * cfg.head_dim
+              * (cfg.n_heads + 2 * cfg.n_kv_heads))
+    return max(0, (free - relaid) // layer)
+
+
+def _tree_bytes(tree) -> int:
+    return sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(tree))
+
+
+def _limit_bytes() -> int:
+    """``bytes_limit`` of the device the step runs on (a constant of the
+    device kind); 0 where it states none, as the CPU."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 0))
+
+
+class QLoRAStep:
+    """step(state, qweights, fp_params, batch) -> (state, metrics): one
+    jitted program a batch shape, the first ``kept(...)["n_keep"]``
+    layers of which keep KEPT_NAMES for the backward pass."""
+
+    def __init__(self, build: Callable[[int], Callable],
+                 cfg: llama.LlamaConfig, n_keep: Optional[int]):
+        self._build, self._cfg, self._n_keep = build, cfg, n_keep
+        self._programs: Dict[tuple, tuple] = {}
+
+    def _program(self, *args):
+        shape = tuple(args[-1]["tokens"].shape)
+        if shape not in self._programs:
+            cfg, n_keep = self._cfg, self._n_keep
+            if n_keep is None:
+                n_keep = layers_kept(cfg, *shape, _tree_bytes(args),
+                                     _limit_bytes())
+            kept = {"n_keep": n_keep, "n_layers": cfg.n_layers,
+                    "kept_bytes": n_keep * kept_layer_bytes(cfg, *shape)}
+            self._programs[shape] = (self._build(n_keep), kept)
+        return self._programs[shape]
+
+    def kept(self, *args) -> Dict[str, int]:
+        """{"n_keep", "n_layers", "kept_bytes"} of the program the
+        step's arguments run (arrays or their ShapeDtypeStructs)."""
+        return dict(self._program(*args)[1])
+
+    def lower(self, *args):
+        return self._program(*args)[0].lower(*args)
+
+    def __call__(self, *args):
+        return self._program(*args)[0](*args)
+
+
 def make_qlora_train_step(cfg: llama.LlamaConfig, lc: LoRAConfig,
-                          tc: trainer.TrainConfig,
-                          mesh=None) -> Callable:
+                          tc: trainer.TrainConfig, mesh=None,
+                          n_keep: Optional[int] = None) -> QLoRAStep:
     """step(state, qweights, fp_params, batch) -> (state, metrics).
 
     The int8 base + slim fp tree are frozen inputs (no gradient, no
     donation); optimizer state exists only for the adapters.
     Single-chip oriented: the 8B bench's whole point is one 16 GB chip
     (multi-chip finetunes shard the fp base via train.lora instead).
+
+    As many layers as ``layers_kept`` finds room for keep their
+    frozen-base products and flash residuals instead of computing them
+    twice; under a mesh none does (the arithmetic is one chip's).
+    ``n_keep`` overrides the count, for tests and ahead-of-time
+    compiles; the step's ``kept(...)`` says what a program holds.
     """
     opt = trainer.make_optimizer(tc)
+    if mesh is not None and n_keep is None:
+        n_keep = 0
 
-    def step(state, qweights, fp_params, batch):
-        def lossf(adapters):
-            return loss_fn(qweights, fp_params, adapters, batch, cfg,
-                           lc, mesh=mesh)
+    def build(n_keep):
+        def step(state, qweights, fp_params, batch):
+            def lossf(adapters):
+                return loss_fn(qweights, fp_params, adapters, batch,
+                               cfg, lc, mesh=mesh, n_keep=n_keep)
 
-        (loss, metrics), grads = jax.value_and_grad(
-            lossf, has_aux=True)(state["params"])
-        with jax.named_scope("optimizer"):
-            updates, new_opt = opt.update(grads, state["opt_state"],
-                                          state["params"])
-            new_params = optax.apply_updates(state["params"], updates)
-            metrics = dict(metrics,
-                           grad_norm=optax.global_norm(grads))
-        return {"params": new_params, "opt_state": new_opt,
-                "step": state["step"] + 1}, metrics
+            (loss, metrics), grads = jax.value_and_grad(
+                lossf, has_aux=True)(state["params"])
+            with jax.named_scope("optimizer"):
+                updates, new_opt = opt.update(grads, state["opt_state"],
+                                              state["params"])
+                new_params = optax.apply_updates(state["params"],
+                                                 updates)
+                metrics = dict(metrics,
+                               grad_norm=optax.global_norm(grads))
+            return {"params": new_params, "opt_state": new_opt,
+                    "step": state["step"] + 1}, metrics
 
-    return jax.jit(step, donate_argnums=(0,))
+        return jax.jit(step, donate_argnums=(0,))
+
+    return QLoRAStep(build, cfg, n_keep)
 
 
 def create_qlora_state(cfg: llama.LlamaConfig, lc: LoRAConfig,

@@ -178,6 +178,7 @@ def main() -> None:
     mgr = None
     start_step = 0
     state = None
+    step_counts = {}    # further arguments of the train.step annotation
     if args.ckpt_dir:
         from skypilot_tpu.train import checkpoints
         mgr = checkpoints.CheckpointManager(args.ckpt_dir)
@@ -224,6 +225,14 @@ def main() -> None:
             state = qlora_lib.create_qlora_state(cfg, lc, tc)
         raw_step = qlora_lib.make_qlora_train_step(cfg, lc, tc)
         step_fn = lambda s, b: raw_step(s, qweights, fp_params, b)
+        kept = raw_step.kept(
+            state, qweights, fp_params,
+            {"tokens": jax.ShapeDtypeStruct((batch, args.seq), "int32")})
+        log("QLoRA keeps {n_keep} of {n_layers} layers' frozen-base "
+            "products and flash residuals for the backward pass "
+            "({kept_bytes:,} bytes, by the device's memory limit); the "
+            "rest compute them twice".format(**kept))
+        step_counts = {k: kept[k] for k in ("n_keep", "kept_bytes")}
     elif args.lora:
         from skypilot_tpu.train import lora as lora_lib
         lc = lora_lib.LoRAConfig(rank=args.lora)
@@ -310,7 +319,7 @@ def main() -> None:
         tokens = getattr(batch_data.get("tokens"), "size", 0) \
             if hasattr(batch_data, "get") else 0
         with sky_callback.step():
-            with gp.phase("compute", tokens=tokens):
+            with gp.phase("compute", tokens=tokens, **step_counts):
                 state, metrics = step_fn(state, batch_data)
         if step == start_step:
             # Every program the loop can reach is compiled now; from

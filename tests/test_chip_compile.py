@@ -136,6 +136,55 @@ def test_flash_kernels_compile_for_v5e(one_chip, which):
         assert n == 3
 
 
+# memory_stats()["bytes_limit"] of a v5e chip: 15.75 GiB, which is also
+# what its compiler refuses a program over (my chip runs, PR 39).
+V5E_LIMIT_BYTES = 16_909_336_064
+
+
+@pytest.mark.parametrize("more", ["as_kept", "a_margin_more"])
+def test_qlora_step_fits_a_v5e_at_mistral_7b(one_chip, monkeypatch, more):
+    """The whole QLoRA step — 32 layers at Mistral-7B widths, batch
+    2 x 2048, abstract arguments — with as many layers kept as
+    ``layers_kept`` finds room for under a v5e's limit: the compiler
+    accepts it, and accepts it with the stated margin's worth of layers
+    MORE (so an out-of-memory is that far away by the compiler's own
+    count of arguments and temporaries, which is what the chip reserves;
+    ``memory_analysis()`` reads ~1.7 GB over that)."""
+    from skypilot_tpu.train import lora as lora_lib
+    from skypilot_tpu.train import qlora, trainer
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, d_model=4096, n_layers=32, n_heads=32,
+        n_kv_heads=8, d_ff=14336, rope_theta=1e6, max_seq_len=32768,
+        xent_chunk=512)
+    lc = lora_lib.LoRAConfig(rank=16, alpha=32.0)
+    tc = trainer.TrainConfig()
+    batch, seq = 2, 2048
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    fp, qw = jax.eval_shape(lambda: kvcache.random_quantized_params(cfg))
+    state = jax.eval_shape(lambda: qlora.create_qlora_state(cfg, lc, tc))
+    args = place((state, qw, fp,
+                  {"tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32)}))
+    n_keep = qlora.layers_kept(cfg, batch, seq, qlora._tree_bytes(args),
+                               V5E_LIMIT_BYTES)
+    assert n_keep == 13
+    layer = qlora.kept_layer_bytes(cfg, batch, seq)
+    if more == "a_margin_more":
+        n_keep += -(-qlora.MARGIN_BYTES // layer)
+    step = qlora.make_qlora_train_step(cfg, lc, tc, n_keep=n_keep)
+    assert step.kept(*args)["kept_bytes"] == n_keep * layer
+    compiled = step.lower(*args).compile()
+    # flash forward of the kept layers, of the rest and of their second
+    # forward; two backward kernels in each of the two backward scans.
+    assert compiled.as_text().count("tpu_custom_call") == 7
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes
+               - qlora._tree_bytes(args)) < 2**20      # scalars pad
+    assert mem.temp_size_in_bytes > n_keep * layer
+
+
 @pytest.fixture(scope="module")
 def engine_8b_2layers():
     """The serve recipe's engine (w8a8, int8 KV, 32 slots, 1280) at
