@@ -507,7 +507,8 @@ class DraftEngine:
         active = np.zeros((self.n_slots + 1,), bool)
         active[spare] = True
         active_dev = jnp.asarray(active)
-        with metrics.suppress():
+        with flight_lib.STARTUP.phase("warm_grid.draft"), \
+                metrics.suppress():
             for span in self.span_ladder:
                 sarg = self._span_arg(span)
                 for kk in sorted({k, k + 1}):
@@ -530,12 +531,7 @@ class DraftEngine:
                 zeros, zeros)
             self.cache["length"] = jnp.zeros_like(self.cache["length"])
         self.compile_watch.drain_new()
-        summ = self.compile_watch.summary()
-        for key in summ:
-            if key not in pre_keys:
-                flight_lib.COMPILE_SECONDS.labels(
-                    program=key).observe(summ[key])
-                flight_lib.PROGRAMS_COMPILED.inc()
+        self.compile_watch.republish(pre_keys)
         return self.compile_watch.count - before
 
     def declare_warmup_complete(self) -> None:

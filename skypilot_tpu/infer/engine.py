@@ -1699,7 +1699,11 @@ class InferenceEngine:
         active_dev = jnp.asarray(active)
         spans = [self._span_arg(s) for s in self.span_ladder]
         lora_kw = self._lora_args()
-        with metrics.suppress():
+        # One start-up phase a program family (a child of the server's
+        # ``startup.warm_grid``); each closes outside the suppression
+        # its sweep runs under, so its gauge is kept.
+        family = flight_lib.STARTUP.phase
+        with family("warm_grid.decode"), metrics.suppress():
             for sarg in spans:
                 self.cache, self.rng, _ = self._decode_fn(
                     self.params, self.cache, self.rng, active_dev,
@@ -1722,8 +1726,10 @@ class InferenceEngine:
                         active_dev, self.table_device(), k=self.spec_k,
                         qweights=self.qweights, span=sarg,
                         kernel=self.kv_kernel, **lora_kw)
-                if self.prefill_chunk:
-                    chunk = jnp.zeros((self.prefill_chunk,), jnp.int32)
+        with family("warm_grid.chunk"), metrics.suppress():
+            if self.prefill_chunk:
+                chunk = jnp.zeros((self.prefill_chunk,), jnp.int32)
+                for sarg in spans:
                     for final in (False, True):
                         self.cache, self.rng, _ = \
                             self._prefill_chunk_fn(
@@ -1736,6 +1742,7 @@ class InferenceEngine:
                                 final=final, qweights=self.qweights,
                                 span=sarg, kernel=self.kv_kernel,
                                 **lora_kw)
+        with family("warm_grid.wave"), metrics.suppress():
             # Admission waves: pad_waves pins every wave at max_wave
             # rows, so one program per bucket suffices. Unpadded
             # engines pad each wave to the next power of two of its
@@ -1774,6 +1781,7 @@ class InferenceEngine:
                         jnp.asarray(slot_ids), self.rng,
                         self.table_device(), bucket=bucket,
                         qweights=self.qweights, **wave_lora)
+        with family("warm_grid.small"), metrics.suppress():
             # The admission path's small gather/scatter programs.
             claim_len = jnp.asarray(self.max_len, jnp.int32)
             self.cache = self._claim_fn(
@@ -1809,17 +1817,15 @@ class InferenceEngine:
             # Scrub: zero the length bookkeeping — the sweep's data
             # rows are dead without a length exposing them.
             self.cache["length"] = jnp.zeros_like(self.cache["length"])
+            # The grid is warm when its first executions have run, not
+            # when the last of them is queued.
+            jax.block_until_ready((self.cache, self.pool))
         self.compile_watch.drain_new()   # not any burst's to claim
         # Republish the sweep's compile metrics OUTSIDE suppress: the
         # wrapper's increments were discarded inside it, but "programs
         # compiled on this replica" must mirror the watch registry —
         # or a warm-grid fleet would read `compiles 0` on skytpu top.
-        summ = self.compile_watch.summary()
-        for key in summ:
-            if key not in pre_keys:
-                flight_lib.COMPILE_SECONDS.labels(
-                    program=key).observe(summ[key])
-                flight_lib.PROGRAMS_COMPILED.inc()
+        self.compile_watch.republish(pre_keys)
         n = self.compile_watch.count - before
         if self.spec_k and self.draft_engine is not None:
             # The drafter's grid (rollouts at K and K+1 per span rung,

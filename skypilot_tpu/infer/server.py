@@ -1178,6 +1178,11 @@ def serve(engine, host: str = "0.0.0.0", port: int = 8080,
 
 
 def _main() -> None:
+    # Start-up goes on the record phase by phase (flight.STARTUP): the
+    # first phase opened stamps ``before_main`` — process start to here —
+    # and installs the compile ledger; ``server.listening`` carries the
+    # whole account (docs/observability.md §Start-up).
+    startup = flight_lib.STARTUP
     compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="llama3-400m")
@@ -1338,12 +1343,17 @@ def _main() -> None:
     os.environ.pop(tracing.ENV_VAR, None)
     tracing.set_process_name("model-server")
 
-    import jax
+    with startup.phase("imports"):
+        import jax
+
+        from skypilot_tpu.infer import adapters as ad_lib
+        from skypilot_tpu.infer import draft as draft_lib
+        from skypilot_tpu.infer import engine as eng, kvcache, sampling
+        from skypilot_tpu.models import registry
+    with startup.phase("backend"):
+        devices = jax.devices()    # the backend starts here
     if args.profiler_port:
         jax.profiler.start_server(args.profiler_port)
-
-    from skypilot_tpu.infer import engine as eng, kvcache, sampling
-    from skypilot_tpu.models import registry
 
     try:
         cfg = registry.get_config(args.config)
@@ -1365,7 +1375,6 @@ def _main() -> None:
     if args.tp > 1:
         import numpy as np
         from jax.sharding import Mesh
-        devices = jax.devices()
         if len(devices) < args.tp:
             raise SystemExit(f"--tp {args.tp} needs {args.tp} devices, "
                              f"found {len(devices)}")
@@ -1374,8 +1383,10 @@ def _main() -> None:
     # int8 without the float tree they would quantize from, float in
     # the compute dtype, sharded at init under --tp. A tree that
     # cannot fit is a typed start-up error naming the bytes.
-    params, qweights = eng.random_serving_weights(
-        cfg, weights_int8=args.weights_int8, mesh=mesh)
+    with startup.phase("weights"):
+        params, qweights = jax.block_until_ready(
+            eng.random_serving_weights(
+                cfg, weights_int8=args.weights_int8, mesh=mesh))
     # "--span-buckets 0" disables bucketing; a comma list is an
     # explicit ladder; unset falls through to the engine default /
     # SKYTPU_SPAN_BUCKETS.
@@ -1384,76 +1395,72 @@ def _main() -> None:
         rungs = [int(t) for t in
                  args.span_buckets.replace(",", " ").split()]
         span_buckets = [r for r in rungs if r > 0] or 0
-    # Multi-LoRA adapter catalog (docs/serving.md §Adapter catalog):
-    # a JSON {name: checkpoint path} names the replica's fine-tunes;
-    # loading to device is on demand (the first request naming one
-    # pays the hot-load). None = the zero-cost adapterless engine.
-    from skypilot_tpu.infer import adapters as ad_lib
-    catalog = ad_lib.catalog_from_env(cfg, adapters_json=args.adapters,
-                                      slots=args.adapter_slots,
-                                      rank=args.adapter_rank)
-    # Model-backed drafter (docs/serving.md §Speculative decoding): a
-    # 'self:N' draft shares the target's first N blocks (float or
-    # int8) by reference. None = the n-gram drafter stays the only
-    # rung.
-    from skypilot_tpu.infer import draft as draft_lib
-    draft_engine = draft_lib.draft_engine_from_env(
-        params, cfg, n_slots=args.slots, max_len=args.max_len,
-        spec=args.draft_model, kv_int8=args.kv_int8,
-        qweights=qweights)
-    engine = eng.InferenceEngine(
-        params, cfg, n_slots=args.slots, max_len=args.max_len,
-        mesh=mesh,
-        prompt_buckets=(128, min(512, args.max_len),
-                        args.max_len),
-        sampling_params=sampling.SamplingParams(
-            temperature=args.temperature),
-        kv_int8=args.kv_int8, qweights=qweights,
-        max_wave=args.admit_wave,
-        prefill_chunk=args.prefill_chunk,
-        kv_block=args.kv_block, kv_blocks=args.kv_blocks,
-        span_buckets=span_buckets, kv_lazy=args.kv_lazy,
-        kv_kernel=args.kv_kernel,
-        # Serving default: prefix reuse ON (repeated system prompts are
-        # the common serving workload); the engine-level default stays
-        # 0 so library users opt in. A family whose blocks cannot be
-        # shared defaults to 0 and refuses more, by name.
-        prefix_pool=(args.prefix_pool
-                     if args.prefix_pool is not None
-                     else int(os.environ.get(
-                         "SKYTPU_PREFIX_POOL",
-                         "0" if "prefix_pool" in progs.UNSUPPORTED
-                         else "8") or 0)),
-        # Serving default: speculation ON at K=4 (greedy serving is the
-        # common case and a missed draft costs one empty verify slot);
-        # the engine-level default stays 0 so library users opt in.
-        spec_k=(args.spec_k
-                if args.spec_k is not None
-                else int(os.environ.get(
-                    "SKYTPU_SPEC_K", "4" if can_verify else "0") or 0)),
-        draft_engine=draft_engine,
-        spec_pipeline=(bool(args.spec_pipeline)
-                       if args.spec_pipeline is not None else None),
-        # One compiled prefill program per bucket: an odd wave size
-        # must never hit a mid-traffic XLA compile on a live replica.
-        pad_waves=True,
-        # Multi-tenant QoS (SKYTPU_QOS=1): WFQ + priority lanes in the
-        # engine's waiting deque. All host-side — tenant count never
-        # enters program identity (the compile watch is the gate).
-        qos=qos_lib.scheduler_from_env(),
-        adapters=catalog)
+    with startup.phase("engine_init"):     # cache, pools, tables
+        # Multi-LoRA adapter catalog (docs/serving.md §Adapter catalog):
+        # a JSON {name: checkpoint path} names the replica's fine-tunes;
+        # loading to device is on demand (the first request naming one
+        # pays the hot-load). None = the zero-cost adapterless engine.
+        catalog = ad_lib.catalog_from_env(cfg, adapters_json=args.adapters,
+                                          slots=args.adapter_slots,
+                                          rank=args.adapter_rank)
+        # Model-backed drafter (docs/serving.md §Speculative decoding): a
+        # 'self:N' draft shares the target's first N blocks (float or
+        # int8) by reference. None = the n-gram drafter stays the only
+        # rung.
+        draft_engine = draft_lib.draft_engine_from_env(
+            params, cfg, n_slots=args.slots, max_len=args.max_len,
+            spec=args.draft_model, kv_int8=args.kv_int8,
+            qweights=qweights)
+        engine = eng.InferenceEngine(
+            params, cfg, n_slots=args.slots, max_len=args.max_len,
+            mesh=mesh,
+            prompt_buckets=(128, min(512, args.max_len),
+                            args.max_len),
+            sampling_params=sampling.SamplingParams(
+                temperature=args.temperature),
+            kv_int8=args.kv_int8, qweights=qweights,
+            max_wave=args.admit_wave,
+            prefill_chunk=args.prefill_chunk,
+            kv_block=args.kv_block, kv_blocks=args.kv_blocks,
+            span_buckets=span_buckets, kv_lazy=args.kv_lazy,
+            kv_kernel=args.kv_kernel,
+            # Serving default: prefix reuse ON (repeated system prompts are
+            # the common serving workload); the engine-level default stays
+            # 0 so library users opt in. A family whose blocks cannot be
+            # shared defaults to 0 and refuses more, by name.
+            prefix_pool=(args.prefix_pool
+                         if args.prefix_pool is not None
+                         else int(os.environ.get(
+                             "SKYTPU_PREFIX_POOL",
+                             "0" if "prefix_pool" in progs.UNSUPPORTED
+                             else "8") or 0)),
+            # Serving default: speculation ON at K=4 (greedy serving is the
+            # common case and a missed draft costs one empty verify slot);
+            # the engine-level default stays 0 so library users opt in.
+            spec_k=(args.spec_k
+                    if args.spec_k is not None
+                    else int(os.environ.get(
+                        "SKYTPU_SPEC_K", "4" if can_verify else "0") or 0)),
+            draft_engine=draft_engine,
+            spec_pipeline=(bool(args.spec_pipeline)
+                           if args.spec_pipeline is not None else None),
+            # One compiled prefill program per bucket: an odd wave size
+            # must never hit a mid-traffic XLA compile on a live replica.
+            pad_waves=True,
+            # Multi-tenant QoS (SKYTPU_QOS=1): WFQ + priority lanes in the
+            # engine's waiting deque. All host-side — tenant count never
+            # enters program identity (the compile watch is the gate).
+            qos=qos_lib.scheduler_from_env(),
+            adapters=catalog)
+        jax.block_until_ready((engine.cache, engine.pool))
     if args.warm_grid:
         # Compile the whole program grid BEFORE /health can flip, then
         # arm the compile watch: from here on, a new program compiling
         # under live traffic is an alarm, not tens of silent seconds
         # of TPOT (docs/observability.md §Flight recorder).
-        t0 = time.time()
-        n = engine.warm_programs(max_burst=args.max_burst)
-        engine.declare_warmup_complete()
-        tracing.add_event(
-            "server.programs_warmed",
-            {"programs": n,
-             "warm_s": round(time.time() - t0, 2)}, echo=True)
+        with startup.phase("warm_grid"):
+            engine.warm_programs(max_burst=args.max_burst)
+            engine.declare_warmup_complete()
     # Startup leaves some hundreds of thousands of live objects behind
     # (JAX itself, every traced program). A full collection walks them
     # all: ~0.1 s with the loop thread stopped, and WHERE it lands is an
@@ -1462,17 +1469,23 @@ def _main() -> None:
     # cell's TTFT p95 by 13 % (PERF.md §6, PR 25). Park them in the
     # permanent generation: later collections see only what serving
     # allocates.
-    gc.collect()
-    gc.freeze()
-    model, httpd = serve(engine, port=args.port,
-                         max_burst=args.max_burst,
-                         open_burst=args.open_burst,
-                         open_window_s=args.open_window,
-                         coalesce_s=args.coalesce,
-                         qos=qos_lib.admission_from_env("server"))
+    with startup.phase("gc_freeze"):
+        gc.collect()
+        gc.freeze()
+    with startup.phase("listen"):
+        model, httpd = serve(engine, port=args.port,
+                             max_burst=args.max_burst,
+                             open_burst=args.open_burst,
+                             open_window_s=args.open_window,
+                             coalesce_s=args.coalesce,
+                             qos=qos_lib.admission_from_env("server"))
+    watches = [engine.compile_watch]
+    if draft_engine is not None:
+        watches.append(draft_engine.compile_watch)
     tracing.add_event("server.listening",
                       {"port": args.port,
-                       "device": attribution.device_report()},
+                       "device": attribution.device_report(),
+                       "startup": startup.report(watches)},
                       echo=True)
     try:
         httpd.serve_forever()

@@ -27,6 +27,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.observability import flight
 from skypilot_tpu.parallel import sharding as sh
 from skypilot_tpu.train import trainer
 
@@ -161,10 +162,14 @@ def create_lora_state(cfg: llama.LlamaConfig, lc: LoRAConfig,
     opt = trainer.make_optimizer(tc)
     init_fn = _state_init_fn(cfg, lc, opt)
     rng = jax.random.key(seed)
-    if mesh is None:
-        return jax.jit(init_fn)(rng)
-    shardings = lora_state_shardings(cfg, lc, tc, mesh)
-    return jax.jit(init_fn, out_shardings=shardings)(rng)
+    # Start-up phase ``state``, as trainer.create_train_state keeps it.
+    with flight.STARTUP.phase("state"):
+        if mesh is None:
+            build = jax.jit(init_fn)
+        else:
+            build = jax.jit(init_fn, out_shardings=lora_state_shardings(
+                cfg, lc, tc, mesh))
+        return jax.block_until_ready(build(rng))
 
 
 def base_param_shardings(cfg: llama.LlamaConfig, mesh: Mesh, model=llama):
@@ -185,6 +190,7 @@ def make_lora_train_step(cfg: llama.LlamaConfig, lc: LoRAConfig,
     base_params are a frozen input (no gradient, no donation): the same
     base tree serves every step. Pass ``base_sh`` if already computed.
     """
+    flight.COMPILES.install()
     opt = trainer.make_optimizer(tc)
     constrain = sh.make_constrain(mesh, act_rules)
 

@@ -34,6 +34,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.observability import flight
 from skypilot_tpu.ops import flash_attention as fa
 from skypilot_tpu.train import trainer
 from skypilot_tpu.train.lora import LoRAConfig
@@ -337,6 +338,7 @@ def make_qlora_train_step(cfg: llama.LlamaConfig, lc: LoRAConfig,
     ``n_keep`` overrides the count, for tests and ahead-of-time
     compiles; the step's ``kept(...)`` says what a program holds.
     """
+    flight.COMPILES.install()    # the step's compile goes on the ledger
     opt = trainer.make_optimizer(tc)
     if mesh is not None and n_keep is None:
         n_keep = 0

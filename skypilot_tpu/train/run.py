@@ -104,18 +104,26 @@ def main() -> None:
     # Multi-host: join the cluster-wide jax.distributed rendezvous using
     # the runtime's env contract (runtime/constants.py) before touching
     # devices. Multislice (MEGASCALE_*) is consumed by libtpu directly.
-    from skypilot_tpu.parallel.distributed import initialize_from_env
-    initialize_from_env()
+    # Start-up goes on the record phase by phase (flight.STARTUP), as
+    # the model server's does; ``train.ready`` carries the account once
+    # the first step has run (docs/observability.md §Start-up).
+    from skypilot_tpu.observability import flight, tracing
+    startup = flight.STARTUP
+    with startup.phase("imports"):
+        from skypilot_tpu.parallel.distributed import initialize_from_env
+        initialize_from_env()
 
-    import jax
+        import jax
+
+        import skypilot_tpu.callbacks as sky_callback
+        from skypilot_tpu.parallel import mesh as mesh_lib
+        from skypilot_tpu.parallel import sharding as sh_rules
+        from skypilot_tpu.train import trainer
+        from skypilot_tpu.utils import timeline
+    with startup.phase("backend"):
+        jax.devices()              # the backend starts here
     if args.profiler_port:
         jax.profiler.start_server(args.profiler_port)
-
-    import skypilot_tpu.callbacks as sky_callback
-    from skypilot_tpu.parallel import mesh as mesh_lib
-    from skypilot_tpu.parallel import sharding as sh_rules
-    from skypilot_tpu.train import trainer
-    from skypilot_tpu.utils import timeline
 
     if args.model == "llama":
         from skypilot_tpu.models import llama as model
@@ -163,7 +171,7 @@ def main() -> None:
     # over the losses the logging cadence fetches anyway. The roofline
     # peak is published here so skytpu top's train MFU has its
     # denominator in a train-only process.
-    from skypilot_tpu.observability import attribution, flight
+    from skypilot_tpu.observability import attribution
     from skypilot_tpu.observability import goodput as goodput_lib
     peak_f, peak_bw = attribution.device_peaks()
     attribution.ROOFLINE_PEAK_FLOPS.set(peak_f * n)
@@ -198,18 +206,21 @@ def main() -> None:
         from skypilot_tpu.train import lora as lora_lib
         from skypilot_tpu.train import qlora as qlora_lib
         lc = lora_lib.LoRAConfig(rank=args.qlora)
-        if args.qlora_random_base:
-            fp_params, qweights = kvcache.random_quantized_params(cfg)
-        else:
-            base = jax.jit(
-                lambda r: model.init_params(r, cfg))(jax.random.key(1))
-            qweights = {
-                "blocks": jax.jit(kvcache.quantize_block_weights)(base),
-                "head": jax.jit(
-                    lambda p: kvcache.quantize_head(p, cfg))(base),
-            }
-            fp_params = kvcache.slim_params(base)
-            del base   # the int8 copy replaces the fp block weights
+        with startup.phase("weights"):       # the frozen int8 base
+            if args.qlora_random_base:
+                fp_params, qweights = kvcache.random_quantized_params(cfg)
+            else:
+                base = jax.jit(
+                    lambda r: model.init_params(r, cfg))(jax.random.key(1))
+                qweights = {
+                    "blocks": jax.jit(
+                        kvcache.quantize_block_weights)(base),
+                    "head": jax.jit(
+                        lambda p: kvcache.quantize_head(p, cfg))(base),
+                }
+                fp_params = kvcache.slim_params(base)
+                del base   # the int8 copy replaces the fp block weights
+            jax.block_until_ready((fp_params, qweights))
         log(f"QLoRA rank {args.qlora}: "
             f"{lora_lib.num_trainable_params(cfg, lc):,} trainable over "
             f"an int8 base of {cfg.num_params():,} params")
@@ -237,9 +248,10 @@ def main() -> None:
         from skypilot_tpu.train import lora as lora_lib
         lc = lora_lib.LoRAConfig(rank=args.lora)
         base_sh = lora_lib.base_param_shardings(cfg, mesh, model)
-        base_params = jax.jit(
-            lambda r: model.init_params(r, cfg),
-            out_shardings=base_sh)(jax.random.key(1))
+        with startup.phase("weights"):       # the frozen float base
+            base_params = jax.block_until_ready(jax.jit(
+                lambda r: model.init_params(r, cfg),
+                out_shardings=base_sh)(jax.random.key(1)))
         log(f"LoRA rank {args.lora}: "
             f"{lora_lib.num_trainable_params(cfg, lc):,} trainable / "
             f"{cfg.num_params():,} base params (frozen)")
@@ -320,11 +332,22 @@ def main() -> None:
             if hasattr(batch_data, "get") else 0
         with sky_callback.step():
             with gp.phase("compute", tokens=tokens, **step_counts):
-                state, metrics = step_fn(state, batch_data)
+                if step == start_step:
+                    # The step that compiles (goodput books it to
+                    # warmup_compile), held to its end on the device.
+                    with startup.phase("first_step"):
+                        state, metrics = jax.block_until_ready(
+                            step_fn(state, batch_data))
+                else:
+                    state, metrics = step_fn(state, batch_data)
         if step == start_step:
             # Every program the loop can reach is compiled now; from
             # here a new key is a mid-run retrace worth alarming on.
             watch.declare_warm()
+            tracing.add_event(
+                "train.ready",
+                {"device": attribution.device_report(),
+                 "startup": startup.report([watch])}, echo=True)
         loss = grad_norm = None
         if (step + 1) % args.log_every == 0 or step + 1 == args.steps:
             with gp.phase("eval"):

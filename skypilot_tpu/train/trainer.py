@@ -31,6 +31,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.observability import flight
 from skypilot_tpu.observability import metrics as obs_metrics
 from skypilot_tpu.observability import tracing
 from skypilot_tpu.parallel import sharding as sh
@@ -205,10 +206,15 @@ def create_train_state(cfg: llama.LlamaConfig, tc: TrainConfig,
                             jnp.zeros((), jnp.int32))
 
     rng = jax.random.key(seed)
-    if mesh is None:
-        return jax.jit(init_fn)(rng)
-    shardings = state_shardings(cfg, mesh, rules, model)
-    return jax.jit(init_fn, out_shardings=shardings)(rng)
+    # Start-up phase ``state``: to the state's arrival on the device(s),
+    # not to its dispatch (docs/observability.md §Start-up).
+    with flight.STARTUP.phase("state"):
+        if mesh is None:
+            build = jax.jit(init_fn)
+        else:
+            build = jax.jit(init_fn, out_shardings=state_shardings(
+                cfg, mesh, rules, model))
+        return jax.block_until_ready(build(rng))
 
 
 def create_abstract_state(cfg: llama.LlamaConfig, tc: TrainConfig,
@@ -262,6 +268,7 @@ def make_train_step(cfg: llama.LlamaConfig, tc: TrainConfig,
     as a program, and a post-warmup retrace emits the typed event the
     goodput ledger and SLO watchdog alarm on.
     """
+    flight.COMPILES.install()    # the step's compile goes on the ledger
     opt = make_optimizer(tc)
     constrain = sh.make_constrain(mesh, act_rules)
 
