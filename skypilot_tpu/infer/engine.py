@@ -309,6 +309,10 @@ class BurstHandle:
     # Burst sequence number: the dispatch and the fetch annotations of
     # one burst carry it, so a trace reader pairs them exactly.
     seq: int = 0
+    # Blocks the burst's slots held at dispatch, where the family's
+    # program reads by residency (``_family_notes``): the completion
+    # record carries it beside ``tiles``.
+    kv_blocks: Optional[int] = None
 
 
 class PromptTooLongError(ValueError):
@@ -1526,11 +1530,24 @@ class InferenceEngine:
             return 0
         return -(-n_live // kvcache.TILE)
 
-    def _state_rows(self, slots) -> Dict[str, int]:
-        """The decode dispatch annotation's ``state_rows``: the slots
-        whose per-slot state the program updates (a family without one
+    def _family_notes(self, slots) -> Dict[str, int]:
+        """What the family's module adds to a decode dispatch annotation
+        (host arithmetic over what the round already holds):
+        ``state_rows`` — the slots whose per-slot state the program
+        updates (a family without one says nothing) — and ``kv_blocks``
+        — the blocks that hold the round's slots' rows at its start,
+        which a program whose K/V read is bounded by residency visits a
+        layer (``tiles * TILE * ceil(span / kv_block)`` is what the rung
+        alone would make it read; a family whose read is not bounded
         says nothing)."""
-        return {"state_rows": len(slots)} if self._progs.SLOT_STATE else {}
+        notes = {}
+        if self._progs.SLOT_STATE:
+            notes["state_rows"] = len(slots)
+        if self._progs.DECODE_READS_BLOCKS_HELD:
+            notes["kv_blocks"] = sum(
+                -(-self._slot_rows(self.slot_req[s]) // self.kv_block)
+                for s in slots)
+        return notes
 
     def _record_flight(self, burst: str, begin_s: float, end_s: float,
                        program: Dict[str, Any], slots, reqs,
@@ -1542,7 +1559,8 @@ class InferenceEngine:
                        dev_keys: Optional[List[Optional[str]]] = None,
                        calibrator: Optional[
                            attribution_lib.DeviceTimeCalibrator]
-                       = None) -> None:
+                       = None,
+                       kv_blocks: Optional[int] = None) -> None:
         """Append one burst record to the flight recorder. HOST
         bookkeeping only — every value here already lives on the host
         (request lists, ints, floats); a device fetch on this path
@@ -1574,6 +1592,8 @@ class InferenceEngine:
         extra: Dict[str, Any] = {}
         if burst in ("decode", "verify", "decode1"):
             extra["tiles"] = self._tiles(burst, len(slots))
+            if kv_blocks is not None:
+                extra["kv_blocks"] = kv_blocks
         if stall:
             extra["stall"] = True
         if drafted:
@@ -3771,12 +3791,13 @@ class InferenceEngine:
         # The program's k steps run at ``rows`` batch rows of which
         # ``slots`` are live, ``promoted`` of them above their own rung;
         # a layer reads and attends the live ones in ``tiles`` turns.
+        notes = self._family_notes(slots)
         with timeline.phase(
                 "engine.decode.dispatch", seq=self._burst_seq, k=k,
                 slots=len(slots), rows=self.n_slots + 1,
                 tiles=self._tiles("decode", len(slots)),
                 span=attn_span, promoted=promoted, why=why,
-                waiting=len(self.waiting), **self._state_rows(slots)):
+                waiting=len(self.waiting), **notes):
             self.cache, self.rng, toks = self._decode_burst_fn(
                 self.params, self.cache, self.rng,
                 jnp.asarray(active), self.table_device(), k=k,
@@ -3788,7 +3809,8 @@ class InferenceEngine:
                            span_arg=sarg,
                            key=self.compile_watch.last_key,
                            dispatch_done_s=time.time(),
-                           seq=self._burst_seq)
+                           seq=self._burst_seq,
+                           kv_blocks=notes.get("kv_blocks"))
 
     def complete_decode_burst(self, handle: "BurstHandle"
                               ) -> Dict[int, List[int]]:
@@ -3855,7 +3877,8 @@ class InferenceEngine:
             "decode", begin_s=begin_s, end_s=end_s,
             program={"k": handle.k, "span": handle.span_arg},
             slots=handle.slots, reqs=live_reqs, toks=n_emitted,
-            dispatch_s=handle.dispatch_done_s, dev_keys=[handle.key])
+            dispatch_s=handle.dispatch_done_s, dev_keys=[handle.key],
+            kv_blocks=handle.kv_blocks)
         return out, n_emitted
 
     def step_decode_once(self) -> Dict[int, int]:
@@ -3883,12 +3906,13 @@ class InferenceEngine:
                             histogram=DECODE_STEP_SECONDS)
         ev.begin()
         self._burst_seq += 1
+        notes = self._family_notes(slots)
         with timeline.phase(
                 "engine.decode.dispatch", seq=self._burst_seq, k=1,
                 slots=len(slots), rows=self.n_slots + 1,
                 tiles=self._tiles("decode1", len(slots)),
                 span=attn_span, promoted=promoted, why="step",
-                waiting=len(self.waiting), **self._state_rows(slots)):
+                waiting=len(self.waiting), **notes):
             self.cache, self.rng, toks = self._decode_fn(
                 self.params, self.cache, self.rng, jnp.asarray(active),
                 self.table_device(), qweights=self.qweights, span=sarg,
@@ -3916,7 +3940,8 @@ class InferenceEngine:
             "decode1", begin_s=ev.begin_s, end_s=time.time(),
             program={"k": 1, "span": sarg},
             slots=slots, reqs=step_reqs, toks=len(out),
-            dispatch_s=t_disp, dev_keys=[step_key])
+            dispatch_s=t_disp, dev_keys=[step_key],
+            kv_blocks=notes.get("kv_blocks"))
         return out
 
     def run_to_completion(self, max_burst: int = 8) -> List[Request]:

@@ -36,7 +36,11 @@ The full layers' decode attention reads a live slot's resident rows IN
 PLACE, a block a turn (:func:`_attend_in_place`: once for the scores,
 once for the weighted values), where the other families gather a copy
 first: with 30 key/value heads a slot's rows are 16 KB a token and layer,
-and copying them cost more than attending them. A prefill chunk's 512
+and copying them cost more than attending them. The read is bounded by
+RESIDENCY (``DECODE_READS_BLOCKS_HELD``): a live slot's blocks up to the
+rows it holds and a dead slot's not at all, whatever the program's span
+rung — one long request sets the rung for every slot of the round, and
+a tile is padded to whole slots. A prefill chunk's 512
 query rows attend a gathered copy of the one slot's rows
 (:func:`_gather_kv`, in pieces of 1 MiB).
 
@@ -67,6 +71,7 @@ SPARE_COLUMN = None
 # This family's answers to the engine (``kvcache.programs_for``).
 FAMILY = "hybrid (recurrent state + paged KV)"
 SLOT_STATE = ("state", "conv")
+DECODE_READS_BLOCKS_HELD = True
 _NO_BOUNDARY = ("a shared block holds K/V rows but no recurrent state at "
                 "its boundary")
 UNSUPPORTED = {
@@ -251,6 +256,14 @@ def insert(cache: Cache, prefix: Cache, slot, true_len, first_token,
 _GATHER_WINDOW_BYTES = 1 << 20
 
 
+def _span_blocks(cache: Cache, table_rows, span) -> int:
+    """Blocks of a slot's table row that cover its first ``span`` logical
+    rows (all of them without a span)."""
+    if span is None:
+        return table_rows.shape[1] - 1
+    return -(-span // cache["k"].shape[2])
+
+
 @jax.named_scope("kv_gather")
 def _gather_kv(cfg, cache: Cache, fi, table_rows, span):
     """Full layer ``fi``'s K and V of the slots whose table rows are
@@ -259,83 +272,88 @@ def _gather_kv(cfg, cache: Cache, fi, table_rows, span):
     ``kvcache._gather_kv_layer``'s read (whole blocks of the table
     prefix straight out of the pool seen flat, then cut to the span),
     with each block fetched in PIECES of at most
-    :data:`_GATHER_WINDOW_BYTES` and cut to the heads that are real. (A
-    sentinel id gathers the next layer's first block, or clamps: garbage
-    the caller's mask never admits.)"""
-    flat, at, piece = _pool_pieces(cache, fi, table_rows, span,
-                                   _GATHER_WINDOW_BYTES)
-    rows = at.shape[1] * piece if span is None else span
-    return [pool[at].reshape(at.shape[0], at.shape[1] * piece,
-                             *pool.shape[2:])[:, :rows, :cfg.n_kv_heads]
-            for pool in flat]
-
-
-def _pool_pieces(cache: Cache, fi, table_rows, span, window_bytes=None):
-    """How :func:`_gather_kv` and :func:`_attend_in_place` address full
-    layer ``fi``'s rows of the slots ``table_rows`` [T, nb + 1]: the K
-    and V pools seen flat in PIECES ``[L * blocks * parts, piece, heads,
-    hd]`` — whole blocks, or halves of them until a piece holds at most
-    ``window_bytes`` —, the piece ids ``at`` [T, P] of each slot's first
-    ``span`` logical rows (whole blocks; all without a span) in logical
-    order, and ``piece``. (A sentinel id addresses the next layer's
+    :data:`_GATHER_WINDOW_BYTES` — halves of a block until one fits: the
+    pools seen as ``[L * blocks * parts, piece, heads, hd]`` — and cut
+    to the heads that are real. (A sentinel id gathers the next layer's
     first block, or clamps: garbage the caller's mask never admits.)"""
     L, n_blocks, bl, heads, hd = cache["k"].shape
-    nb = table_rows.shape[1] - 1 if span is None else -(-span // bl)
+    nb = _span_blocks(cache, table_rows, span)
     row_bytes = heads * hd * cache["k"].dtype.itemsize
     piece = bl
-    while window_bytes and piece % 2 == 0 \
-            and piece * row_bytes > window_bytes:
+    while piece % 2 == 0 and piece * row_bytes > _GATHER_WINDOW_BYTES:
         piece //= 2
     parts = bl // piece
     at = (fi * n_blocks + table_rows[:, :nb])[:, :, None] * parts \
         + jnp.arange(parts)
     flat = [cache[n].reshape(L * n_blocks * parts, piece, heads, hd)
             for n in ("k", "v")]
-    return flat, at.reshape(at.shape[0], nb * parts), piece
+    at = at.reshape(at.shape[0], nb * parts)
+    rows = nb * bl if span is None else span
+    return [pool[at].reshape(at.shape[0], nb * bl, heads, hd)[
+        :, :rows, :cfg.n_kv_heads] for pool in flat]
 
 
-def _attend_in_place(cfg, cache: Cache, fi, table_rows, span, q, pos,
+def _blocks_held(cache: Cache, rows, span_blocks: int):
+    """Blocks that hold a slot's first ``rows`` rows (any shape), at
+    most the ``span_blocks`` the program's span covers."""
+    return jnp.minimum(-(-rows // cache["k"].shape[2]), span_blocks)
+
+
+def _attend_in_place(cfg, cache: Cache, fi, table_rows, span, q, held,
                      staged_k, staged_v, staged_mask):
     """Decode attention of one tile of slots WITHOUT a copy of their
-    rows: ``q`` [T, 1, n_heads, hd] over each slot's resident rows ``<
-    pos`` [T], read out of the pool a piece a turn — once for the scores,
-    once, after the softmax, for the weighted values — and over the
-    staged columns ``staged_k`` / ``staged_v`` [T, k, G, hd] that
-    ``staged_mask`` [1, 1, k] admits, under one softmax. Equal to
-    gathering the rows and :func:`_attend` up to summation order. ->
-    [T, 1, n_heads, hd] float32."""
+    rows: ``q`` [T, 1, n_heads, hd] over each slot's resident rows in
+    full layer ``fi``, read out of the pool a block a turn — once for
+    the scores, once, after the softmax, for the weighted values — and
+    over the staged columns ``staged_k`` / ``staged_v`` [T, k, G, hd]
+    that ``staged_mask`` [1, 1, k] admits, under one softmax. ``held``
+    [T, 2]: the resident rows of each slot that count (``< held[:, 0]``;
+    0 for a dead slot) and the blocks that hold them (:func:`_blocks_held`):
+    a slot is read for THAT many turns — the loop over a slot's blocks
+    nests in the loop over the tile's slots — whatever the span: a block
+    past a slot's rows has masked scores and exact-zero weights (and the
+    table's sentinel for an id), so the span only sizes the buffers.
+    Equal to gathering the rows and :func:`_attend` up to summation
+    order. -> [T, 1, n_heads, hd] float32."""
     T, _, nh, hd = q.shape
     G = cfg.n_kv_heads
     rep, f32 = nh // G, jnp.float32
-    (fk, fv), at, piece = _pool_pieces(cache, fi, table_rows, span)
-    P = at.shape[1]
+    L, n_blocks, bl = cache["k"].shape[:3]
+    P = _span_blocks(cache, table_rows, span)
+    fk, fv = (cache[n].reshape((L * n_blocks,) + cache[n].shape[2:])
+              for n in ("k", "v"))
+    at = fi * n_blocks + table_rows[:, :P]
     qf = q[:, 0].reshape(T, G, rep, hd).astype(f32) * hd ** -0.5
 
-    def score(i, scores):
-        t, j = i // P, i % P
-        kp = lax.dynamic_index_in_dim(fk, at[t, j], 0, False)[:, :G]
-        s = jnp.einsum("mgk,grk->mgr", kp.astype(f32),
-                       lax.dynamic_index_in_dim(qf, t, 0, False))
-        return lax.dynamic_update_slice(scores, s[None], (t, j * piece, 0, 0))
+    def block(pool, t, j):
+        return lax.dynamic_index_in_dim(pool, at[t, j], 0, False)[:, :G] \
+            .astype(f32)
 
-    scores = lax.fori_loop(0, T * P, score,
-                           jnp.zeros((T, P * piece, G, rep), f32))
+    def slot_by_slot(turn, carry):
+        def slot(t, carry):
+            return lax.fori_loop(0, held[t, 1],
+                                 lambda j, c: turn(t, j, c), carry)
+        return lax.fori_loop(0, T, slot, carry)
+
+    def score(t, j, scores):
+        s = jnp.einsum("mgk,grk->mgr", block(fk, t, j), qf[t])
+        return lax.dynamic_update_slice(scores, s[None], (t, j * bl, 0, 0))
+
+    scores = slot_by_slot(score, jnp.zeros((T, P * bl, G, rep), f32))
     neg = jnp.asarray(-1e30, f32)
-    resident = jnp.arange(P * piece)[None, :] < pos[:, None]
+    resident = jnp.arange(P * bl)[None, :] < held[:, :1]
     scores = jnp.where(resident[:, :, None, None], scores, neg)
     staged = jnp.einsum("tgrk,tmgk->tmgr", qf, staged_k.astype(f32))
     staged = jnp.where(staged_mask[0, 0][None, :, None, None], staged, neg)
     w = jax.nn.softmax(jnp.concatenate([scores, staged], axis=1), axis=1)
-    w_res, w_st = w[:, :P * piece], w[:, P * piece:]
+    w_res, w_st = w[:, :P * bl], w[:, P * bl:]
 
-    def weigh(i, acc):
-        t, j = i // P, i % P
-        vp = lax.dynamic_index_in_dim(fv, at[t, j], 0, False)[:, :G]
-        wp = lax.dynamic_slice(w_res, (t, j * piece, 0, 0),
-                               (1, piece, G, rep))[0]
-        return acc.at[t].add(jnp.einsum("mgr,mgk->grk", wp, vp.astype(f32)))
+    def weigh(t, j, acc):
+        wp = lax.dynamic_slice(w_res, (t, j * bl, 0, 0), (1, bl, G, rep))[0]
+        return acc.at[t].add(
+            jnp.einsum("mgr,mgk->grk", wp, block(fv, t, j)))
 
-    o = lax.fori_loop(0, T * P, weigh, jnp.zeros((T, G, rep, hd), f32))
+    o = slot_by_slot(weigh, jnp.zeros((T, G, rep, hd), f32))
     o = o + jnp.einsum("tmgr,tmgk->tgrk", w_st, staged_v.astype(f32))
     return o.reshape(T, 1, nh, hd)
 
@@ -503,9 +521,17 @@ def _staged_steps(params, cache: Cache, cfg: oh.OlmoHybridConfig, table,
     kdt = cache["k"].dtype
     pos0 = cache["length"]
     batch_ix = jnp.arange(B)
-    tiles = kvcache._live_tiles(live, pos0, table)
+    n_tiles, order, _, table_rows = kvcache._live_tiles(live, pos0, table)
     if live is None:
         live = jnp.ones((B,), bool)
+    # What a full layer reads of the pool is bounded by residency — a
+    # live slot's blocks up to the rows it holds, a dead slot's not at
+    # all — and the bounds are constants of the program: they ride to
+    # each turn where ``_live_tiles`` puts the slots' lengths.
+    rows = jnp.where(live, pos0, 0)[order]
+    held = jnp.stack([rows, _blocks_held(
+        cache, rows, _span_blocks(cache, table, span))], axis=1)
+    tiles = (n_tiles, order, held, table_rows)
 
     def step(carry, s):
         with jax.named_scope("decode_step"):
@@ -530,9 +556,9 @@ def _staged_steps(params, cache: Cache, cfg: oh.OlmoHybridConfig, table,
                     lk = lax.dynamic_index_in_dim(sk, fi, 0, False)
                     lv = lax.dynamic_index_in_dim(sv, fi, 0, False)
 
-                    def attend(ids, pos, table_rows):
+                    def attend(ids, held, table_rows):
                         return _attend_in_place(
-                            cfg, cache, fi, table_rows, span, q[ids], pos,
+                            cfg, cache, fi, table_rows, span, q[ids], held,
                             lk[ids], lv[ids], staged)
 
                     o = kvcache._visit_tiles(

@@ -112,6 +112,10 @@ def programs_for(cfg):
       takes ``live`` in ``decode_step`` too, is told ``carried`` /
       ``state_rows`` in the dispatch annotations, and cannot share
       blocks (a block's rows are not all a sharer needs);
+    * ``DECODE_READS_BLOCKS_HELD`` — whether a decode program's K/V
+      read is bounded by residency (each live slot's blocks up to the
+      rows it holds, not every tile slot's up to the span rung): the
+      dispatch annotations then say ``kv_blocks``;
     * ``token_bytes(cfg, cache)``, ``hbm_rows(cache, params)`` and
       ``roofline_dims(cfg)`` — the cache bytes a token holds, the HBM
       ledger's rows for what the family keeps on the device, and what
@@ -126,6 +130,7 @@ def programs_for(cfg):
 FAMILY = "GQA decoder"
 UNSUPPORTED: Dict[str, str] = {}
 SLOT_STATE: Tuple[str, ...] = ()
+DECODE_READS_BLOCKS_HELD = False
 
 
 def token_bytes(cfg: llama.LlamaConfig, cache: Cache) -> int:

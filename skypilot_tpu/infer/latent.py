@@ -98,6 +98,7 @@ UNSUPPORTED = {
     "kv_kernel": "the paged-attention kernel reads per-head K/V",
 }
 SLOT_STATE = ()
+DECODE_READS_BLOCKS_HELD = False
 
 
 def roofline_dims(cfg: glm.GlmMoeConfig) -> dict:

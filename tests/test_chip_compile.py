@@ -474,7 +474,36 @@ def test_hybrid_programs_compile_for_v5e(one_chip, hybrid_engine_1period,
         "the state is gathered by slot id"
     # Transients stay a fraction of what is resident at 16 layers (8.2
     # GB of weights): the largest here is the head's 0.77 GB.
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < 2.2e9
+    if program != "decode_burst":
+        return
+    # The decode program reads the pool in place, a block a turn of a
+    # loop whose trip count is the blocks a slot HOLDS (here at the top
+    # rung, 34 blocks a slot): nothing the size of a pool tensor —
+    # whole, or in the halves a gather of a window over 1 MiB is lowered
+    # to — is sliced, copied, gathered or selected; what makes such an
+    # array is the argument, its views, and the flush's two in-place
+    # scatters.
+    pool = math.prod(e.cache["k"].shape)
+    made = {}
+    for dims, kind in re.findall(
+            r"= bf16\[([\d,]+),32,128\]\S* ([\w\-]+)\(", text):
+        if 32 * 128 * math.prod(int(d) for d in dims.split(",")) \
+                >= pool // 2:
+            made[kind] = made.get(kind, 0) + 1
+    assert set(made) <= {"parameter", "get-tuple-element", "bitcast",
+                         "fusion", "scatter"}, made
+    assert made["fusion"] == made["scatter"] == 2, made
+    # The loop over a slot's blocks nests in the loop over the tile's
+    # slots, for the scores and again for the values: two loops more
+    # than the parent's ten.
+    assert text.count(" while(") == 12
+    # ... and its temporaries are the parent's (PR 40: 64 332 800 B with
+    # one flat loop over every tile slot and span block), to the 0.4 MB
+    # by which the scores buffer's layout now pads 30 heads to 32
+    # sublanes; a copy of one pool tensor would be 84 MB.
+    assert temps < 64_332_800 * 1.01
 
 
 def test_peaks_table_is_keyed_by_device_kind():

@@ -322,6 +322,133 @@ def test_a_tile_that_mixes_live_and_dead_slots(cfg, params):
     assert moved == live.tolist()
 
 
+def _unbounded_attend_in_place(cfg, cache, fi, table_rows, span, q, pos,
+                               staged_k, staged_v, staged_mask):
+    """The in-place read as it was before it was bounded by residency:
+    every slot of the tile for every block of the span, one flat loop —
+    kept here as the reference the bounded read must equal EXACTLY on
+    the live rows (a skipped block added masked scores and exact-zero
+    weights)."""
+    T, _, nh, hd = q.shape
+    G = cfg.n_kv_heads
+    rep, f32 = nh // G, jnp.float32
+    L, n_blocks, bl = cache["k"].shape[:3]
+    P = -(-span // bl)
+    fk, fv = (cache[n].reshape((L * n_blocks,) + cache[n].shape[2:])
+              for n in ("k", "v"))
+    at = fi * n_blocks + table_rows[:, :P]
+    qf = q[:, 0].reshape(T, G, rep, hd).astype(f32) * hd ** -0.5
+
+    def score(i, scores):
+        t, j = i // P, i % P
+        kp = jax.lax.dynamic_index_in_dim(fk, at[t, j], 0, False)[:, :G]
+        s = jnp.einsum("mgk,grk->mgr", kp.astype(f32),
+                       jax.lax.dynamic_index_in_dim(qf, t, 0, False))
+        return jax.lax.dynamic_update_slice(scores, s[None],
+                                            (t, j * bl, 0, 0))
+
+    scores = jax.lax.fori_loop(0, T * P, score,
+                               jnp.zeros((T, P * bl, G, rep), f32))
+    neg = jnp.asarray(-1e30, f32)
+    resident = jnp.arange(P * bl)[None, :] < pos[:, None]
+    scores = jnp.where(resident[:, :, None, None], scores, neg)
+    staged = jnp.einsum("tgrk,tmgk->tmgr", qf, staged_k.astype(f32))
+    staged = jnp.where(staged_mask[0, 0][None, :, None, None], staged, neg)
+    w = jax.nn.softmax(jnp.concatenate([scores, staged], axis=1), axis=1)
+    w_res, w_st = w[:, :P * bl], w[:, P * bl:]
+
+    def weigh(i, acc):
+        t, j = i // P, i % P
+        vp = jax.lax.dynamic_index_in_dim(fv, at[t, j], 0, False)[:, :G]
+        wp = jax.lax.dynamic_slice(w_res, (t, j * bl, 0, 0),
+                                   (1, bl, G, rep))[0]
+        return acc.at[t].add(jnp.einsum("mgr,mgk->grk", wp, vp.astype(f32)))
+
+    o = jax.lax.fori_loop(0, T * P, weigh, jnp.zeros((T, G, rep, hd), f32))
+    o = o + jnp.einsum("tmgr,tmgk->tgrk", w_st, staged_v.astype(f32))
+    return o.reshape(T, 1, nh, hd)
+
+
+# One tile: a slot at the full span, a short one, a length exactly on a
+# block boundary, a live slot of length 0, and a DEAD slot that holds
+# rows (mid-prefill). Blocks 10 and 11 are nobody's; the table's
+# sentinel (12) addresses the next layer's first block, or clamps to 11.
+_TILE_LENGTHS = [64, 5, 32, 0, 40]
+_TILE_LIVE = [True, True, True, True, False]
+_TILE_BLOCKS = {0: [3, 1, 4, 0], 1: [2], 2: [7, 6], 4: [8, 9, 5]}
+
+
+@pytest.mark.parametrize("fi", [0, 1], ids=["layer0", "last_layer"])
+@pytest.mark.parametrize("case", ["numbers", "poison"])
+def test_in_place_read_is_bounded_by_residency(cfg, case, fi):
+    """``_attend_in_place`` reads a live slot's blocks up to the rows it
+    holds and a dead slot's not at all. ``numbers``: the live rows equal
+    gather + ``_attend`` (summation order) AND the unbounded in-place
+    read bit for bit. ``poison``: every block of the pool that no live
+    slot's rows reach — the dead slot's, the free ones, the other layer
+    and with it the sentinel's target — is NaN, and the live rows are
+    finite and unchanged (a NaN times a zero weight would show any block
+    still read)."""
+    n_blocks, bl, span, k = 12, 16, 64, 4
+    T = len(_TILE_LENGTHS)
+    rng = np.random.default_rng(41)
+    cache = hybrid.init_paged_cache(cfg, T, n_blocks, bl)
+    for name in ("k", "v"):
+        cache[name] = jnp.asarray(rng.normal(size=cache[name].shape),
+                                  jnp.float32)
+    table = _table(T, n_blocks, _TILE_BLOCKS)
+    lengths = jnp.asarray(_TILE_LENGTHS, jnp.int32)
+    live = np.asarray(_TILE_LIVE)
+    q = jnp.asarray(rng.normal(size=(T, 1, cfg.n_heads, cfg.head_dim)),
+                    jnp.float32)
+    sk, sv = (jnp.asarray(rng.normal(
+        size=(T, k, cfg.n_kv_heads, cfg.head_dim)), jnp.float32)
+        for _ in range(2))
+    staged = (jnp.arange(k) <= 1)[None, None, :]
+    rows = jnp.where(jnp.asarray(live), lengths, 0)
+    held = jnp.stack([rows, hybrid._blocks_held(cache, rows, span // bl)], 1)
+    assert np.asarray(held[:, 1]).tolist() == [4, 1, 2, 0, 0]
+
+    def bounded(c):
+        return np.asarray(jax.jit(lambda c: hybrid._attend_in_place(
+            cfg, c, jnp.asarray(fi), table, span, q, held, sk, sv,
+            staged))(c))
+
+    def unbounded(c):
+        return np.asarray(jax.jit(lambda c: _unbounded_attend_in_place(
+            cfg, c, jnp.asarray(fi), table, span, q, lengths, sk, sv,
+            staged))(c))
+
+    got = bounded(cache)
+    assert np.isfinite(got).all()
+    if case == "numbers":
+        ck, cv = hybrid._gather_kv(cfg, cache, fi, table, span)
+        resident = (jnp.arange(span)[None, None, :]
+                    < lengths[:, None, None])
+        want = np.asarray(hybrid._attend(
+            cfg, q, [(ck, cv, resident), (sk, sv, staged)]))
+        assert np.abs(got - want)[live].max() < LOGIT_TOL
+        exact = unbounded(cache)
+        assert np.array_equal(got[live], exact[live])
+        # The dead slot is not the unbounded read's: nothing of it is.
+        assert not np.array_equal(got[~live], exact[~live])
+        return
+    keep = np.zeros(cache["k"].shape[:2], bool)
+    for slot, blocks in _TILE_BLOCKS.items():
+        if live[slot]:
+            keep[fi, blocks[:-(-_TILE_LENGTHS[slot] // bl)]] = True
+    assert keep.sum() == 7
+    poisoned = dict(cache)
+    for name in ("k", "v"):
+        poisoned[name] = jnp.where(keep[:, :, None, None, None],
+                                   cache[name], jnp.nan)
+    after = bounded(poisoned)
+    assert np.isfinite(after[live]).all()
+    assert np.array_equal(after[live], got[live])
+    # ... and the unbounded read does touch them: the case is not vacuous.
+    assert not np.isfinite(unbounded(poisoned)[live]).all()
+
+
 # -- (d) ---------------------------------------------------------------------
 
 @pytest.mark.parametrize("lengths", [(12, 20), (70, 45)],
@@ -483,12 +610,28 @@ def test_dispatch_annotations_say_carried_and_state_rows(
         cfg, params, tmp_path, monkeypatch):
     """``engine.chunk.dispatch`` says whether the chunk continued a
     resident state, ``engine.decode.dispatch`` how many slots' states
-    the burst updates; an engine of the Llama family says neither."""
+    the burst updates and how many blocks hold its slots' rows
+    (``kv_blocks``: what a layer reads, against the ``tiles * TILE *
+    ceil(span / block)`` the rung alone would make it); the flight
+    record carries the same count; an engine of the Llama family says
+    none of the three."""
     path = tmp_path / "timeline.json"
     monkeypatch.setenv(timeline.ENV_VAR, str(path))
     e = _engine(params, cfg)
     e.add_request(_prompts([75], seed=12)[0], max_new_tokens=24)
     e.add_request(_prompts([20], seed=13)[0], max_new_tokens=24)
+    held, round_slots = [], e._round_slots
+
+    def spy(width):
+        span, slots, promoted = round_slots(width)
+        if slots:
+            held.append(sum(
+                -(-(len(r.prompt) + len(r.tokens) + e._inflight_tokens)
+                  // 16) for r in (e.slot_req[s] for s in slots)))
+        return span, slots, promoted
+
+    monkeypatch.setattr(e, "_round_slots", spy)
+    seq0 = e.flight.seq()
     e.run_to_completion(max_burst=4)
     lcfg = llama.CONFIGS["llama3-tiny"]
     le = eng.InferenceEngine(
@@ -506,7 +649,21 @@ def test_dispatch_annotations_say_carried_and_state_rows(
               if ev["name"] == "engine.decode.dispatch"]
     assert bursts and all(b["state_rows"] == b["slots"] for b in bursts)
     assert max(b["state_rows"] for b in bursts) == 2
-    n_before = len(events)
+    # What the spy counted from the requests themselves, dispatch by
+    # dispatch; never the rung's blocks (75 and 20 tokens in blocks of
+    # 16: 5..7 + 2..3 a layer where the rung alone reads 16 or 32).
+    assert [b["kv_blocks"] for b in bursts] == held
+    assert max(b["slots"] for b in bursts) == 2
+    for b in bursts:
+        assert 0 < b["kv_blocks"] <= (
+            b["tiles"] * kvcache.TILE * -(-b["span"] // 16))
+    assert sum(held) < sum(b["tiles"] * kvcache.TILE * -(-b["span"] // 16)
+                           for b in bursts) / 2
+    records = [r for r in e.flight.since(seq0) if r["burst"] == "decode"]
+    assert [r["kv_blocks"] for r in records] == held
+    assert all(r["kv_blocks"] <= r["tiles"] * kvcache.TILE * 8
+               for r in records)
+    n_before, seq1 = len(events), le.flight.seq()
     le.add_request(list(range(1, 50)), max_new_tokens=4)
     le.run_to_completion(max_burst=4)
     timeline.save_now()
@@ -515,5 +672,8 @@ def test_dispatch_annotations_say_carried_and_state_rows(
     mine = [ev["args"] for ev in later
             if ev["name"] in ("engine.chunk.dispatch",
                               "engine.decode.dispatch")]
-    assert mine and not any("carried" in a or "state_rows" in a
-                            for a in mine)
+    assert mine and not any(
+        "carried" in a or "state_rows" in a or "kv_blocks" in a
+        for a in mine)
+    others = le.flight.since(seq1)
+    assert others and not any("kv_blocks" in r for r in others)

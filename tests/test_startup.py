@@ -204,8 +204,9 @@ def test_first_phase_stamps_before_main_and_children_stay_out_of_the_sum(
     assert phases["warm_grid"] >= phases["warm_grid.decode"] >= 0.01
     rep = startup.report()
     top = sum(v for k, v in rep["phases"].items() if "." not in k)
+    # Five numbers, each rounded to four decimals: up to 2.5e-4 apart.
     assert rep["total_s"] == pytest.approx(top + rep["unattributed_s"],
-                                           abs=2e-4)
+                                           abs=3e-4)
     assert set(rep) == {"total_s", "phases", "unattributed_s", "compile",
                         "memory"}
     assert set(rep["compile"]) == {"programs", "trace_s", "lower_s",
