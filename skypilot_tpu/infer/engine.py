@@ -101,6 +101,16 @@ DECODE_STALL_SECONDS = metrics.histogram(
     "bounds",
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
              0.5, 1.0, 2.5, 5.0))
+WINDOW_ROWS = metrics.counter(
+    "skytpu_window_rows_read_total",
+    "Ring rows the window layers of decode programs had to read: per "
+    "dispatched program, the sum over its live slots of min(rows held, "
+    "window) (models with sliding-window layers only)")
+WINDOW_KEYS = metrics.counter(
+    "skytpu_window_keys_scored_total",
+    "Key rows the window layers of prefill programs had to score: per "
+    "dispatched chunk or wave, the sum over its real tokens of "
+    "min(position + 1, window) (models with sliding-window layers only)")
 KV_BLOCKS_TOTAL = metrics.gauge(
     "skytpu_kv_blocks_total",
     "Paged KV cache: physical blocks in the pool (0 when the engine "
@@ -110,7 +120,8 @@ KV_TOKEN_BYTES = metrics.gauge(
     "Cache bytes one token holds over all layers, from the cache's own "
     "tensors: 2 x layers x kv_heads x head_dim (+ scales) for per-head "
     "K/V, layers x (kv_lora_rank + rope dim) for a latent (MLA) cache, "
-    "the full-attention layers only for a hybrid cache")
+    "the full-attention layers only for a hybrid cache, the global "
+    "layers only for a windowed one")
 KV_BLOCKS_USED = metrics.gauge(
     "skytpu_kv_blocks_used",
     "Paged KV cache: blocks currently referenced by decode slots "
@@ -313,6 +324,9 @@ class BurstHandle:
     # program reads by residency (``_family_notes``): the completion
     # record carries it beside ``tiles``.
     kv_blocks: Optional[int] = None
+    # Ring rows the burst's slots held at dispatch (a family with
+    # window layers; ``_family_notes``).
+    window_rows: Optional[int] = None
 
 
 class PromptTooLongError(ValueError):
@@ -410,6 +424,17 @@ class UnsupportedOptionError(ValueError):
             "option": option,
             "family": family,
         }
+
+
+def window_keys(start: int, n: int, window: int) -> int:
+    """Key rows a window layer must score for the ``n`` tokens at
+    positions ``start .. start + n - 1``: the sum of ``min(p + 1,
+    window)`` (a token sees itself and what its window still holds)."""
+    end = start + n
+    ramp = min(end, window)
+    keys = (ramp * (ramp + 1) - start * (start + 1)) // 2 \
+        if start < ramp else 0
+    return keys + window * max(0, end - max(start, window))
 
 
 def refuse_options(progs, **given) -> None:
@@ -762,6 +787,9 @@ class InferenceEngine:
         # (kvcache.programs_for lists them) — first of all which of
         # these options it cannot serve.
         self._progs = progs = kvcache.programs_for(cfg)
+        # Rows a window layer keeps per slot (None: the family has no
+        # such layer, and says nothing of them in its annotations).
+        self._ring_rows = progs.ring_rows(cfg)
         refuse_options(progs, **{
             "kv_block=0": kv_block == 0 or (
                 kv_block is None and os.environ.get(
@@ -1454,6 +1482,9 @@ class InferenceEngine:
         (rows x bucket x (ff + 2d) fp32 plus the wave logits), the one
         family with no host-authoritative array to read."""
         led = self.hbm_ledger
+        # The gauge is the process's: rows an earlier engine of another
+        # family left there (a test worker's) are not this engine's.
+        led.zero_published_rows()
         led.set_bytes("weights", self._weight_bytes)
         # What the cache holds is the family's to name: ``kv_pool``,
         # or ``latent_kv_pool`` (+ the ``expert_weights`` view inside
@@ -1539,7 +1570,9 @@ class InferenceEngine:
         which a program whose K/V read is bounded by residency visits a
         layer (``tiles * TILE * ceil(span / kv_block)`` is what the rung
         alone would make it read; a family whose read is not bounded
-        says nothing)."""
+        says nothing) — and ``window_rows`` — the ring rows a window
+        layer reads for them, ``min(rows, window)`` a slot (a family
+        without window layers says nothing)."""
         notes = {}
         if self._progs.SLOT_STATE:
             notes["state_rows"] = len(slots)
@@ -1547,7 +1580,24 @@ class InferenceEngine:
             notes["kv_blocks"] = sum(
                 -(-self._slot_rows(self.slot_req[s]) // self.kv_block)
                 for s in slots)
+        if self._ring_rows:
+            notes["window_rows"] = sum(
+                min(self._slot_rows(self.slot_req[s]), self._ring_rows)
+                for s in slots)
+            WINDOW_ROWS.inc(notes["window_rows"])
         return notes
+
+    def _window_notes(self, runs) -> Dict[str, int]:
+        """What a prefill dispatch annotation says of the window layers:
+        ``window_keys``, the key rows one of them must score for the
+        program's real tokens — ``runs``: (first position, tokens) a
+        request. A family without window layers says nothing."""
+        if not self._ring_rows:
+            return {}
+        keys = sum(window_keys(start, n, self._ring_rows)
+                   for start, n in runs)
+        WINDOW_KEYS.inc(keys)
+        return {"window_keys": keys}
 
     def _record_flight(self, burst: str, begin_s: float, end_s: float,
                        program: Dict[str, Any], slots, reqs,
@@ -1560,7 +1610,9 @@ class InferenceEngine:
                        calibrator: Optional[
                            attribution_lib.DeviceTimeCalibrator]
                        = None,
-                       kv_blocks: Optional[int] = None) -> None:
+                       kv_blocks: Optional[int] = None,
+                       window_rows: Optional[int] = None,
+                       window_keys: Optional[int] = None) -> None:
         """Append one burst record to the flight recorder. HOST
         bookkeeping only — every value here already lives on the host
         (request lists, ints, floats); a device fetch on this path
@@ -1594,6 +1646,10 @@ class InferenceEngine:
             extra["tiles"] = self._tiles(burst, len(slots))
             if kv_blocks is not None:
                 extra["kv_blocks"] = kv_blocks
+            if window_rows is not None:
+                extra["window_rows"] = window_rows
+        if window_keys is not None:
+            extra["window_keys"] = window_keys
         if stall:
             extra["stall"] = True
         if drafted:
@@ -2776,7 +2832,8 @@ class InferenceEngine:
         if fresh and req.n_chunks == 0:
             req.queue_s = max(t0 - req.submit_s, 0.0)
             counts["queue_ms"] = round(req.queue_s * 1e3, 3)
-        with timeline.phase("engine.chunk.dispatch", **counts):
+        window = self._window_notes([(start, n_valid)])
+        with timeline.phase("engine.chunk.dispatch", **counts, **window):
             self.cache, self.rng, tok_dev = self._prefill_chunk_fn(
                 self.params, self.cache, jnp.asarray(chunk),
                 jnp.asarray(start, jnp.int32),
@@ -2807,7 +2864,7 @@ class InferenceEngine:
             program={"span": attn_span, "final": final},
             slots=[req.slot], reqs=[req], toks=1 if final else 0,
             stall=decode_active, dispatch_s=t_disp,
-            dev_keys=[chunk_key])
+            dev_keys=[chunk_key], window_keys=window.get("window_keys"))
         st.pos += n_valid
         if not final:
             return True
@@ -3073,7 +3130,9 @@ class InferenceEngine:
                 bucket=bucket,
                 prompt_tokens=sum(self._ctx_len(r) for r in wave),
                 queue_ms_sum=round(sum(queued), 3),
-                queue_ms_max=round(max(queued, default=0.0), 3)):
+                queue_ms_max=round(max(queued, default=0.0), 3),
+                **self._window_notes(
+                    [(0, self._ctx_len(r)) for r in wave])):
             return self._launch_wave(wave, slots, bucket, n, span)
 
     def _launch_wave(self, wave: List["Request"], slots: List[int],
@@ -3810,7 +3869,8 @@ class InferenceEngine:
                            key=self.compile_watch.last_key,
                            dispatch_done_s=time.time(),
                            seq=self._burst_seq,
-                           kv_blocks=notes.get("kv_blocks"))
+                           kv_blocks=notes.get("kv_blocks"),
+                           window_rows=notes.get("window_rows"))
 
     def complete_decode_burst(self, handle: "BurstHandle"
                               ) -> Dict[int, List[int]]:
@@ -3878,7 +3938,7 @@ class InferenceEngine:
             program={"k": handle.k, "span": handle.span_arg},
             slots=handle.slots, reqs=live_reqs, toks=n_emitted,
             dispatch_s=handle.dispatch_done_s, dev_keys=[handle.key],
-            kv_blocks=handle.kv_blocks)
+            kv_blocks=handle.kv_blocks, window_rows=handle.window_rows)
         return out, n_emitted
 
     def step_decode_once(self) -> Dict[int, int]:
@@ -3941,7 +4001,8 @@ class InferenceEngine:
             program={"k": 1, "span": sarg},
             slots=slots, reqs=step_reqs, toks=len(out),
             dispatch_s=t_disp, dev_keys=[step_key],
-            kv_blocks=notes.get("kv_blocks"))
+            kv_blocks=notes.get("kv_blocks"),
+            window_rows=notes.get("window_rows"))
         return out
 
     def run_to_completion(self, max_burst: int = 8) -> List[Request]:

@@ -89,6 +89,11 @@ UNSUPPORTED = {
 }
 
 
+def ring_rows(cfg) -> None:
+    """No window layers: no ring (see ``kvcache.programs_for``)."""
+    return None
+
+
 # Rows of a (bf16) tile: the pool's heads axis is second-minor.
 _HEAD_TILE = 16
 

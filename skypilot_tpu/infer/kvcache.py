@@ -91,7 +91,8 @@ def programs_for(cfg):
     family's model module names it (``SERVE_PROGRAMS``): this module for
     the GQA decoders, ``infer/latent.py`` for the latent-cache family,
     ``infer/hybrid.py`` for the decoders that keep a recurrent state per
-    slot beside their K/V rows. The engine's jitted entry points call
+    slot beside their K/V rows, ``infer/windowed.py`` for those whose
+    sliding-window layers keep a ring of rows per slot. The engine's jitted entry points call
     through it and are otherwise one code path. What the engine uses of
     a family module, with this module's signatures:
 
@@ -116,6 +117,10 @@ def programs_for(cfg):
       read is bounded by residency (each live slot's blocks up to the
       rows it holds, not every tile slot's up to the span rung): the
       dispatch annotations then say ``kv_blocks``;
+    * ``ring_rows(cfg)`` — the rows a sliding-window layer keeps per
+      slot (``None``: the family has no such layer): the decode
+      dispatch annotations then say ``window_rows``, the prefill ones
+      ``window_keys``;
     * ``token_bytes(cfg, cache)``, ``hbm_rows(cache, params)`` and
       ``roofline_dims(cfg)`` — the cache bytes a token holds, the HBM
       ledger's rows for what the family keeps on the device, and what
@@ -131,6 +136,11 @@ FAMILY = "GQA decoder"
 UNSUPPORTED: Dict[str, str] = {}
 SLOT_STATE: Tuple[str, ...] = ()
 DECODE_READS_BLOCKS_HELD = False
+
+
+def ring_rows(cfg) -> None:
+    """No window layers: no ring (see ``kvcache.programs_for``)."""
+    return None
 
 
 def token_bytes(cfg: llama.LlamaConfig, cache: Cache) -> int:

@@ -101,6 +101,11 @@ SLOT_STATE = ()
 DECODE_READS_BLOCKS_HELD = False
 
 
+def ring_rows(cfg) -> None:
+    """No window layers: no ring (see ``kvcache.programs_for``)."""
+    return None
+
+
 def roofline_dims(cfg: glm.GlmMoeConfig) -> dict:
     """A token multiplies with its chosen experts only."""
     return {"param_count": cfg.active_params(), "n_layers": cfg.n_layers,

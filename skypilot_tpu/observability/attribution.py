@@ -79,7 +79,8 @@ DEVTIME_EWMA_MS = metrics.gauge(
 HBM_BYTES = metrics.gauge(
     "skytpu_hbm_bytes",
     "Analytical HBM ledger: bytes each device-resident tensor family "
-    "holds (weights, kv_pool or latent_kv_pool, recurrent_state, kv_used, "
+    "holds (weights, kv_pool or latent_kv_pool, recurrent_state, "
+    "window_ring, kv_used, "
     "draft_pool, adapter_pool, prefix_pinned, workspace; expert_weights "
     "is the routed experts' part of weights)",
     labelnames=("component",))
@@ -327,6 +328,17 @@ class HbmLedger:
         self._lock = threading.Lock()
         self._components: Dict[str, int] = {}   # guarded-by: _lock
         self._memstats_warned = False           # guarded-by: _lock
+
+    @staticmethod
+    def zero_published_rows() -> None:
+        """Zero every row the process-global gauge holds. The gauge is
+        one per PROCESS and a row's name is a family's to choose
+        (``latent_kv_pool``, ``recurrent_state``, ``window_ring``): a
+        second engine in the process — a test worker's, never a
+        server's — would otherwise add its limit to a first one's
+        rows, and the headroom rule would read a breach nobody has."""
+        for _, child in HBM_BYTES.children():
+            child.set(0)
 
     def set_bytes(self, component: str, n: int) -> None:
         n = max(int(n), 0)

@@ -506,6 +506,85 @@ def test_hybrid_programs_compile_for_v5e(one_chip, hybrid_engine_1period,
     assert temps < 64_332_800 * 1.01
 
 
+@pytest.fixture(scope="module")
+def windowed_engine_cut():
+    """The windowed family at every published Trinity-Mini width, cut as
+    the cell cuts it (the leading dense layer + one whole period: three
+    window layers and a global one, all 128 experts; weights are shapes
+    only; the cache is real: 7 slots — two tiles — of 33 280 rows over a
+    pool of 70 blocks of 512, and 8 rings a window layer, ~0.2 GB of
+    host memory)."""
+    from skypilot_tpu.models import afmoe
+    whole = afmoe.CONFIGS["trinity-mini"]
+    cfg = dataclasses.replace(whole, n_layers=5, n_dense_layers=1,
+                              layer_types=whole.layer_types[:5])
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(cfg.dtype),
+        afmoe.init_params(jax.random.key(0), cfg)))
+    return eng.InferenceEngine(
+        params, cfg, n_slots=7, max_len=33280,
+        prompt_buckets=(128, 512, 33280), max_wave=4, pad_waves=True,
+        prefix_pool=0, spec_k=0, kv_block=512, kv_blocks=70)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_chunk",
+                                     "admit_wave"])
+def test_windowed_programs_compile_for_v5e(one_chip, windowed_engine_cut,
+                                           program):
+    """The ring-and-pool programs lower for the chip at the TOP rung (33
+    280 rows): a token is 2048 B a layer in pool and ring alike (4
+    key/value heads side by side on the minor axis; a heads axis of 4
+    would be padded to a tile's 16 sublanes), what a slot holds is
+    written IN PLACE — no program copies or re-lays a whole pool or ring
+    tensor, and none builds an array of either's size with the 4 heads
+    on an axis of their own — and the decode program holds no Mosaic
+    kernel (the prefill programs hold the grouped expert products: four
+    calls an expert layer)."""
+    e = windowed_engine_cut
+    params, _, cache, rng, table, S = _engine_args(e, one_chip)
+    i32 = S((), jnp.int32)
+    if program == "decode_burst":
+        lowered = e._decode_burst_fn.__wrapped__.lower(
+            params, cache, rng, S((e.n_slots + 1,), jnp.bool_), table,
+            k=4, qweights=None, span=None, kernel=False)
+        kernels = 0
+    elif program == "prefill_chunk":
+        lowered = e._prefill_chunk_fn.__wrapped__.lower(
+            params, cache, S((512,), jnp.int32), i32, i32, i32, i32, rng,
+            table, final=True, qweights=None, span=None, kernel=False)
+        kernels = 16
+    else:
+        lowered = e._admit_wave_fn.__wrapped__.lower(
+            params, cache, S((4, 512), jnp.int32), S((4,), jnp.int32),
+            S((4,), jnp.int32), rng, table, bucket=512, qweights=None)
+        kernels = 16
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels
+    assert e.cache["k"].shape == (1, 70, 512, 512)
+    assert e.cache["win_k"].shape == (4, 8, 2048, 512)
+    for name in ("k", "win_k"):
+        row = e.cache[name].shape[-1] * e.cache[name].dtype.itemsize
+        assert 2 * row == 2048                   # K and V, a token, a layer
+    held = sum(e.cache[n].nbytes for n in ("k", "v", "win_k", "win_v"))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    for name in ("k", "win_k"):
+        shape = ",".join(str(n) for n in e.cache[name].shape)
+        assert not re.search(rf"bf16\[{shape}\]\S* copy\(", text), \
+            f"the {name} tensor is copied"
+    smallest = min(math.prod(e.cache[n].shape) for n in ("k", "win_k"))
+    for dims in re.findall(r"= bf16\[([\d,]+),4,128\]", text):
+        assert 512 * math.prod(int(d) for d in dims.split(",")) \
+            < smallest // 2, f"bf16[{dims},4,128]: heads on their own axis"
+    # Transients stay a fraction of what is resident (8.5 GB of weights):
+    # the largest is a chunk's key tile of float32 scores.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    if program == "decode_burst":
+        # steps; per unrolled layer its tile turns, slot and block loops
+        # (scores, values), and the expert visits.
+        assert text.count(" while(") >= 12
+
+
 def test_peaks_table_is_keyed_by_device_kind():
     v5e = _Device("tpu", "TPU v5 lite")
     row = attribution.peaks_for(v5e)
