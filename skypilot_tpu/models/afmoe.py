@@ -425,9 +425,10 @@ def sequence_mask(cfg: AfmoeConfig, n: int, window: bool) -> jax.Array:
 def _whole_experts(stacked: Params):
     """A period place's stacked tensors split for the scan: the routed
     experts WHOLE, seen ``[periods * E, ...]`` (an invariant the loop
-    indexes by ``expert_base`` — ``glm_moe.experts_grouped`` says why a
-    layer's slice is not handed to the grouped product), the rest
-    sliced a period a turn."""
+    indexes by ``expert_base``: ``glm_moe.experts_grouped``'s kernel —
+    ``ops.grouped_ffn.grouped_swiglu`` on a TPU — reads this layer's E
+    experts where they lie in it; a layer's slice handed to a kernel
+    would first be copied), the rest sliced a period a turn."""
     whole = {name: w.reshape((-1,) + w.shape[2:])
              for name, w in stacked.items()
              if name in glm_moe.EXPERT_TENSORS}

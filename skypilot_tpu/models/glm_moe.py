@@ -27,9 +27,11 @@ residual adds a layer; untied embedding and head):
   of one or two live rows streams a tenth of the expert weights
   (:func:`experts_few_rows`). Many rows (a prefill chunk or wave) sort
   the token-choices by expert and multiply by groups
-  (``lax.ragged_dot``, which the TPU compiler lowers to its
-  grouped-matmul kernel at these sizes and to the all-expert form
-  below ~1 k rows).
+  (:func:`experts_grouped`): on a TPU one Pallas kernel,
+  ``ops.grouped_ffn.grouped_swiglu``, that reads each expert of the
+  layer once and keeps the gate and up products in VMEM; elsewhere,
+  and for rows or widths that are no whole tile, three
+  ``lax.ragged_dot``.
 
 The heterogeneous stack is TWO parameter groups, ``dense`` and ``moe``,
 each stacked on a leading layer axis and each one ``lax.scan``
@@ -49,6 +51,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from skypilot_tpu.models import llama
+from skypilot_tpu.observability import flight
+from skypilot_tpu.ops import attention as attn_ops
+from skypilot_tpu.ops import grouped_ffn
 from skypilot_tpu.parallel import ring_attention as ra
 
 Params = Dict[str, Any]
@@ -488,23 +493,45 @@ def experts_grouped(cfg: GlmMoeConfig, h, idx, w, layer) -> jax.Array:
 
     Inside :func:`scan_layers` the expert matrices arrive as the WHOLE
     stack ``[layers * E, ...]`` with this layer's first group at
-    ``layer["expert_base"]``: the grouped product then runs over
-    ``layers * E`` groups, all empty but this layer's, and reads its
-    experts where they lie — a layer's slice of the stack handed to the
-    kernel is first copied (1.2 GB a layer, a third of a chunk's
-    time on the v5e)."""
+    ``layer["expert_base"]``, and are read where they lie — a layer's
+    slice of the stack handed to a kernel is first copied (1.2 GB a
+    layer, a third of a chunk's time on the v5e).
+
+    The products take one of two forms, chosen at trace time from the
+    backend and the shapes (``ops.grouped_ffn.tiles_for``) and noted on
+    the program's ``program.compiled`` line. On a TPU, with rows and
+    widths that are whole tiles: ONE Pallas kernel
+    (``ops.grouped_ffn.grouped_swiglu``) that walks this layer's E
+    groups only, reads an expert's three matrices once and none of an
+    expert nobody chose, and keeps ``g``, ``u`` and ``silu(g) * u`` in
+    VMEM. Elsewhere (the CPU, a row count that is no whole tile): three
+    ``lax.ragged_dot`` over the stack's ``layers * E`` groups, all empty
+    but this layer's."""
     dt = cfg.dtype
     T, K = idx.shape
     flat = idx.reshape(-1)
     order = jnp.argsort(flat, stable=True)
-    n_groups = layer["we_gate"].shape[0]
     base = layer.get("expert_base", 0)
-    sizes = jnp.zeros((n_groups,), jnp.int32).at[base + flat].add(1)
     xs = h[order // K]                                   # [T*K, D]
-    g = lax.ragged_dot(xs, layer["we_gate"].astype(dt), sizes)
-    u = lax.ragged_dot(xs, layer["we_up"].astype(dt), sizes)
-    y = lax.ragged_dot(jax.nn.silu(g) * u, layer["we_down"].astype(dt),
-                       sizes)
+    w_gate, w_up, w_down = (layer[name].astype(dt)
+                            for name in EXPERT_TENSORS)
+    D, F = xs.shape[1], w_gate.shape[-1]
+    tiles = attn_ops._on_tpu() and grouped_ffn.tiles_for(
+        T * K, D, F, xs.dtype.itemsize)
+    flight.COMPILES.note("expert_ffn", ("grouped_swiglu" if tiles else
+                                        "ragged_dot") + f"@{T * K}x{D}x{F}")
+    if tiles:
+        sizes = jnp.zeros((cfg.n_routed_experts,), jnp.int32).at[flat].add(1)
+        offsets = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+        y = grouped_ffn.grouped_swiglu(xs, w_gate, w_up, w_down, offsets,
+                                       base, tiles=tiles)
+    else:
+        sizes = jnp.zeros((w_gate.shape[0],), jnp.int32
+                          ).at[base + flat].add(1)
+        g = lax.ragged_dot(xs, w_gate, sizes)
+        u = lax.ragged_dot(xs, w_up, sizes)
+        y = lax.ragged_dot(jax.nn.silu(g) * u, w_down, sizes)
     y = y * w.reshape(-1)[order][:, None].astype(dt)
     return y[jnp.argsort(order)].reshape(T, K, -1).sum(axis=1)
 

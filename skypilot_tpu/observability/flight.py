@@ -239,6 +239,8 @@ class _ThreadCompiles(threading.local):
         self.intervals: List[Tuple[float, float]] = []
         # Tracing and lowering since this thread's last compile.
         self.open = {"trace_s": 0.0, "lower_s": 0.0}
+        # What the code being traced said of itself (CompileLedger.note).
+        self.notes: Dict[str, set] = {}
         self.load_s = 0.0
         self.cache_hit: Optional[bool] = None
         self.totals = _zero_totals()
@@ -289,6 +291,14 @@ class CompileLedger:
             self._installed = True
             return True
 
+    def note(self, key: str, value: str) -> None:
+        """Code that chooses between forms AT TRACE TIME says which it
+        took (``expert_ffn``: ``models.glm_moe.experts_grouped``): the
+        compile this thread finishes next carries ``key`` with every
+        distinct value noted since the last, on its record and its
+        ``program.compiled`` line."""
+        self._thread.notes.setdefault(key, set()).add(value)
+
     # -- the listeners (the compiling thread) ------------------------------
 
     def _own_seconds(self, th: _ThreadCompiles, seconds: float) -> float:
@@ -325,8 +335,10 @@ class CompileLedger:
         load = min(th.load_s, own) if th.cache_hit else 0.0
         rec = {"fun_name": str(kw.get("fun_name", "?")), **th.open,
                "compile_s": own - load, "load_s": load,
-               "cache_hit": th.cache_hit}
+               "cache_hit": th.cache_hit,
+               **{k: ",".join(sorted(v)) for k, v in th.notes.items()}}
         th.open = {"trace_s": 0.0, "lower_s": 0.0}
+        th.notes = {}
         th.load_s, th.cache_hit = 0.0, None
         gained = {s + "_s": rec[s + "_s"] for s in STAGES}
         if rec["cache_hit"] is not None:

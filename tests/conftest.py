@@ -33,11 +33,13 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# The paged-attention kernel compiles with Mosaic by default; the CPU
-# suite is the one place that asks for the Pallas interpreter.
-from skypilot_tpu.ops import paged_attention  # noqa: E402
+# The paged-attention and grouped-SwiGLU kernels compile with Mosaic by
+# default; the CPU suite is the one place that asks for the Pallas
+# interpreter.
+from skypilot_tpu.ops import grouped_ffn, paged_attention  # noqa: E402
 
 paged_attention.INTERPRET = True
+grouped_ffn.INTERPRET = True
 
 import pytest  # noqa: E402
 

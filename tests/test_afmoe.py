@@ -28,6 +28,8 @@ from benchmarks.families import afmoe as family
 from benchmarks.reference import afmoe as ref
 from skypilot_tpu.infer import kvcache, windowed
 from skypilot_tpu.models import afmoe, glm_moe, registry
+from skypilot_tpu.ops import attention as attn_ops
+from skypilot_tpu.ops import grouped_ffn
 
 SEED = 2_900_000_017          # more than 31 bits
 LOGIT_TOL = 1e-3
@@ -269,11 +271,11 @@ def test_reference_blocks_need_not_divide_the_length(dims, monkeypatch):
 
 # -- the expert layer IS glm_moe's, at other numbers ------------------------
 
-def _expert_layer(dims, bias):
+def _expert_layer(dims, bias, **widths):
     """One expert layer at the PUBLISHED counts — 128 experts, top-8 —
     and small widths."""
     wide = dataclasses.replace(dims, n_routed_experts=128,
-                               experts_per_tok=8)
+                               experts_per_tok=8, **widths)
     layer = {n: a.astype(jnp.float32) for n, a in G.layer_tensors(
         _key(), wide, np.uint32(3), True).items()}
     layer["router_bias"] = jnp.asarray(bias, jnp.float32)
@@ -287,33 +289,42 @@ def _bias(hot=(), cold=()):
     return b
 
 
-@pytest.mark.parametrize("form", ["few-rows", "grouped"])
+@pytest.mark.parametrize("form", ["few-rows", "grouped", "grouped-kernel"])
 @pytest.mark.parametrize("bias", [
     _bias(hot=[5]), _bias(hot=range(8)), _bias(cold=range(64, 128)),
     _bias()], ids=["one-for-all", "all-to-eight", "half-chosen-by-none",
                    "free"])
 def test_expert_layer_at_128_top_8_under_skewed_routing(cfg, dims, bias,
-                                                        form):
-    """BOTH forms of ``glm_moe``'s expert layer at 128 experts, top-8,
+                                                        form, monkeypatch):
+    """The forms of ``glm_moe``'s expert layer at 128 experts, top-8,
     against the reference's loop over every expert: one expert chosen by
     every row, eight chosen by all, half chosen by none. Every
-    token-choice is in the result: nothing dropped."""
-    wide, layer = _expert_layer(dims, bias)
-    wcfg = dataclasses.replace(cfg, n_routed_experts=128, experts_per_tok=8)
-    h = jax.random.normal(jax.random.key(8), (96, cfg.d_model))
+    token-choice is in the result: nothing dropped. (``grouped-kernel``:
+    the Pallas form a TPU takes for whole tiles, interpreted — 128 rows
+    at widths of 128.)"""
+    rows, widths = 96, {}
+    if form == "grouped-kernel":
+        rows, widths = 128, dict(d_model=128, moe_d_ff=128)
+        monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    wide, layer = _expert_layer(dims, bias, **widths)
+    wcfg = dataclasses.replace(cfg, n_routed_experts=128, experts_per_tok=8,
+                               **widths)
+    h = jax.random.normal(jax.random.key(8), (rows, wcfg.d_model))
     idx, w = glm_moe.route(wcfg, h, layer)
     counts = np.bincount(np.asarray(idx).ravel(), minlength=128)
-    assert counts.sum() == 96 * 8
+    assert counts.sum() == rows * 8
     if bias[5] == 9 and bias[0] == 0:
-        assert counts[5] == 96
+        assert counts[5] == rows
     if bias[0] == 9:
-        assert (counts[:8] == 96).all() and counts[8:].sum() == 0
+        assert (counts[:8] == rows).all() and counts[8:].sum() == 0
     if bias[64] == -9:
         assert counts[64:].sum() == 0
     if form == "few-rows":
         got, n = glm_moe.experts_few_rows(wcfg, h, idx, w, layer)
         assert int(n) == np.count_nonzero(counts)
     else:
+        assert (grouped_ffn.tiles_for(rows * 8, wcfg.d_model, wcfg.moe_d_ff)
+                is not None) == (form == "grouped-kernel")
         got = glm_moe.experts_grouped(wcfg, h, idx, w, layer)
     shared = glm_moe._swiglu(h, layer["ws_gate"], layer["ws_up"],
                              layer["ws_down"], jnp.float32)

@@ -34,6 +34,7 @@ from skypilot_tpu.models import llama
 from skypilot_tpu.observability import attribution
 from skypilot_tpu.ops import attention as attn_ops
 from skypilot_tpu.ops import flash_attention as fa
+from skypilot_tpu.ops import grouped_ffn
 from skypilot_tpu.ops import paged_attention as pa
 from skypilot_tpu.utils import compile_cache
 
@@ -134,6 +135,31 @@ def test_flash_kernels_compile_for_v5e(one_chip, which):
             jax.grad(_flash_loss, argnums=(0, 1, 2)), x, x, x,
             S((6, 2048), jnp.int32))
         assert n == 3
+
+
+@pytest.mark.parametrize("rows, d, f, groups, experts", [
+    (2048, 2048, 1536, 6 * 64, 64),      # a GLM-4.7-Flash chunk: 512 x top-4
+    (4096, 2048, 1024, 128, 128),        # a Trinity-Mini chunk: 512 x top-8
+    (8192, 2048, 1536, 6 * 64, 64)],     # GLM's widest wave: 4 x 512 x top-4
+    ids=["glm-chunk", "trinity-chunk", "glm-widest-wave"])
+def test_grouped_swiglu_compiles_for_v5e(one_chip, rows, d, f, groups,
+                                         experts):
+    """The grouped-SwiGLU kernel lowers at the two published widths with
+    the tiles the shapes give, over the WHOLE stack of experts and a
+    traced ``expert_base``: a tiling the Mosaic lowering refuses, or
+    blocks beyond the VMEM limit the call states, would show here."""
+    S = _sds(one_chip)
+    bf = jnp.bfloat16
+    assert grouped_ffn.tiles_for(rows, d, f) is not None
+
+    def ffn(xs, w_gate, w_up, w_down, offsets, base):
+        return grouped_ffn.grouped_swiglu(xs, w_gate, w_up, w_down,
+                                          offsets, base, interpret=False)
+
+    assert _compiled_kernels(
+        ffn, S((rows, d), bf), S((groups, d, f), bf), S((groups, d, f), bf),
+        S((groups, f, d), bf), S((experts + 1,), jnp.int32),
+        S((), jnp.int32)) == 1
 
 
 # memory_stats()["bytes_limit"] of a v5e chip: 15.75 GiB, which is also
@@ -318,14 +344,17 @@ def latent_engine_2layers():
 @pytest.mark.parametrize("program", ["decode_burst", "prefill_chunk",
                                      "admit_wave"])
 def test_latent_programs_compile_for_v5e(one_chip, latent_engine_2layers,
-                                         program):
+                                         program, monkeypatch):
     """The MLA / expert programs lower for the chip, the grouped
-    expert products of a chunk or wave become the TPU's ragged-dot
-    kernels (a decode step's few rows take none), and the donated
+    expert products of a chunk or wave are ONE Mosaic kernel
+    (``grouped_swiglu``; a decode step's few rows take none), and the donated
     latent pool is written IN PLACE: a scatter with the layer as a
     window dim made the compiler transpose the whole pool into another
     layout and back (temporaries of the pool's own size)."""
     e = latent_engine_2layers
+    # The forms a chip's trace takes (the backend here is the CPU).
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_ffn, "INTERPRET", False)
     params, _, cache, rng, table, S = _engine_args(e, one_chip)
     i32 = S((), jnp.int32)
     if program == "decode_burst":
@@ -337,12 +366,12 @@ def test_latent_programs_compile_for_v5e(one_chip, latent_engine_2layers,
         lowered = e._prefill_chunk_fn.__wrapped__.lower(
             params, cache, S((512,), jnp.int32), i32, i32, i32, i32, rng,
             table, final=True, qweights=None, span=4352, kernel=False)
-        kernels = 4          # group metadata + gate, up, down
+        kernels = 1          # the expert layer's grouped SwiGLU
     else:
         lowered = e._admit_wave_fn.__wrapped__.lower(
             params, cache, S((4, 512), jnp.int32), S((4,), jnp.int32),
             S((4,), jnp.int32), rng, table, bucket=512, qweights=None)
-        kernels = 4
+        kernels = 1
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == kernels
@@ -354,6 +383,9 @@ def test_latent_programs_compile_for_v5e(one_chip, latent_engine_2layers,
     shape = ",".join(str(n) for n in e.cache["c_kv"].shape)
     assert not re.search(rf"bf16\[{shape}\]\S* copy\(", text), \
         "the c_kv pool is copied"
+    # No layer's experts (1.2 GB) are sliced out of the stack and copied
+    # for the kernel: transients stay well under one layer's.
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 def test_latent_decode_reads_the_expert_stack_in_place(
@@ -530,7 +562,7 @@ def windowed_engine_cut():
 @pytest.mark.parametrize("program", ["decode_burst", "prefill_chunk",
                                      "admit_wave"])
 def test_windowed_programs_compile_for_v5e(one_chip, windowed_engine_cut,
-                                           program):
+                                           program, monkeypatch):
     """The ring-and-pool programs lower for the chip at the TOP rung (33
     280 rows): a token is 2048 B a layer in pool and ring alike (4
     key/value heads side by side on the minor axis; a heads axis of 4
@@ -538,9 +570,11 @@ def test_windowed_programs_compile_for_v5e(one_chip, windowed_engine_cut,
     written IN PLACE — no program copies or re-lays a whole pool or ring
     tensor, and none builds an array of either's size with the 4 heads
     on an axis of their own — and the decode program holds no Mosaic
-    kernel (the prefill programs hold the grouped expert products: four
-    calls an expert layer)."""
+    kernel (the prefill programs hold the grouped expert products: one
+    ``grouped_swiglu`` call an expert layer)."""
     e = windowed_engine_cut
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_ffn, "INTERPRET", False)
     params, _, cache, rng, table, S = _engine_args(e, one_chip)
     i32 = S((), jnp.int32)
     if program == "decode_burst":
@@ -552,12 +586,12 @@ def test_windowed_programs_compile_for_v5e(one_chip, windowed_engine_cut,
         lowered = e._prefill_chunk_fn.__wrapped__.lower(
             params, cache, S((512,), jnp.int32), i32, i32, i32, i32, rng,
             table, final=True, qweights=None, span=None, kernel=False)
-        kernels = 16
+        kernels = 4
     else:
         lowered = e._admit_wave_fn.__wrapped__.lower(
             params, cache, S((4, 512), jnp.int32), S((4,), jnp.int32),
             S((4,), jnp.int32), rng, table, bucket=512, qweights=None)
-        kernels = 16
+        kernels = 4
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == kernels

@@ -36,6 +36,8 @@ from skypilot_tpu.infer import engine as eng
 from skypilot_tpu.infer import kvcache, latent, sampling
 from skypilot_tpu.models import glm_moe as glm
 from skypilot_tpu.models import llama, registry
+from skypilot_tpu.ops import attention as attn_ops
+from skypilot_tpu.ops import grouped_ffn
 from skypilot_tpu.utils import timeline
 
 SEED = 2_900_000_011          # more than 31 bits
@@ -328,17 +330,31 @@ def _shared(cfg, h, layer):
                        layer["ws_down"], jnp.float32)
 
 
-@pytest.mark.parametrize("form", ["visited", "few-rows", "grouped"])
+@pytest.mark.parametrize("form", ["visited", "few-rows", "grouped",
+                                  "grouped-kernel"])
 @BIASES
-def test_expert_layer_under_forced_imbalance(cfg, dims, params, bias, form):
+def test_expert_layer_under_forced_imbalance(cfg, dims, params, bias, form,
+                                             monkeypatch):
+    rows = 96
+    if form == "grouped-kernel":
+        # The Pallas form of the grouped products (interpreted here):
+        # taken on a TPU for whole tiles, so 128 rows x top-2 at widths
+        # of 128, in a layer of the same seeded tensors.
+        rows = 128
+        dims = dataclasses.replace(dims, d_model=128, moe_d_ff=128)
+        cfg = dataclasses.replace(cfg, d_model=128, moe_d_ff=128)
+        params = {"moe": {n: a.astype(jnp.float32)[None]
+                          for n, a in G.layer_tensors(
+                              _key(), dims, np.uint32(1), True).items()}}
+        monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
     layer = _expert_layer(params, dims, bias)
-    h = jax.random.normal(jax.random.key(8), (96, cfg.d_model))
+    h = jax.random.normal(jax.random.key(8), (rows, cfg.d_model))
     idx, w = glm.route(cfg, h, layer)
     counts = np.bincount(np.asarray(idx).ravel(), minlength=8)
     if bias[0] == 9:
-        assert counts[0] == counts[1] == 96 and counts[2:].sum() == 0
+        assert counts[0] == counts[1] == rows and counts[2:].sum() == 0
     if bias[6] == 9:
-        assert counts[6] == 96 and counts[3:6].sum() == 0
+        assert counts[6] == rows and counts[3:6].sum() == 0
     if form == "visited":
         combine = glm.combine_weights(cfg, idx, w)
         ids, n = glm.touched_experts(cfg, idx)
@@ -348,6 +364,8 @@ def test_expert_layer_under_forced_imbalance(cfg, dims, params, bias, form):
         got, n = glm.experts_few_rows(cfg, h, idx, w, layer)
         assert int(n) == np.count_nonzero(counts)
     else:
+        assert (grouped_ffn.tiles_for(rows * 2, cfg.d_model, cfg.moe_d_ff)
+                is not None) == (form == "grouped-kernel")
         got = glm.experts_grouped(cfg, h, idx, w, layer)
     want = ref.expert_ffn(h, layer, dims, ref.Precision())
     assert float(jnp.abs(want).max()) > 0.5
