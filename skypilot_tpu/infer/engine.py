@@ -121,7 +121,8 @@ KV_TOKEN_BYTES = metrics.gauge(
     "tensors: 2 x layers x kv_heads x head_dim (+ scales) for per-head "
     "K/V, layers x (kv_lora_rank + rope dim) for a latent (MLA) cache, "
     "the full-attention layers only for a hybrid cache, the global "
-    "layers only for a windowed one")
+    "layers only for a windowed one, the attention layers only for a "
+    "short-convolution one")
 KV_BLOCKS_USED = metrics.gauge(
     "skytpu_kv_blocks_used",
     "Paged KV cache: blocks currently referenced by decode slots "
@@ -790,6 +791,10 @@ class InferenceEngine:
         # Rows a window layer keeps per slot (None: the family has no
         # such layer, and says nothing of them in its annotations).
         self._ring_rows = progs.ring_rows(cfg)
+        # Expert layers x experts; a family without an expert layer
+        # says nothing of them.
+        per_step = getattr(progs, "experts_per_step", None)
+        self._experts_per_step = per_step(cfg) if per_step else None
         refuse_options(progs, **{
             "kv_block=0": kv_block == 0 or (
                 kv_block is None and os.environ.get(
@@ -3851,6 +3856,10 @@ class InferenceEngine:
         # ``slots`` are live, ``promoted`` of them above their own rung;
         # a layer reads and attends the live ones in ``tiles`` turns.
         notes = self._family_notes(slots)
+        if self._experts_per_step:
+            # What the burst would read of the routed experts if its
+            # rows chose them all; the fetch says what it did read.
+            notes["experts_held"] = k * self._experts_per_step
         with timeline.phase(
                 "engine.decode.dispatch", seq=self._burst_seq, k=k,
                 slots=len(slots), rows=self.n_slots + 1,
@@ -3900,6 +3909,8 @@ class InferenceEngine:
                 name, counter = self._progs.SPARE_COLUMN
                 counts[name] = int(toks[:, self.n_slots].sum())
                 counter.inc(counts[name])
+            if self._experts_per_step:
+                counts["experts_held"] = handle.k * self._experts_per_step
             ph.set(tokens=n_emitted,
                    retired=len(self.finished) - before, **counts)
         if n_emitted:

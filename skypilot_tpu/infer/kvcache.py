@@ -92,7 +92,9 @@ def programs_for(cfg):
     the GQA decoders, ``infer/latent.py`` for the latent-cache family,
     ``infer/hybrid.py`` for the decoders that keep a recurrent state per
     slot beside their K/V rows, ``infer/windowed.py`` for those whose
-    sliding-window layers keep a ring of rows per slot. The engine's jitted entry points call
+    sliding-window layers keep a ring of rows per slot,
+    ``infer/shortconv.py`` for those whose short-convolution layers keep
+    a tail per slot. The engine's jitted entry points call
     through it and are otherwise one code path. What the engine uses of
     a family module, with this module's signatures:
 
@@ -121,6 +123,11 @@ def programs_for(cfg):
       slot (``None``: the family has no such layer): the decode
       dispatch annotations then say ``window_rows``, the prefill ones
       ``window_keys``;
+    * ``experts_per_step(cfg)`` — the routed experts a decode step
+      would read if its rows chose them all, expert layers x experts
+      (absent where the family has no expert layer): beside a burst's
+      ``experts_read`` (``SPARE_COLUMN``) the decode annotations then
+      say ``experts_held``, ``k`` times that;
     * ``token_bytes(cfg, cache)``, ``hbm_rows(cache, params)`` and
       ``roofline_dims(cfg)`` — the cache bytes a token holds, the HBM
       ledger's rows for what the family keeps on the device, and what

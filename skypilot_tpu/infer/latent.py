@@ -106,6 +106,10 @@ def ring_rows(cfg) -> None:
     return None
 
 
+# Expert layers x experts (see ``kvcache.programs_for``).
+experts_per_step = glm.experts_per_step
+
+
 def roofline_dims(cfg: glm.GlmMoeConfig) -> dict:
     """A token multiplies with its chosen experts only."""
     return {"param_count": cfg.active_params(), "n_layers": cfg.n_layers,

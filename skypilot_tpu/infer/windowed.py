@@ -103,6 +103,10 @@ def ring_rows(cfg: afmoe.AfmoeConfig) -> Optional[int]:
     return cfg.window
 
 
+# Expert layers x experts (see ``kvcache.programs_for``).
+experts_per_step = glm_moe.experts_per_step
+
+
 # Ring rows one turn of a window layer's in-place read takes: the
 # largest divisor of the window at most this (the ring's "block").
 _RING_BLOCK = 512

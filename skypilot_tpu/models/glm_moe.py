@@ -418,6 +418,13 @@ def route(cfg: GlmMoeConfig, h: jax.Array, layer: Params):
     return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
+def experts_per_step(cfg) -> int:
+    """Routed experts a decode step would read if its rows chose them
+    all: expert layers x experts (a serve-program module's answer to
+    ``kvcache.programs_for``, beside its count of those it did read)."""
+    return cfg.n_moe_layers * cfg.n_routed_experts
+
+
 def touched_experts(cfg: GlmMoeConfig, idx: jax.Array, live=None):
     """The distinct experts the LIVE rows of ``idx`` [T, K] chose:
     ``(ids [E] int32 — ascending, the first n of them meant, zeros
@@ -537,7 +544,9 @@ def experts_grouped(cfg: GlmMoeConfig, h, idx, w, layer) -> jax.Array:
 
 
 def moe_ffn(cfg: GlmMoeConfig, h: jax.Array, layer: Params, live=None):
-    """Shared + routed experts over rows h [B, S, D] (post-norm).
+    """Routed experts, plus the shared one where the family has it
+    (``n_shared_experts`` 0: no ``ws_*`` tensor is read and no shared
+    product runs), over rows h [B, S, D] (post-norm).
     ``live`` [B, S] bool (few rows only: a decode step's live slots):
     a row that is not live chooses no expert and gets a zero routed
     output; absent = every row counts. Returns ``(y [B, S, D], routed
@@ -554,6 +563,8 @@ def moe_ffn(cfg: GlmMoeConfig, h: jax.Array, layer: Params, live=None):
             raise ValueError("a row mask is a few-row (decode) argument")
         y = experts_grouped(cfg, rows, idx, w, layer)
         n = jnp.zeros((), jnp.int32)
+    if not cfg.n_shared_experts:
+        return y.astype(cfg.dtype).reshape(B, S, D), n
     with jax.named_scope("shared_expert"):
         y = y.astype(cfg.dtype) + _swiglu(
             rows, layer["ws_gate"], layer["ws_up"], layer["ws_down"],

@@ -11,12 +11,13 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
-from skypilot_tpu.models import afmoe, glm_moe, llama, olmo_hybrid
+from skypilot_tpu.models import (afmoe, glm_moe, lfm2_moe, llama,
+                                 olmo_hybrid)
 
 # Served decoder families, in lookup order. Each module brings, besides
 # the three names above, ``SERVE_PROGRAMS``: the module that holds its
 # serve programs (``infer.kvcache.programs_for``).
-FAMILIES = (llama, glm_moe, olmo_hybrid, afmoe)
+FAMILIES = (llama, glm_moe, olmo_hybrid, afmoe, lfm2_moe)
 
 
 def serving_configs() -> Dict[str, Any]:

@@ -80,7 +80,7 @@ HBM_BYTES = metrics.gauge(
     "skytpu_hbm_bytes",
     "Analytical HBM ledger: bytes each device-resident tensor family "
     "holds (weights, kv_pool or latent_kv_pool, recurrent_state, "
-    "window_ring, kv_used, "
+    "window_ring, conv_tail, kv_used, "
     "draft_pool, adapter_pool, prefix_pinned, workspace; expert_weights "
     "is the routed experts' part of weights)",
     labelnames=("component",))

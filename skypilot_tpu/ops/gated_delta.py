@@ -170,14 +170,14 @@ def chunk_rule(q, k, v, g, beta, state, chunk: int = CHUNK
 # The short causal convolution, with a carried tail
 # ---------------------------------------------------------------------------
 
-def causal_conv(x: jax.Array, w: jax.Array, tail: jax.Array,
-                n_valid: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal convolution of width ``K`` over time, then SiLU:
-    ``y_t = silu(sum_j w[j] xx[t + j])`` with ``xx`` the carried ``tail``
-    (the ``K - 1`` inputs before ``x_0``) followed by ``x``, so ``w[K -
-    1]`` multiplies the current input. ``x`` [B, T, C], ``w`` [K, C],
-    ``tail`` [B, K - 1, C], ``n_valid`` [B] the real tokens of each row
-    -> (``y`` [B, T, C] in ``x``'s dtype, the tail after each row's LAST
+def carried_conv(x: jax.Array, w: jax.Array, tail: jax.Array,
+                 n_valid: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal convolution of width ``K`` over time, no
+    activation: ``y_t = sum_j w[j] xx[t + j]`` with ``xx`` the carried
+    ``tail`` (the ``K - 1`` inputs before ``x_0``) followed by ``x``, so
+    ``w[K - 1]`` multiplies the current input. ``x`` [B, T, C], ``w``
+    [K, C], ``tail`` [B, K - 1, C], ``n_valid`` [B] the real tokens of
+    each row -> (``y`` [B, T, C] float32, the tail after each row's LAST
     REAL token: ``xx[n_valid : n_valid + K - 1]``, which reaches back
     into the carried tail when fewer than ``K - 1`` tokens are real)."""
     K = w.shape[0]
@@ -188,4 +188,12 @@ def causal_conv(x: jax.Array, w: jax.Array, tail: jax.Array,
     new_tail = jax.vmap(
         lambda row, at: lax.dynamic_slice_in_dim(row, at, K - 1, 0))(
             xx, n_valid)
-    return jax.nn.silu(acc).astype(x.dtype), new_tail.astype(tail.dtype)
+    return acc, new_tail.astype(tail.dtype)
+
+
+def causal_conv(x: jax.Array, w: jax.Array, tail: jax.Array,
+                n_valid: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """:func:`carried_conv`, then SiLU, in ``x``'s dtype (the linear
+    mixer's convolution; ``models/lfm2_moe.py``'s has no activation)."""
+    acc, new_tail = carried_conv(x, w, tail, n_valid)
+    return jax.nn.silu(acc).astype(x.dtype), new_tail

@@ -619,6 +619,91 @@ def test_windowed_programs_compile_for_v5e(one_chip, windowed_engine_cut,
         assert text.count(" while(") >= 12
 
 
+@pytest.fixture(scope="module")
+def shortconv_engine_cut():
+    """The short-convolution family at every published LFM2-8B-A1B
+    width, cut as the cell cuts it (the leading dense layer + 12 expert
+    layers: 10 conv, 3 attention, all 32 experts; weights are shapes
+    only; the cache is real: 7 slots — two tiles — of 1280 rows over a
+    pool of 40 blocks of 256, and 8 tails a conv layer, a few MB of host
+    memory)."""
+    from skypilot_tpu.models import lfm2_moe
+    whole = lfm2_moe.CONFIGS["lfm2-8b-a1b"]
+    cfg = dataclasses.replace(whole, n_layers=13, n_dense_layers=1,
+                              layer_types=whole.layer_types[:13])
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(cfg.dtype),
+        lfm2_moe.init_params(jax.random.key(0), cfg)))
+    return eng.InferenceEngine(
+        params, cfg, n_slots=7, max_len=1280, max_wave=4, pad_waves=True,
+        prefix_pool=0, spec_k=0, kv_blocks=40)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_chunk",
+                                     "admit_wave"])
+def test_shortconv_programs_compile_for_v5e(one_chip, shortconv_engine_cut,
+                                            program, monkeypatch):
+    """The tail-and-pool programs lower for the chip with HEADS OF 64
+    (every other served family has 128): a token is 2048 B an attention
+    layer in the pool (8 key/value heads of 64 side by side on the minor
+    axis, 512 values; laid ``[..., 8, 64]`` the minor dim would pad to
+    128 lanes), what a slot holds — rows and the two-row tails — is
+    written IN PLACE (the family's scatters: the pool flush a layer and
+    512 rows a turn, the tails by slot), a slot's tail is read by one
+    small gather (8 KB, far under the 1 MiB a window may hold), no
+    program copies or re-lays a pool tensor, an expert stack or the
+    embedding, or builds a pool-sized array with the heads on an axis of
+    their own, and the decode program
+    holds no Mosaic kernel (the prefill programs hold the grouped expert
+    products: one ``grouped_swiglu`` call an expert layer, whose tensors
+    are arrays of their own — no stack is indexed in a loop body)."""
+    e = shortconv_engine_cut
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_ffn, "INTERPRET", False)
+    params, _, cache, rng, table, S = _engine_args(e, one_chip)
+    i32 = S((), jnp.int32)
+    if program == "decode_burst":
+        lowered = e._decode_burst_fn.__wrapped__.lower(
+            params, cache, rng, S((e.n_slots + 1,), jnp.bool_), table,
+            k=4, qweights=None, span=None, kernel=False)
+        kernels = 0
+    elif program == "prefill_chunk":
+        lowered = e._prefill_chunk_fn.__wrapped__.lower(
+            params, cache, S((512,), jnp.int32), i32, i32, i32, i32, rng,
+            table, final=True, qweights=None, span=None, kernel=False)
+        kernels = 12
+    else:
+        lowered = e._admit_wave_fn.__wrapped__.lower(
+            params, cache, S((4, 512), jnp.int32), S((4,), jnp.int32),
+            S((4,), jnp.int32), rng, table, bucket=512, qweights=None)
+        kernels = 12
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels
+    assert e.cache["k"].shape == (3, 40, 256, 512)
+    assert e.cache["conv"].shape == (10, 8, 2, 2048)
+    assert 2 * e.cache["k"].shape[-1] * e.cache["k"].dtype.itemsize == 2048
+    held = sum(e.cache[n].nbytes for n in ("k", "v", "conv"))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    # (The tails, 0.3 MB here and 2.7 MB at 33 slots, the compiler MOVES
+    # to its fast memory for the steps of a burst: a copy worth having.)
+    for shape in ("3,40,256,512", "32,2048,1792", "32,1792,2048",
+                  "65536,2048"):
+        assert not re.search(rf"bf16\[{shape}\]\S* copy\(", text), \
+            f"a bf16[{shape}] tensor is copied"
+    pool = math.prod(e.cache["k"].shape)
+    for dims in re.findall(r"= bf16\[([\d,]+),8,64\]", text):
+        assert 512 * math.prod(int(d) for d in dims.split(",")) \
+            < pool // 2, f"bf16[{dims},8,64]: heads on their own axis"
+    # Transients stay a fraction of what is resident (9.2 GB of weights):
+    # the largest is a chunk's key tile of float32 scores.
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    if program == "decode_burst":
+        # steps; per attention layer its tile turns, slot and block loops
+        # (scores, values); per expert layer its visit.
+        assert text.count(" while(") >= 1 + 3 * 5 + 12
+
+
 def test_peaks_table_is_keyed_by_device_kind():
     v5e = _Device("tpu", "TPU v5 lite")
     row = attribution.peaks_for(v5e)

@@ -22,6 +22,7 @@ best logit leads its second by more than MARGIN.
 """
 
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,14 @@ def _check_greedy(reference, prompt, out):
             assert tok == int(row.argmax())
             judged += 1
     assert judged >= len(out) // 2       # the guard must not eat the test
+
+
+def _events_since(path, t0_us):
+    """The saved timeline's events that began at or after ``t0_us``
+    (``time.time() * 1e6``, the timeline's own clock)."""
+    with open(path) as f:
+        return [ev for ev in json.load(f)["traceEvents"]
+                if ev.get("ts", 0) >= t0_us]
 
 
 def _engine(params, cfg, **kw):
@@ -617,6 +626,9 @@ def test_dispatch_annotations_say_carried_and_state_rows(
     none of the three."""
     path = tmp_path / "timeline.json"
     monkeypatch.setenv(timeline.ENV_VAR, str(path))
+    # The timeline's buffer is the process's: another test's events (an
+    # engine of another family, under the same worker) must not be read.
+    t0 = time.time() * 1e6
     e = _engine(params, cfg)
     e.add_request(_prompts([75], seed=12)[0], max_new_tokens=24)
     e.add_request(_prompts([20], seed=13)[0], max_new_tokens=24)
@@ -639,8 +651,7 @@ def test_dispatch_annotations_say_carried_and_state_rows(
         max_len=128, prompt_buckets=(16, 128), prefill_chunk=32,
         kv_block=16)
     timeline.save_now()
-    with open(path) as f:
-        events = json.load(f)["traceEvents"]
+    events = _events_since(path, t0)
     chunks = [ev["args"] for ev in events
               if ev["name"] == "engine.chunk.dispatch"]
     assert [c["carried"] for c in chunks] == [0, 1, 1]
@@ -663,12 +674,11 @@ def test_dispatch_annotations_say_carried_and_state_rows(
     assert [r["kv_blocks"] for r in records] == held
     assert all(r["kv_blocks"] <= r["tiles"] * kvcache.TILE * 8
                for r in records)
-    n_before, seq1 = len(events), le.flight.seq()
+    t1, seq1 = time.time() * 1e6, le.flight.seq()
     le.add_request(list(range(1, 50)), max_new_tokens=4)
     le.run_to_completion(max_burst=4)
     timeline.save_now()
-    with open(path) as f:
-        later = json.load(f)["traceEvents"][n_before:]
+    later = _events_since(path, t1)
     mine = [ev["args"] for ev in later
             if ev["name"] in ("engine.chunk.dispatch",
                               "engine.decode.dispatch")]
