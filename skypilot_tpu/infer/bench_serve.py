@@ -306,6 +306,10 @@ def run_http(config=None, requests=16, slots=16, prompt_len=None,
                               kv_int8, weights_int8,
                               max_wave=admit_wave, buckets=buckets,
                               pad_waves=True)
+    # Both row rungs of every bucket: the warmup wave below lands on
+    # whichever rung its arrivals happen to fill, and a lone straggler
+    # in a timed wave must not compile the other.
+    engine.warm_programs(max_burst=max_burst)
 
     def free_port():
         with socket.socket() as s:
@@ -334,10 +338,9 @@ def run_http(config=None, requests=16, slots=16, prompt_len=None,
                              "stream": True}).encode()
                 for p in prompts]
 
-    # Warmup: the same concurrent wave as the measurement — compiles
-    # every admission program (pad_waves: one per bucket) and both
-    # decode burst sizes (open_burst while slots drain in, max_burst
-    # once full) outside the timed window.
+    # Warmup: the same concurrent wave as the measurement — runs the
+    # admission programs and both decode burst sizes (open_burst while
+    # slots drain in, max_burst once full) outside the timed window.
     _client_wave("127.0.0.1", lb_port, payloads)
 
     def _tpots(res):

@@ -49,6 +49,10 @@ PREFILL_SECONDS = metrics.histogram(
 PREFILL_REQUESTS = metrics.counter(
     "skytpu_prefill_requests_total",
     "Requests prefilled, by prompt bucket", labelnames=("bucket",))
+PREFILL_WAVES = metrics.counter(
+    "skytpu_prefill_waves_total",
+    "Admission waves prefilled, by prompt bucket and the row rung the "
+    "wave was padded to", labelnames=("bucket", "rows"))
 DECODE_STEP_SECONDS = metrics.histogram(
     "skytpu_decode_step_seconds",
     "Decode device-call latency, dispatch to token fetch (one call "
@@ -847,13 +851,23 @@ class InferenceEngine:
         # <= 0 means uncapped (a 0 cap would otherwise spin _admit
         # forever on empty waves).
         self.max_wave = max_wave if max_wave and max_wave > 0 else None
-        # pad_waves: every admission wave is padded to exactly max_wave
-        # rows (dummy rows -> spare slot), so ONE compiled program per
-        # bucket serves every wave. A straggler wave pays dummy prefill
-        # compute; in exchange no mid-traffic XLA compile can ever
-        # stall a request (a fresh (bucket, rows) pair otherwise
-        # compiles on first sight — tens of seconds on an 8B model).
+        # pad_waves: every admission wave is padded (dummy rows ->
+        # spare slot) to the smallest rung of the fixed row ladder
+        # {1, max_wave} that holds it, so TWO compiled programs per
+        # bucket serve every wave and warm_programs can warm them all:
+        # no mid-traffic XLA compile can ever stall a request (a fresh
+        # (bucket, rows) pair otherwise compiles on first sight — tens
+        # of seconds on an 8B model). A lone arrival prefills one row;
+        # a wave of 2..max_wave-1 pays dummy prefill compute. Unpadded
+        # engines pad to the next power of two of the wave's size and
+        # compile each pair on first sight.
         self.pad_waves = bool(pad_waves and self.max_wave)
+        if self.pad_waves:
+            self.wave_rungs = tuple(sorted({1, self.max_wave}))
+        else:
+            cap = self.max_wave or n_slots
+            self.wave_rungs = tuple(
+                1 << i for i in range((cap - 1).bit_length() + 1))
         self.sampling_params = sampling_params
         self.eos_id = eos_id
         # Speculative decoding: a host-side drafter proposes up to K
@@ -1824,20 +1838,10 @@ class InferenceEngine:
                                 span=sarg, kernel=self.kv_kernel,
                                 **lora_kw)
         with family("warm_grid.wave"), metrics.suppress():
-            # Admission waves: pad_waves pins every wave at max_wave
-            # rows, so one program per bucket suffices. Unpadded
-            # engines pad each wave to the next power of two of its
-            # size — warm that whole ladder, or declaring warmup
-            # complete would false-page on the first 2-row wave.
-            if self.pad_waves:
-                rows_ladder = [self.max_wave]
-            else:
-                cap = self.max_wave or self.n_slots
-                rows_ladder = [1]
-                r = 2
-                while r <= (1 << (cap - 1).bit_length()):
-                    rows_ladder.append(r)
-                    r <<= 1
+            # Admission waves: every (bucket, rung) pair a wave can
+            # be padded to — warm the whole ladder, or declaring
+            # warmup complete would false-page on the first wave that
+            # lands on a cold rung.
             for bucket in self.buckets:
                 if self.prefill_chunk and bucket > self.prefill_chunk \
                         and min(self.buckets) <= self.prefill_chunk:
@@ -1847,7 +1851,7 @@ class InferenceEngine:
                     # at a long --max-len its program is the largest
                     # the engine could build.
                     continue
-                for rows in rows_ladder:
+                for rows in self.wave_rungs:
                     tokens_b = np.ones((rows, bucket), np.int32)
                     true_lens = np.ones((rows,), np.int32)
                     slot_ids = np.full((rows,), spare, np.int32)
@@ -2541,12 +2545,13 @@ class InferenceEngine:
         # Waves are grouped by prompt bucket (prefill is O(S^2): one
         # long prompt must not drag every co-admitted short prompt up
         # to its bucket) and capped at max_wave, then padded to the
-        # next power-of-two row count (dummy rows -> spare slot) so
-        # each (bucket, rows) pair compiles exactly once. ``on_wave``
-        # fires as each wave's first tokens LAND (fetch order = device
-        # order) — the server streams them while later, already
-        # dispatched waves are still prefilling; requests on_wave
-        # drains into ``waiting`` join the next outer-loop pass.
+        # smallest rung of ``wave_rungs`` that holds them (dummy rows
+        # -> spare slot) so each (bucket, rows) pair compiles exactly
+        # once. ``on_wave`` fires as each wave's first tokens LAND
+        # (fetch order = device order) — the server streams them while
+        # later, already dispatched waves are still prefilling;
+        # requests on_wave drains into ``waiting`` join the next
+        # outer-loop pass.
         #
         # PIPELINED: all waves' device programs are dispatched first
         # (JAX dispatch is async; the programs chain on the donated
@@ -3124,10 +3129,7 @@ class InferenceEngine:
                 parent=req.span_ctx, attrs={"rid": req.rid})
             if req.first_token_s is None:      # not a preemption resume
                 req.queue_s = max(span.begin_s - req.submit_s, 0.0)
-        if self.pad_waves:
-            n = self.max_wave
-        else:
-            n = 1 << (len(wave) - 1).bit_length() if len(wave) > 1 else 1
+        n = next(r for r in self.wave_rungs if r >= len(wave))
         queued = [req.queue_s * 1e3 for req in wave
                   if req.first_token_s is None]
         with timeline.phase(
@@ -3193,6 +3195,8 @@ class InferenceEngine:
                        3))
         if decode_active:
             DECODE_STALL_SECONDS.observe(max(now - span.begin_s, 0.0))
+        PREFILL_WAVES.labels(bucket=str(bucket),
+                             rows=str(first.shape[0])).inc()
         self._record_flight(
             "wave", begin_s=span.begin_s, end_s=now,
             program={"bucket": bucket, "rows": first.shape[0]},

@@ -105,8 +105,7 @@ class ModelServer:
     """
 
     def __init__(self, engine, max_burst: int = 8,
-                 open_burst: int = 4, open_window_s: float = 1.0,
-                 coalesce_s: float = 0.012,
+                 open_burst: int = 4, coalesce_s: float = 0.012,
                  qos: Optional[qos_lib.AdmissionController] = None):
         self.engine = engine
         self.max_burst = max_burst
@@ -120,29 +119,29 @@ class ModelServer:
         # wait a beat (in 2 ms slices, re-draining) before dispatching.
         # Burst arrivals land over several ms — on a single-core host
         # the handler threads need the GIL the loop thread is holding —
-        # and an eager dispatch sends a 1-row wave padded to max_wave
-        # rows of FULL-bucket prefill: measured 7 waves instead of 6
-        # for a 24-request burst at wave 4, one entirely wasted 8B
-        # prefill program per run. The sleep slices also yield the GIL,
-        # which is exactly what lets the stragglers enqueue.
+        # and an eager dispatch sends the first arrival as a wave of
+        # its own: measured 7 waves instead of 6 for a 24-request
+        # burst at wave 4, one more read of an 8B model's weights per
+        # run. The sleep slices also yield the GIL, which is exactly
+        # what lets the stragglers enqueue.
         self.coalesce_s = coalesce_s
-        # Burst size while the admission window is OPEN (free slots
-        # exist AND traffic is arriving): a late HTTP arrival waits at
-        # most one short burst before its prefill, instead of a full
-        # max_burst decode (JetStream's prefill-over-generate priority;
-        # r3 driver bench showed 5x TTFT variance from arrivals
-        # stranded behind full bursts). Full bursts run when every
-        # slot is busy — admission is impossible then — and ALSO when
-        # no request has arrived for ``open_window_s``: free slots
-        # alone must not pin the burst short, or a partially loaded
-        # server pays per-burst dispatch forever (measured 359 vs 748
-        # tok/s at 24 requests on 32 slots). An unlucky arrival after
-        # a quiet spell waits at most one long burst, and the very
-        # next burst is short again.
+        # Burst size while a slot is free: a late HTTP arrival waits at
+        # most the short burst in flight and the one queued behind it
+        # before its prefill, instead of two max_burst decodes
+        # (JetStream's prefill-over-generate priority; r3 driver bench
+        # showed 5x TTFT variance from arrivals stranded behind full
+        # bursts). Full bursts run only when every slot is busy —
+        # admission is impossible then. The length turns on nothing but
+        # slot state: it used to go long after a wall-clock second
+        # without arrivals, to amortise a dispatch that the async pair
+        # below now hides (the sync path, speculation, pays one fetch
+        # a short burst), and an arrival after such a spell — most
+        # arrivals of a lightly loaded replica — then waited out up to
+        # two long bursts (535 ms each at a 16.6 ms step), by a phase
+        # that a few milliseconds decided.
         self.open_burst = min(open_burst, max_burst)
-        self.open_window_s = open_window_s
-        # Monotonic: an NTP step must not pin the window open (short
-        # bursts forever) or spuriously slam it shut.
+        # Monotonic: an NTP step must not stretch or cut a coalescing
+        # wait.
         self._last_arrival = 0.0     # guarded-by: _inbox_lock
         # Double-buffered decode (engines exposing the async pair):
         # burst k+1 is dispatched BEFORE burst k's tokens are fetched
@@ -532,8 +531,9 @@ class ModelServer:
             return False
         # Coalesce a filling wave: more arrivals are in flight when the
         # last one is only milliseconds old. Never waits when the wave
-        # is already full, slots are exhausted, or traffic has gone
-        # quiet — and the wait is bounded by one coalesce_s total.
+        # is already full, slots are exhausted, or the last arrival is
+        # older than coalesce_s — and the wait is bounded by one
+        # coalesce_s total.
         if eng.waiting and eng.free_slots:
             target = min(getattr(eng, "max_wave", None)
                          or len(eng.free_slots),
@@ -575,23 +575,19 @@ class ModelServer:
             eng.prefill_chunk_step()
             self._flush_streams()   # final chunk emits a first token
         if eng.slot_req:
-            quiet = (time.monotonic() - self._last_arrival
-                     > self.open_window_s)
             # While a chunked prefill is in flight, bursts stay short
             # regardless of slot pressure: the alternation granularity
             # IS the chunked-prefill TTFT bound. ``chunking`` is the
             # engine's live deque — its truthiness reflects claims made
             # by the admit call above.
-            k = (self.max_burst
-                 if (not eng.free_slots or quiet) and not chunking
-                 else self.open_burst)
+            full = not eng.free_slots and not chunking
+            k = self.max_burst if full else self.open_burst
             why = {}
             if self._takes_why:
                 # Why this burst has the length it has: it rides the
                 # engine's dispatch annotation.
                 why["why"] = ("chunking" if chunking
-                              else "full" if not eng.free_slots
-                              else "quiet" if quiet else "open")
+                              else "full" if full else "open")
             if self._async_decode:
                 # Dispatch the NEXT burst before fetching the previous
                 # one: the device decodes while this thread streams.
@@ -1167,11 +1163,10 @@ def make_handler(model: ModelServer):
 
 def serve(engine, host: str = "0.0.0.0", port: int = 8080,
           max_burst: int = 8, open_burst: int = 4,
-          open_window_s: float = 1.0, coalesce_s: float = 0.012,
+          coalesce_s: float = 0.012,
           qos: Optional[qos_lib.AdmissionController] = None):
     model = ModelServer(engine, max_burst=max_burst,
                         open_burst=open_burst,
-                        open_window_s=open_window_s,
                         coalesce_s=coalesce_s, qos=qos)
     httpd = _Threading((host, port), make_handler(model))
     return model, httpd
@@ -1198,15 +1193,9 @@ def _main() -> None:
                     help="decode tokens per device call (streaming "
                          "granularity vs dispatch amortization)")
     ap.add_argument("--open-burst", type=int, default=4,
-                    help="decode burst while free slots remain AND "
-                         "traffic arrived within --open-window — keeps "
+                    help="decode burst while free slots remain — keeps "
                          "late arrivals from waiting out a full burst "
                          "before their prefill")
-    ap.add_argument("--open-window", type=float, default=1.0,
-                    help="seconds since the last arrival during which "
-                         "bursts stay short when slots are free; after "
-                         "a quiet spell bursts go long (dispatch "
-                         "amortization on a partially loaded server)")
     ap.add_argument("--admit-wave", type=int, default=8,
                     help="admission wave cap: early waves' first "
                          "tokens stream while later waves prefill "
@@ -1444,8 +1433,9 @@ def _main() -> None:
             draft_engine=draft_engine,
             spec_pipeline=(bool(args.spec_pipeline)
                            if args.spec_pipeline is not None else None),
-            # One compiled prefill program per bucket: an odd wave size
-            # must never hit a mid-traffic XLA compile on a live replica.
+            # Two compiled prefill programs per bucket (1 row and
+            # --admit-wave rows), both warmed: no wave size can hit a
+            # mid-traffic XLA compile on a live replica.
             pad_waves=True,
             # Multi-tenant QoS (SKYTPU_QOS=1): WFQ + priority lanes in the
             # engine's waiting deque. All host-side — tenant count never
@@ -1476,7 +1466,6 @@ def _main() -> None:
         model, httpd = serve(engine, port=args.port,
                              max_burst=args.max_burst,
                              open_burst=args.open_burst,
-                             open_window_s=args.open_window,
                              coalesce_s=args.coalesce,
                              qos=qos_lib.admission_from_env("server"))
     watches = [engine.compile_watch]

@@ -704,6 +704,35 @@ def test_shortconv_programs_compile_for_v5e(one_chip, shortconv_engine_cut,
         assert text.count(" while(") >= 1 + 3 * 5 + 12
 
 
+@pytest.mark.parametrize("engine", [
+    "engine_8b_2layers", "hybrid_engine_1period", "windowed_engine_cut"],
+    ids=["w8a8", "hybrid", "windowed"])
+def test_one_row_wave_compiles_with_smaller_temporaries(
+        one_chip, engine, request, monkeypatch):
+    """Under ``pad_waves`` a lone arrival's wave is ONE row of its
+    bucket (the ladder's other rung beside ``max_wave``): the program
+    lowers for the chip at published widths beside the four-row one,
+    and, since the runtime reserves a program's temporaries for as long
+    as the executable lives, holds fewer of them than the four-row
+    program whose place beside the cache was already paid for. Its
+    expert layers keep the grouped kernel (512 tokens are whole tiles)."""
+    e = request.getfixturevalue(engine)
+    assert e.wave_rungs == (1, 4)
+    monkeypatch.setattr(attn_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_ffn, "INTERPRET", False)
+    params, qw, cache, rng, table, S = _engine_args(e, one_chip)
+    temps, kernels = {}, {}
+    for rows in e.wave_rungs:
+        compiled = e._admit_wave_fn.__wrapped__.lower(
+            params, cache, S((rows, 512), jnp.int32), S((rows,), jnp.int32),
+            S((rows,), jnp.int32), rng, table, bucket=512,
+            qweights=qw).compile()
+        temps[rows] = compiled.memory_analysis().temp_size_in_bytes
+        kernels[rows] = compiled.as_text().count("tpu_custom_call")
+    assert temps[1] < temps[4], temps
+    assert kernels[1] == kernels[4], kernels
+
+
 def test_peaks_table_is_keyed_by_device_kind():
     v5e = _Device("tpu", "TPU v5 lite")
     row = attribution.peaks_for(v5e)

@@ -615,6 +615,28 @@ def test_warm_grid_ledger_and_token_bytes(cfg, params):
         == cfg.n_full_layers * 2 * 16 * 16 * 4
 
 
+def test_a_lone_request_rides_a_one_row_wave_with_the_same_tokens(
+        cfg, params):
+    """Under ``pad_waves`` a lone request's wave is padded to ONE row
+    and a fuller one to ``max_wave``: the request's tokens are the same
+    from either program (its recurrent state is written per row), and after the
+    warm grid neither wave size meets a program not yet compiled."""
+    e = _engine(params, cfg, max_wave=4)
+    assert e.warm_programs(max_burst=8) > 0
+    e.declare_warmup_complete()
+    programs = e.compile_watch.count
+    prompts = _prompts([12, 20, 7, 30], seed=21)
+    seq0 = e.flight.seq()
+    alone = e.generate(prompts[:1], max_new_tokens=6)[0]
+    e.reset()
+    together = e.generate(prompts, max_new_tokens=6)[0]
+    assert alone == together
+    assert [r["program"]["rows"] for r in e.flight.since(seq0)
+            if r["burst"] == "wave"] == [1, 4]
+    assert e.compile_watch.count == programs
+    assert e.compile_watch.unexpected == []
+
+
 def test_dispatch_annotations_say_carried_and_state_rows(
         cfg, params, tmp_path, monkeypatch):
     """``engine.chunk.dispatch`` says whether the chunk continued a

@@ -331,13 +331,11 @@ def test_adaptive_burst_short_while_slots_free():
         model.shutdown()
 
 
-def test_adaptive_burst_open_window():
-    """With free slots remaining and traffic recent, the server uses
-    open_burst. open_window_s pinned huge: a loop-thread stall on a
-    loaded CI host must not flip the quiet fallback mid-test."""
+def test_adaptive_burst_open_while_slots_free():
+    """With free slots remaining the server uses open_burst, whatever
+    the clock says."""
     fake = _FakeEngine(n_slots=8)
-    model = srv.ModelServer(fake, max_burst=16, open_burst=2,
-                            open_window_s=1e9)
+    model = srv.ModelServer(fake, max_burst=16, open_burst=2)
     try:
         p = model._add([1, 2], 6)
         assert p.event.wait(timeout=30)
@@ -346,19 +344,25 @@ def test_adaptive_burst_open_window():
         model.shutdown()
 
 
-def test_adaptive_burst_long_when_quiet():
-    """Free slots alone must not pin bursts short: once no request has
-    arrived for open_window_s, bursts go long (a partially loaded
-    server would otherwise pay per-burst dispatch forever)."""
+def test_adaptive_burst_short_after_a_quiet_spell():
+    """A spell without arrivals must not lengthen the bursts while a
+    slot is free: the next arrival would wait out the long burst in
+    flight and the one queued behind it (the rule this replaces turned
+    on a wall-clock second, and a replayed schedule's first-token tail
+    forked on which side of it a burst was dispatched)."""
     fake = _FakeEngine(n_slots=8)
-    model = srv.ModelServer(fake, max_burst=16, open_burst=2,
-                            open_window_s=0.0)
+    model = srv.ModelServer(fake, max_burst=16, open_burst=2)
+    burst = fake.decode_burst
+
+    def burst_long_after_the_last_arrival(max_burst=8):
+        model._last_arrival = -1e9
+        return burst(max_burst)
+
+    fake.decode_burst = burst_long_after_the_last_arrival
     try:
         p = model._add([1, 2], 6)
         assert p.event.wait(timeout=30)
-        # Every arrival is instantly "quiet" at window 0 -> full bursts
-        # despite 7 free slots.
-        assert fake.bursts and all(b == 16 for b in fake.bursts)
+        assert len(fake.bursts) >= 2 and all(b == 2 for b in fake.bursts)
     finally:
         model.shutdown()
 
@@ -417,21 +421,58 @@ def test_engine_reset_clears_slots():
     assert len(out[0]) == 3
 
 
-def test_pad_waves_single_program_per_bucket():
-    """pad_waves pads every admission wave to max_wave rows, so results
-    are identical to the unpadded engine and odd wave sizes cannot
-    trigger fresh prefill compiles mid-traffic."""
+@pytest.fixture(scope="module")
+def wave_engines():
+    """An unpadded engine and a padded, warmed one with its compile
+    watch armed (both built once: the cases below share the compiles)."""
+    from skypilot_tpu.observability import flight as fl
     cfg = llama.CONFIGS["llama3-tiny"]
     params = llama.init_params(jax.random.key(0), cfg)
     plain = eng.InferenceEngine(params, cfg, n_slots=8, max_len=32,
                                 prompt_buckets=(8,))
     padded = eng.InferenceEngine(params, cfg, n_slots=8, max_len=32,
                                  prompt_buckets=(8,), max_wave=4,
-                                 pad_waves=True)
-    prompts = [[3, 1, 4], [1, 5], [9, 2, 6, 5], [3, 5, 8], [9, 7]]
+                                 pad_waves=True,
+                                 flight_recorder=fl.FlightRecorder())
+    assert padded.warm_programs(max_burst=8) > 0   # generate(): k <= 8
+    padded.declare_warmup_complete()
+    return plain, padded
+
+
+@pytest.mark.parametrize("n_requests, padded_rows", [
+    (1, [1]), (2, [4]), (3, [4]), (4, [4]), (5, [4, 1])])
+def test_pad_waves_single_program_per_bucket(wave_engines, n_requests,
+                                             padded_rows):
+    """pad_waves pads an admission wave to the smallest rung of
+    {1, max_wave} that holds it: results are identical to the unpadded
+    engine, a lone request prefills one row, and after warm_programs no
+    wave size meets a program the compile watch has not seen."""
+    from skypilot_tpu.observability import metrics as metrics_lib
+    plain, padded = wave_engines
+    prompts = [[3, 1, 4], [1, 5], [9, 2, 6, 5], [3, 5, 8],
+               [9, 7]][:n_requests]
     want = plain.generate(prompts, max_new_tokens=4)
+    programs = padded.compile_watch.count
+    seq0 = padded.flight.seq()
+
+    def waves_counted():
+        fam = metrics_lib.REGISTRY.snapshot().get(
+            "skytpu_prefill_waves_total", {"samples": []})
+        return {s["labels"]["rows"]: s["value"] for s in fam["samples"]
+                if s["labels"]["bucket"] == "8"}
+    before = waves_counted()
     got = padded.generate(prompts, max_new_tokens=4)
     assert got == want
+    waves = [r for r in padded.flight.since(seq0) if r["burst"] == "wave"]
+    assert [r["program"]["rows"] for r in waves] == padded_rows
+    assert [len(r["rids"]) for r in waves] == [
+        min(n_requests, 4), 1][:len(waves)]
+    assert padded.compile_watch.count == programs
+    assert padded.compile_watch.unexpected == []
+    after = waves_counted()
+    for rows in ("1", "4"):
+        assert after.get(rows, 0) - before.get(rows, 0) == \
+            padded_rows.count(int(rows))
 
 
 def test_metrics_endpoint_exposition(model_server):
