@@ -37,6 +37,11 @@ _SCOPES: Dict[str, Set[str]] = {
         "_admit_impl", "_prefill_chunk_impl", "_spec_decode_burst_impl",
         "_dispatch_decode_burst_impl", "_complete_decode_burst_impl",
         "recover",
+        # Queued chunks (PR 47): a non-final chunk is dispatched and
+        # landed later; the landing's wait is the one sync a chunk
+        # that nobody fetches still has, and it is free wherever a
+        # later program has landed already.
+        "_land_chunks", "_chunk_landed",
         # Paged-KV block management (PR 7): all host-side numpy/list
         # bookkeeping — a device fetch here would drain the dispatch
         # pipeline once per claim/retire.

@@ -535,6 +535,9 @@ def _interference(engine, fillers, longs, burst, idle_bursts=8):
         if engine.chunking:
             t0 = _time.time()
             engine.prefill_chunk_step()
+            # Honest host sync: a non-final chunk is dispatched and not
+            # awaited, and the stall is the chunk's time on the device.
+            float(engine.cache["length"][0])
             stalls.append(_time.time() - t0)
         engine.decode_burst(burst)
         now = _time.time()

@@ -97,7 +97,10 @@ PREFIX_HIT_RATIO = metrics.gauge(
 PREFILL_CHUNKS = metrics.counter(
     "skytpu_prefill_chunks_total",
     "Chunked-prefill device calls (one fixed-size chunk each, "
-    "interleaved with decode bursts)")
+    "interleaved with decode bursts), counted when the chunk is "
+    "landed: awaited=1 the prompt's final chunk, whose token the host "
+    "fetched; awaited=0 a chunk dispatched and not waited for",
+    labelnames=("awaited",))
 DECODE_STALL_SECONDS = metrics.histogram(
     "skytpu_decode_stall_seconds",
     "Time active decode slots waited on a prefill device call (one "
@@ -480,16 +483,18 @@ class KvPoolWedgedError(RuntimeError):
 
 
 @contextlib.contextmanager
-def _dispatch_boundary(seam: str):
+def _dispatch_boundary(seam: str, point: bool = True):
     """Typed failure boundary around one device dispatch seam.
 
     Chaos point ``engine.dispatch`` fires inside the try so an injected
-    fault takes the same wrap path a real device error would. Typed
-    client errors (prompt too long, unsatisfiable quota) pass through
-    unwrapped — they are the caller's fault, not a crash — as do
-    already-wrapped dispatch errors from a nested seam."""
+    fault takes the same wrap path a real device error would
+    (``point=False``: the wait for a program whose dispatch already was
+    a point). Typed client errors (prompt too long, unsatisfiable
+    quota) pass through unwrapped — they are the caller's fault, not a
+    crash — as do already-wrapped dispatch errors from a nested seam."""
     try:
-        chaos.point("engine.dispatch", seam=seam)
+        if point:
+            chaos.point("engine.dispatch", seam=seam)
         yield
     except (EngineDispatchError, PromptTooLongError,
             KvQuotaUnsatisfiableError):
@@ -740,6 +745,26 @@ class _ChunkState:
     pos: int            # next row offset to prefill
     total: int          # len(ctx)
     ctx: Optional[List[int]] = None
+
+
+@dataclasses.dataclass
+class _ChunkHandle:
+    """A dispatched chunk program whose bookkeeping has not run yet
+    (see :meth:`InferenceEngine.prefill_chunk_step`). A non-final
+    chunk's token is garbage nobody reads: the handle keeps it only as
+    the thing to wait on when the chunk is landed."""
+    tok: jax.Array                    # still on device
+    req: Request
+    final: bool
+    begin_s: float                    # wall clock before the dispatch
+    dispatch_done_s: float            # ... and when it returned
+    key: Optional[str]                # compile-watch program key
+    span_arg: Optional[int]           # the program's static span
+    decode_active: bool               # rows were decoding at dispatch
+    # Decode bursts dispatched before this chunk: a burst with a higher
+    # ``seq`` runs behind it, so that burst's landing proves it done.
+    burst_seq: int
+    window_keys: Optional[int] = None
 
 
 class InferenceEngine:
@@ -1223,6 +1248,10 @@ class InferenceEngine:
         self.slot_req: Dict[int, Request] = {}
         self.waiting: Deque[Request] = collections.deque()
         self.chunking: Deque[_ChunkState] = collections.deque()
+        # Non-final chunks of the head chunker that were dispatched and
+        # not awaited, oldest first: at most one when the next chunk is
+        # dispatched, none once a final chunk has been fetched.
+        self._queued_chunks: Deque[_ChunkHandle] = collections.deque()
         self.finished: List[Request] = []
         # Requests a crashed admission pass was holding in locals
         # (crash safety; see _rescue_admit_limbo).
@@ -1631,7 +1660,8 @@ class InferenceEngine:
                        = None,
                        kv_blocks: Optional[int] = None,
                        window_rows: Optional[int] = None,
-                       window_keys: Optional[int] = None) -> None:
+                       window_keys: Optional[int] = None,
+                       queued: Optional[int] = None) -> None:
         """Append one burst record to the flight recorder. HOST
         bookkeeping only — every value here already lives on the host
         (request lists, ints, floats); a device fetch on this path
@@ -1639,7 +1669,9 @@ class InferenceEngine:
         observe. COW/eviction/lazy-grow attribution: whatever
         accumulated since the previous record rides this one (claims
         run just before the wave/chunk record they belong to; lazy
-        growth happens inside the burst being recorded)."""
+        growth happens inside the burst being recorded; a queued
+        chunk's record is written at its landing, so what its claim
+        accumulated may ride the burst record that lands before it)."""
         cow, self._fl_cow = self._fl_cow, 0
         evs, self._fl_evictions = self._fl_evictions, 0
         lazy, self._fl_lazy_grows = self._fl_lazy_grows, 0
@@ -1669,6 +1701,10 @@ class InferenceEngine:
                 extra["window_rows"] = window_rows
         if window_keys is not None:
             extra["window_keys"] = window_keys
+        if queued is not None:
+            # A chunk record: 1 = dispatched and landed later (its end
+            # is when the host saw it done), 0 = the awaited final one.
+            extra["queued"] = queued
         if stall:
             extra["stall"] = True
         if drafted:
@@ -2801,17 +2837,60 @@ class InferenceEngine:
         return "ok"
 
     def prefill_chunk_step(self) -> bool:
-        """Run ONE chunk of the head chunked prefill (host-synced: the
-        scheduler deliberately alternates chunk -> decode burst, so the
-        chunk's device time is the decode stall it causes — recorded
-        into skytpu_decode_stall_seconds when slots were decoding).
-        Returns True if a chunk ran. Runs behind the ``chunk`` dispatch
-        boundary: a device failure mid-chunk surfaces as a recoverable
+        """Dispatch ONE chunk of the head chunked prefill. Only a
+        prompt's FINAL chunk is awaited: its token is the request's
+        first. A non-final chunk's token is garbage nobody reads, so
+        the chunk is dispatched, kept as a handle and LANDED later —
+        its bookkeeping (``skytpu_prefill_chunks_total``,
+        ``req.n_chunks``, ``skytpu_decode_stall_seconds`` when slots
+        were decoding, the ``chunk`` flight record) runs when a decode
+        burst dispatched behind it is fetched, or at the next chunk
+        but one, whichever comes first. At most one chunk is unlanded
+        when the next is dispatched (one running, one queued), with or
+        without rows decoding, so nothing dispatched later waits behind
+        more than two chunk programs. The scheduler still alternates
+        chunk -> decode burst; the device runs them in dispatch order.
+        Returns True if a chunk was dispatched. Dispatch and landing
+        run behind the ``chunk`` dispatch boundary: a device failure in
+        a chunk, queued or awaited, surfaces as a recoverable
         :class:`EngineDispatchError`."""
         if not self.chunking:
             return False
         with _dispatch_boundary("chunk"):
             return self._prefill_chunk_impl()
+
+    def _land_chunks(self, before_seq: Optional[int] = None,
+                     keep: int = 0) -> None:
+        """Run the bookkeeping of queued chunks, oldest first: all but
+        the newest ``keep``, or only those a burst of ``before_seq``
+        was dispatched behind. Each is waited for on its own output, so
+        its end is its own where the device had not passed it yet and
+        costs nothing where a later program has already landed."""
+        q = self._queued_chunks
+        while len(q) > keep and (before_seq is None
+                                 or q[0].burst_seq < before_seq):
+            handle = q.popleft()
+            with _dispatch_boundary("chunk", point=False), \
+                    timeline.phase("engine.chunk.land"):
+                handle.tok.block_until_ready()
+            self._chunk_landed(handle)
+
+    def _chunk_landed(self, handle: _ChunkHandle) -> None:
+        """Host bookkeeping of one finished chunk program."""
+        end_s = time.time()
+        req = handle.req
+        PREFILL_CHUNKS.labels(awaited="1" if handle.final else "0").inc()
+        req.n_chunks += 1
+        if handle.decode_active:
+            DECODE_STALL_SECONDS.observe(end_s - handle.begin_s)
+        self._record_flight(
+            "chunk", begin_s=handle.begin_s, end_s=end_s,
+            program={"span": handle.span_arg, "final": handle.final},
+            slots=[req.slot], reqs=[req], toks=1 if handle.final else 0,
+            stall=handle.decode_active,
+            dispatch_s=handle.dispatch_done_s, dev_keys=[handle.key],
+            window_keys=handle.window_keys,
+            queued=0 if handle.final else 1)
 
     def _prefill_chunk_impl(self) -> bool:
         st = self.chunking[0]
@@ -2824,7 +2903,6 @@ class InferenceEngine:
         chunk = np.zeros((C,), np.int32)
         chunk[:n_valid] = ctx[start:start + n_valid]
         new_len = st.total if final else self.max_len
-        decode_active = bool(self.slot_req)
         # The big-cache dot reads only rows below this chunk's offset:
         # the span bucket covering ``start`` suffices, and because the
         # span is a pure function of the offset, warm (suffix-only)
@@ -2832,14 +2910,20 @@ class InferenceEngine:
         # the cached-vs-cold parity guarantee extends to spans.
         attn_span = self._span_arg(self._span_for(start))
         self.decode_programs.add(("chunk", final, attn_span))
+        # One running, one queued: the chunk before the last is landed
+        # (waited for, where no burst's landing has proved it done)
+        # before this one joins the device's queue.
+        self._land_chunks(keep=1)
         t0 = time.time()
         fresh = req.first_token_s is None    # not a preemption resume
         counts = {"chunk_tokens": n_valid, "padded_tokens": C,
-                  "final": 1 if final else 0}
+                  "final": 1 if final else 0,
+                  "queued": 0 if final else 1}
         if self._progs.SLOT_STATE:
             # Whether the chunk continues a state resident in the slot.
             counts["carried"] = 1 if start > 0 else 0
-        if fresh and req.n_chunks == 0:
+        if fresh and req.n_chunks == 0 and not self._queued_chunks:
+            # The request's first chunk: none landed, none queued.
             req.queue_s = max(t0 - req.submit_s, 0.0)
             counts["queue_ms"] = round(req.queue_s * 1e3, 3)
         window = self._window_notes([(start, n_valid)])
@@ -2853,31 +2937,30 @@ class InferenceEngine:
                 self.table_device(), final=final,
                 qweights=self.qweights, span=attn_span,
                 kernel=self.kv_kernel, **self._lora_args())
-        t_disp = time.time()             # dispatch returned; fetch next
-        chunk_key = self.compile_watch.last_key
-        with timeline.phase("engine.chunk.fetch",
-                            final=counts["final"]) as ph:
-            tok = int(tok_dev)           # host sync (garbage unless final)
-            if final and fresh:
+        handle = _ChunkHandle(
+            tok=tok_dev, req=req, final=final, begin_s=t0,
+            dispatch_done_s=time.time(),
+            key=self.compile_watch.last_key, span_arg=attn_span,
+            decode_active=bool(self.slot_req),
+            burst_seq=self._burst_seq,
+            window_keys=window.get("window_keys"))
+        st.pos += n_valid
+        if not final:
+            self._queued_chunks.append(handle)
+            return True
+        # The chunks before it first, each at its own end; then THE
+        # fetch of a chunked prefill: the final chunk's token, which is
+        # the request's first.
+        self._land_chunks()
+        with timeline.phase("engine.chunk.fetch", final=1) as ph:
+            tok = int(tok_dev)           # host sync
+            if fresh:
                 # The request's first token lands here: its queue wait
                 # and its TTFT on one event, as on a wave's fetch.
                 ph.set(queue_ms=round(req.queue_s * 1e3, 3),
                        ttft_ms=round(max(time.time() - req.submit_s,
                                          0.0) * 1e3, 3))
-        dt = time.time() - t0
-        PREFILL_CHUNKS.inc()
-        req.n_chunks += 1
-        if decode_active:
-            DECODE_STALL_SECONDS.observe(dt)
-        self._record_flight(
-            "chunk", begin_s=t0, end_s=t0 + dt,
-            program={"span": attn_span, "final": final},
-            slots=[req.slot], reqs=[req], toks=1 if final else 0,
-            stall=decode_active, dispatch_s=t_disp,
-            dev_keys=[chunk_key], window_keys=window.get("window_keys"))
-        st.pos += n_valid
-        if not final:
-            return True
+        self._chunk_landed(handle)
         self.chunking.popleft()
         now = time.time()
         tracing.record_span(
@@ -3397,6 +3480,9 @@ class InferenceEngine:
         error for every future request (advisor r3)."""
         self.waiting.clear()
         self.chunking.clear()
+        # Queued chunks are dropped unlanded: their rows die with the
+        # slots wiped below, and a chunker re-admits from the start.
+        self._queued_chunks.clear()
         self.finished.clear()
         self.slot_req.clear()
         self.free_slots = list(range(self.n_slots))
@@ -3452,9 +3538,11 @@ class InferenceEngine:
         """
         # Snapshot before the wipe: residents (decode slots), chunkers
         # (mid-chunked-prefill — disjoint from residents until the
-        # final chunk), and the untouched queue. Order within each
-        # class is deterministic (rid = arrival order) so a recovered
-        # engine admits in the same order every time.
+        # final chunk; reset() drops their queued chunk handles, and
+        # they re-prefill from the start), and the untouched queue.
+        # Order within each class is deterministic (rid = arrival
+        # order) so a recovered engine admits in the same order every
+        # time.
         residents = sorted(self.slot_req.values(), key=lambda r: r.rid)
         chunkers = [st.req for st in self.chunking]
         chunker_rids = {r.rid for r in chunkers}
@@ -3502,12 +3590,13 @@ class InferenceEngine:
 
     def step_burst(self, max_burst: int = 8,
                    on_wave=None) -> Dict[int, List[int]]:
-        """Admit, run ONE prefill chunk if any are queued (chunk ->
-        decode-burst alternation: long prompts prefill without stalling
-        decode for their whole length), then decode up to ``max_burst``
-        tokens per slot in one device call. Tokens past a request's
-        EOS/limit are discarded host-side (their cache rows die with
-        the slot). Returns {rid: [tokens...]} emitted this call.
+        """Admit, dispatch ONE prefill chunk if any are queued (chunk
+        -> decode-burst alternation: long prompts prefill without
+        stalling decode for their whole length), then decode up to
+        ``max_burst`` tokens per slot in one device call; the burst's
+        fetch lands the chunk that ran before it. Tokens past a
+        request's EOS/limit are discarded host-side (their cache rows
+        die with the slot). Returns {rid: [tokens...]} emitted this call.
         ``on_wave`` fires after each admission wave (streaming flush
         hook)."""
         self._admit(on_wave)
@@ -3725,6 +3814,7 @@ class InferenceEngine:
         # deliberate sync of the spec path — same role as
         # complete_decode_burst's.
         n_done0 = len(self.finished)
+        self._land_chunks()      # all dispatched before this verify
         with timeline.phase(
                 "engine.decode.fetch", seq=self._burst_seq, k=K + 1,
                 parts=1, waiting=len(self.waiting)) as fetch_ph:
@@ -3898,6 +3988,8 @@ class InferenceEngine:
 
     def _complete_decode_burst_impl(self, handle: "BurstHandle"
                                     ) -> Dict[int, List[int]]:
+        # A chunk dispatched before this burst ran before it.
+        self._land_chunks(before_seq=handle.seq)
         with timeline.phase("engine.decode.fetch", seq=handle.seq,
                             k=handle.k, parts=1,
                             waiting=len(self.waiting)) as ph:
@@ -3995,6 +4087,7 @@ class InferenceEngine:
         t_disp = time.time()
         step_key = self.compile_watch.last_key
         n_done0 = len(self.finished)
+        self._land_chunks()      # all dispatched before this step
         with timeline.phase(
                 "engine.decode.fetch", seq=self._burst_seq, k=1,
                 parts=1, waiting=len(self.waiting)) as fetch_ph:

@@ -566,12 +566,16 @@ class ModelServer:
                 eng.admit(on_wave=self._on_wave)
                 self._flush_streams()
         if chunking:
-            # Interference scheduler: land the outstanding burst, run
-            # ONE prefill chunk, then fall through to dispatch the next
-            # decode burst — chunk -> decode alternation, so a long
-            # prompt's prefill never stalls decode slots for more than
-            # one chunk and TPOT stops spiking during admission waves.
-            self._complete_burst()
+            # Interference scheduler: dispatch ONE prefill chunk, then
+            # fall through to dispatch the next decode burst and only
+            # THEN land the outstanding one — chunk -> decode
+            # alternation, so a long prompt's prefill never stalls
+            # decode slots for more than one chunk, in the dispatch-
+            # then-land order the loop runs when nothing chunks: the
+            # device holds a chunk and a burst queued while this thread
+            # streams. The engine awaits only a prompt's final chunk
+            # (its token is the request's first); the burst behind a
+            # non-final chunk lands it.
             eng.prefill_chunk_step()
             self._flush_streams()   # final chunk emits a first token
         if eng.slot_req:

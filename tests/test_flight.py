@@ -402,18 +402,84 @@ def test_chunk_verify_interleave_consistency():
         sum(1 for r in window if r.get("stall"))
 
 
+@pytest.mark.parametrize("live", [False, True], ids=["alone", "live_rows"])
+def test_a_queued_chunks_record_is_written_at_its_landing(live):
+    """A non-final chunk's record appears when the chunk is landed —
+    by the burst dispatched behind it, or before the next chunk but one
+    — closes no earlier than its dispatch returned, says ``queued`` and,
+    where rows were decoding, ``stall``; the counter's ``awaited``
+    label and ``n_chunks`` count every chunk once."""
+    e = _mk_engine(spec_k=0, prefix_pool=0)
+    if live:
+        e.generate([[1, 2, 3]], max_new_tokens=1)    # programs warm
+        e.add_request([4, 5, 6], max_new_tokens=40)
+        e.admit()
+        assert e.slot_req
+    before = metrics_lib.REGISTRY.snapshot()
+    seq0 = e.flight.seq()
+    e.add_request(list(range(1, 30)), max_new_tokens=3)   # 4 chunks
+    e.admit()
+    long_req = e.chunking[0].req
+
+    def chunk_records():
+        return [r for r in e.flight.since(seq0) if r["burst"] == "chunk"]
+
+    assert e.prefill_chunk_step()
+    assert len(e._queued_chunks) == 1 and not chunk_records()
+    assert long_req.n_chunks == 0
+    if live:
+        # The burst behind the chunk lands it.
+        handle = e.dispatch_decode_burst(max_burst=2)
+        e.complete_decode_burst(handle)
+        assert not e._queued_chunks and len(chunk_records()) == 1
+    assert e.prefill_chunk_step()
+    assert e.prefill_chunk_step()
+    # One running, one queued: the third dispatch first landed the
+    # first chunk, where no burst's landing had.
+    assert len(e._queued_chunks) == 2 and len(chunk_records()) == 1
+    assert e.prefill_chunk_step()                 # the final chunk
+    assert not e._queued_chunks and not e.chunking
+    recs = chunk_records()
+    assert [r["queued"] for r in recs] == [1, 1, 1, 0]
+    assert [r["program"]["final"] for r in recs] == [False] * 3 + [True]
+    assert [bool(r.get("stall")) for r in recs] == [live] * 4
+    for r in recs:
+        assert 0 <= r["dispatch_wall_ms"] <= r["dur_s"] * 1e3 + 1e-6
+        assert r["fetch_wall_ms"] >= 0
+    assert long_req.n_chunks == 4 and len(long_req.tokens) == 1
+    after = metrics_lib.REGISTRY.snapshot()
+
+    def awaited(snap, value):
+        return sum(s["value"]
+                   for s in snap["skytpu_prefill_chunks_total"]["samples"]
+                   if s["labels"].get("awaited") == value)
+
+    assert awaited(after, "0") - awaited(before, "0") == 3
+    assert awaited(after, "1") - awaited(before, "1") == 1
+    stalls = _hist_count_delta(before, after,
+                               "skytpu_decode_stall_seconds")
+    assert stalls == (4 if live else 0)
+    e.run_to_completion()
+    assert e.blocks_used == 0
+
+
 def test_reset_mid_flight_ring_survives():
     e = _mk_engine()
     rec = e.flight
     # Long prompt -> chunked claim; run ONE chunk then reset with the
     # prefill mid-flight.
+    e.generate([[1, 2, 3]], max_new_tokens=2)    # history in the ring
     e.add_request(list(range(1, 21)), max_new_tokens=4)
     e.admit()
     assert e.chunking
     e.prefill_chunk_step()
     n = rec.seq()
     assert n >= 1
+    # The non-final chunk was dispatched and not awaited: no record of
+    # it yet, and the reset drops its handle unlanded.
+    assert len(e._queued_chunks) == 1
     e.reset()
+    assert not e._queued_chunks
     # Ring survives the reset (history is the point), bounded, and
     # the engine serves cleanly afterwards with records flowing.
     assert rec.seq() == n

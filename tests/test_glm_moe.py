@@ -22,6 +22,7 @@ benchmark's seeded weights and a FLOAT32 program:
 
 import dataclasses
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -514,11 +515,14 @@ def test_burst_of_two_live_slots_equals_reference_and_counts_their_experts(
                                      for i in range(2)] for s in range(k)]
 
 
-def _fetch_records(path):
+def _fetch_records(path, t0_us):
+    """The fetch records that began at or after ``t0_us`` (the
+    timeline's buffer is the process's: another test's stay out)."""
     timeline.save_now()
     with open(path) as f:
         return [e["args"] for e in json.load(f)["traceEvents"]
-                if e["name"] == "engine.decode.fetch"]
+                if e["name"] == "engine.decode.fetch"
+                and e.get("ts", 0) >= t0_us]
 
 
 def test_engine_burst_reports_experts_read_with_its_tokens(
@@ -529,6 +533,7 @@ def test_engine_burst_reports_experts_read_with_its_tokens(
     the Llama family reports no such field."""
     path = tmp_path / "timeline.json"
     monkeypatch.setenv(timeline.ENV_VAR, str(path))
+    t0 = time.time() * 1e6
     e = _engine(params, cfg)
     prompts = _prompts([20, 27], seed=13)
     rids = [e.add_request(p, max_new_tokens=12) for p in prompts]
@@ -537,7 +542,7 @@ def test_engine_burst_reports_experts_read_with_its_tokens(
     counter = latent.EXPERTS_READ._require_default()
     before = counter.value
     out = e.decode_burst(4)
-    (rec,) = _fetch_records(path)
+    (rec,) = _fetch_records(path, t0)
     assert rec["k"] == 4 and rec["tokens"] == 8
     by_rid = {r.rid: r for r in e.slot_req.values()}
     seqs = [list(p) + by_rid[rid].tokens for p, rid in zip(prompts, rids)]
@@ -554,7 +559,7 @@ def test_engine_burst_reports_experts_read_with_its_tokens(
     le.add_request(list(range(1, 9)), max_new_tokens=6)
     le.admit()
     assert le.decode_burst(4)
-    records = _fetch_records(path)
+    records = _fetch_records(path, t0)
     assert len(records) == 2 and "experts_read" not in records[-1]
     assert records[-1]["tokens"] == 4
     assert counter.value - before == want

@@ -247,6 +247,19 @@ def test_engine_waves_chunks_bursts_and_span_rungs(cfg, params, reference):
     assert len({key[2] for key in e.decode_programs}) >= 2
 
 
+def test_the_serve_loop_queues_chunks_and_serves_the_same_tokens(
+        cfg, params, reference, tmp_path, monkeypatch):
+    """A prompt's non-final chunks are dispatched and not awaited
+    (PR 47); the slot's recurrent state and conv tail are still carried from chunk to chunk in dispatch
+    order."""
+    from tests.test_infer_server import check_a_family_through_the_loop
+    path = tmp_path / "timeline.json"
+    monkeypatch.setenv(timeline.ENV_VAR, str(path))
+    check_a_family_through_the_loop(
+        lambda: _engine(params, cfg), _prompts([100], seed=21)[0],
+        lambda prompt, out: _check_greedy(reference, prompt, out), path)
+
+
 def test_engine_single_steps(cfg, params, reference):
     """``step()``: the one-token program, with a second request
     mid-prefill while the first decodes (its state must not move)."""

@@ -3,6 +3,7 @@
 import json
 import socket
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -195,6 +196,179 @@ def test_server_loop_drives_chunked_prefill():
         assert warm["tokens"] == cold["tokens"]
     finally:
         model.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# A prompt's non-final chunks are dispatched and not awaited (PR 47).
+
+_CHUNK = 8
+
+
+def _events_since(path, t0_us):
+    """The saved timeline's events that began at or after ``t0_us``
+    (the buffer is the process's: other tests' events stay out)."""
+    with open(path) as f:
+        return sorted((ev for ev in json.load(f)["traceEvents"]
+                       if ev.get("ts", 0) >= t0_us),
+                      key=lambda ev: ev["ts"])
+
+
+def _chunk_lens(n_chunks):
+    """(warm-up prompt or None, the judged prompt): a cold prompt of n
+    chunks, or for ONE chunk the repeat of a two-chunk prompt, whose
+    first chunk is a prefix hit and whose suffix is the final chunk."""
+    if n_chunks == 1:
+        prompt = list(range(3, 3 + _CHUNK + 4))
+        return prompt, prompt
+    return None, list(range(3, 3 + (n_chunks - 1) * _CHUNK + 5))
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = llama.CONFIGS["llama3-tiny"]
+    return llama.init_params(jax.random.key(0), cfg), cfg
+
+
+def serve_through_the_loop(engine, prompt, new_tokens, live, path,
+                           warm=None):
+    """Serve ``prompt`` through the SERVER loop of ``engine`` — beside
+    a short request that decodes all the while if ``live`` — and return
+    (result, the loop's events from the submit on, whether the rider
+    was still decoding when the result came). ``path`` is where
+    ``SKYTPU_TIMELINE_FILE_PATH`` points. Other families' serve tests
+    use it too."""
+    from skypilot_tpu.utils import timeline
+    model = srv.ModelServer(engine, max_burst=4, open_burst=2)
+    try:
+        assert model._ready.wait(timeout=300)
+        if warm is not None:
+            assert "error" not in model.submit(warm, 2)
+        rider = None
+        if live:
+            # Rows are live under every chunk of the long prompt.
+            rider = model.submit_stream([5, 6, 7], 100)
+            assert "tokens" in next(rider)
+        t0 = time.time() * 1e6
+        out = model.submit(prompt, new_tokens)
+        assert "error" not in out
+        rode = bool(engine.slot_req) if live else None
+        if rider is not None:
+            list(rider)
+        timeline.save_now()
+        return out, _events_since(path, t0), rode
+    finally:
+        model.shutdown()
+
+
+def check_chunk_order(events, n_chunks, live):
+    """The loop's own order of events, for ONE prompt of ``n_chunks``:
+    only the final chunk is fetched, every other is dispatched
+    ``queued`` and landed, never two of them unlanded when the next is
+    dispatched, and exactly one chunk lies between two bursts
+    dispatched while the prompt chunked. Returns how many bursts said
+    ``why="chunking"``."""
+    def named(name):
+        return [ev for ev in events if ev["name"] == name]
+
+    dispatches = named("engine.chunk.dispatch")
+    assert [d["args"]["final"] for d in dispatches] == \
+        [0] * (n_chunks - 1) + [1]
+    assert [d["args"]["queued"] for d in dispatches] == \
+        [1] * (n_chunks - 1) + [0]
+    fetches = named("engine.chunk.fetch")
+    assert [f["args"]["final"] for f in fetches] == [1]
+    assert fetches[0]["args"]["ttft_ms"] > 0
+    assert len(named("engine.chunk.land")) == n_chunks - 1
+    unlanded = since_burst = chunking_bursts = 0
+    for ev in events:
+        name, args = ev["name"], ev.get("args", {})
+        if name == "engine.chunk.dispatch":
+            assert unlanded <= 1, "two chunks unlanded at a dispatch"
+            unlanded += 1
+            since_burst += 1
+        elif name in ("engine.chunk.land", "engine.chunk.fetch"):
+            unlanded -= 1
+        elif name == "engine.decode.dispatch" \
+                and args.get("why") == "chunking":
+            # One chunk since the burst before it: the alternation.
+            assert since_burst == 1, (since_burst, chunking_bursts)
+            since_burst = 0
+            chunking_bursts += 1
+        elif name == "engine.decode.dispatch" and since_burst:
+            # The burst after the FINAL chunk: the prompt no longer
+            # chunks, so it says "open"; it too follows one chunk where
+            # rows were live, and all of them where no burst had
+            # anything to decode before.
+            assert since_burst == (1 if live else n_chunks)
+            since_burst = 0
+    assert unlanded == 0
+    return chunking_bursts
+
+
+def check_a_family_through_the_loop(make_engine, prompt, check_greedy,
+                                    path):
+    """A family that carries slot state from chunk to chunk (a
+    recurrent state, a ring, conv tails), through the SERVER loop
+    beside a request that decodes all the while: the four-chunk
+    prompt's non-final chunks are dispatched and not awaited, the
+    device runs them in dispatch order all the same — the tokens are
+    the reference's (``check_greedy``) and the library loop's, one
+    chunk lies between two bursts, only the final chunk is fetched."""
+    out, events, rode = serve_through_the_loop(
+        make_engine(), prompt, 8, True, path)
+    assert rode, "the rider finished before the long prompt did"
+    check_greedy(prompt, out["tokens"])
+    assert out["tokens"] == make_engine().generate(
+        [prompt], max_new_tokens=8)[0]
+    assert out["prefill_chunks"] == 4
+    assert check_chunk_order(events, 4, True) == 3
+    assert [ev["args"]["carried"] for ev in events
+            if ev["name"] == "engine.chunk.dispatch"] == [0, 1, 1, 1]
+
+
+@pytest.fixture()
+def timeline_path(tmp_path, monkeypatch):
+    from skypilot_tpu.utils import timeline
+    path = tmp_path / "timeline.json"
+    monkeypatch.setenv(timeline.ENV_VAR, str(path))
+    return path
+
+
+@pytest.mark.parametrize("live", [False, True], ids=["alone", "live_rows"])
+@pytest.mark.parametrize("n_chunks", [1, 2, 3, 9])
+def test_only_the_final_chunk_is_awaited(tiny_model, timeline_path,
+                                         n_chunks, live):
+    """The serve loop over a prompt of n chunks, with and without rows
+    decoding beside it: the tokens are an unchunked engine's; the one
+    ``engine.chunk.fetch`` is the final chunk's; every other chunk was
+    dispatched ``queued`` and landed, never more than one of them
+    unlanded when the next was dispatched; and between two bursts
+    dispatched while the prompt chunked there is exactly one chunk."""
+    params, cfg = tiny_model
+    engine = eng.InferenceEngine(
+        params, cfg, n_slots=3, max_len=128, prompt_buckets=(8, 128),
+        prefill_chunk=_CHUNK, prefix_pool=2)
+    warm, prompt = _chunk_lens(n_chunks)
+    out, events, rode = serve_through_the_loop(
+        engine, prompt, 6, live, timeline_path, warm=warm)
+    plain = eng.InferenceEngine(params, cfg, n_slots=1, max_len=128,
+                                prompt_buckets=(128,))
+    assert out["tokens"] == plain.generate([prompt], max_new_tokens=6)[0]
+    assert out["prefill_chunks"] == n_chunks
+    assert not engine._queued_chunks
+    chunking_bursts = check_chunk_order(events, n_chunks, live)
+    if live:
+        assert rode, "the rider finished before the long prompt did"
+        assert chunking_bursts == n_chunks - 1
+        # A queued chunk that ran beside live rows says so on its
+        # flight record, which closes no earlier than its dispatch.
+        chunks = [r for r in engine.flight.tail()
+                  if r["burst"] == "chunk"][-n_chunks:]
+        assert [r["queued"] for r in chunks] == \
+            [1] * (n_chunks - 1) + [0]
+        assert all(r.get("stall") for r in chunks)
+        assert all(r["dispatch_wall_ms"] <= r["dur_s"] * 1e3 + 1e-6
+                   for r in chunks)
 
 
 def _post_stream(url, payload, timeout=300):

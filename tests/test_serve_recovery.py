@@ -253,6 +253,56 @@ def test_crash_mid_chunk_releases_blocks_and_adapter_pins(params):
     assert e.blocks_used == 0
 
 
+class _DeadToken:
+    """Stands in for the output of a chunk program the device failed."""
+
+    def block_until_ready(self):
+        raise RuntimeError("device halted in a queued chunk")
+
+
+@pytest.mark.parametrize("where", ["next_chunk", "burst", "reset"])
+def test_a_failed_queued_chunk_surfaces_at_its_landing(params, where):
+    """A non-final chunk is dispatched and not awaited (PR 47), so a
+    device failure in it shows when it is landed: before the next chunk
+    but one, or by the burst dispatched behind it — the same typed,
+    recoverable ``EngineDispatchError`` of the ``chunk`` seam — and
+    ``recover()`` / ``reset()`` leave no handle behind; the recovered
+    run's tokens are the uncrashed run's."""
+    def mk():
+        return eng.InferenceEngine(params, CFG, n_slots=2, max_len=64,
+                                   prompt_buckets=(16, 32),
+                                   prefill_chunk=8, kv_block=16)
+    long_prompt = _prompts(n=1, length=30, seed=8)[0]     # 4 chunks
+    want = mk().generate([[7, 8, 9], long_prompt],
+                         max_new_tokens=NEW_TOKENS)
+    e = mk()
+    short = e.add_request([7, 8, 9], max_new_tokens=NEW_TOKENS)
+    e.admit()
+    long = e.add_request(long_prompt, max_new_tokens=NEW_TOKENS)
+    e.admit()
+    assert e.slot_req and e.chunking
+    assert e.prefill_chunk_step()
+    assert len(e._queued_chunks) == 1
+    e._queued_chunks[0].tok = _DeadToken()
+    if where == "reset":
+        e.reset()
+        assert not e._queued_chunks and not e.chunking
+        return
+    with pytest.raises(eng.EngineDispatchError) as err:
+        if where == "burst":
+            e.complete_decode_burst(e.dispatch_decode_burst(max_burst=2))
+        else:
+            e.prefill_chunk_step()     # one running, one queued: fine
+            e.prefill_chunk_step()     # lands the dead one first
+    assert err.value.seam == "chunk" and err.value.recoverable
+    assert e.recover(err.value) == 2
+    assert not e._queued_chunks and not e.chunking
+    assert _drive(e) == 0
+    by_rid = {r.rid: list(r.tokens) for r in e.finished}
+    assert [by_rid[short], by_rid[long]] == want
+    assert e.blocks_used == 0
+
+
 def test_crash_mid_verify_releases_drafter_slots(params, distilled):
     """Leak audit, crash mid spec-verify: every drafter slot is free
     after the recovered run — the draft engine's claims died with the

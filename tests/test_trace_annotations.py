@@ -122,6 +122,20 @@ def test_wave_rows_and_first_chunks_equal_requests_admitted(served):
         assert a[4]["prompt_tokens"] <= a[4]["rows"] * a[4]["bucket"]
 
 
+def test_only_a_final_chunk_is_fetched_the_others_are_queued(served):
+    """A non-final chunk says ``queued`` on its dispatch, has a
+    ``land`` and no ``fetch``; the trailers still count every chunk."""
+    anns, results, _ = served
+    chunks = _named(anns, "engine.chunk.dispatch")
+    assert [a[4]["queued"] for a in chunks] == \
+        [1 - a[4]["final"] for a in chunks]
+    fetches = _named(anns, "engine.chunk.fetch")
+    assert len(fetches) == 2 and all(a[4]["final"] == 1 for a in fetches)
+    assert len(_named(anns, "engine.chunk.land")) == len(chunks) - 2
+    assert sorted(r["prefill_chunks"] for r in results) == \
+        [0] * 5 + [2, 3]
+
+
 def test_queue_wait_never_exceeds_time_to_first_token(served):
     anns, _, _ = served
     first = 0

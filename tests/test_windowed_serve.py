@@ -25,6 +25,7 @@ best logit leads its second by more than MARGIN.
 """
 
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -323,6 +324,19 @@ def test_engine_waves_chunks_bursts_and_span_rungs(cfg, params, reference):
     assert len({key[2] for key in e.decode_programs}) >= 2
 
 
+def test_the_serve_loop_queues_chunks_and_serves_the_same_tokens(
+        cfg, params, reference, tmp_path, monkeypatch):
+    """A prompt's non-final chunks are dispatched and not awaited
+    (PR 47); the ring of the slot's window layers is still carried from chunk to chunk in dispatch
+    order."""
+    from tests.test_infer_server import check_a_family_through_the_loop
+    path = tmp_path / "timeline.json"
+    monkeypatch.setenv(timeline.ENV_VAR, str(path))
+    check_a_family_through_the_loop(
+        lambda: _engine(params, cfg), _prompts([100], seed=21)[0],
+        lambda prompt, out: _check_greedy(reference, prompt, out), path)
+
+
 def test_engine_single_steps(cfg, params, reference):
     """``step()``: the one-token program, with a second request
     mid-prefill while the first decodes (its ring must not move)."""
@@ -506,6 +520,9 @@ def test_dispatch_annotations_say_window_rows_and_window_keys(
     /metrics carry them; an engine of the Llama family says neither."""
     path = tmp_path / "timeline.json"
     monkeypatch.setenv(timeline.ENV_VAR, str(path))
+    # The timeline's buffer is the process's: an earlier test's events
+    # under the same worker must not be read.
+    t0 = time.time() * 1e6
     e = _engine(params, cfg)
     e.add_request(_prompts([75], seed=12)[0], max_new_tokens=24)
     e.add_request(_prompts([20], seed=13)[0], max_new_tokens=24)
@@ -526,7 +543,8 @@ def test_dispatch_annotations_say_window_rows_and_window_keys(
     e.run_to_completion(max_burst=4)
     timeline.save_now()
     with open(path) as f:
-        events = json.load(f)["traceEvents"]
+        events = [ev for ev in json.load(f)["traceEvents"]
+                  if ev.get("ts", 0) >= t0]
     chunks = [ev["args"] for ev in events
               if ev["name"] == "engine.chunk.dispatch"]
     assert [c["chunk_tokens"] for c in chunks] == [32, 32, 11]
@@ -556,12 +574,13 @@ def test_dispatch_annotations_say_window_rows_and_window_keys(
         llama.init_params(jax.random.key(0), lcfg), lcfg, n_slots=2,
         max_len=128, prompt_buckets=(16, 128), prefill_chunk=32,
         kv_block=16)
-    n_before, seq1 = len(events), le.flight.seq()
+    t1, seq1 = time.time() * 1e6, le.flight.seq()
     le.add_request(list(range(1, 50)), max_new_tokens=4)
     le.run_to_completion(max_burst=4)
     timeline.save_now()
     with open(path) as f:
-        later = json.load(f)["traceEvents"][n_before:]
+        later = [ev for ev in json.load(f)["traceEvents"]
+                 if ev.get("ts", 0) >= t1]
     mine = [ev["args"] for ev in later if ev["name"].endswith(".dispatch")]
     assert mine and not any(
         "window_rows" in a or "window_keys" in a for a in mine)
